@@ -1,0 +1,758 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, started cold, drives the two main paths through the entry
+points a user calls and checks what comes out:
+
+  * snapshot:    `trtpu activate` (transferia_tpu.cli.main.main) over an
+                 `fs` parquet source -> mask_field(URL) + filter_rows ->
+                 memory sink, once per placement (host = the reference,
+                 device, device again, auto), on the ClickBench-shaped
+                 73-column table and on the 10-column table.  At these
+                 sizes parquet's writer gives up on a dictionary for the
+                 URL column, so URLs cross the link as per-row SHA
+                 blocks on both; the wide leg also masks SearchPhrase
+                 (eight values), whose pool hashes on the chip once;
+  * replication: `run_replication`, as `trtpu replicate` calls it, over
+                 examples/kafka2ch.yaml (JSON parser, rename + mask) from
+                 the in-repo fake broker into the fake ClickHouse, device
+                 placement pinned, poll sizes ragged.
+
+Right means: the rows the generator's ground truth says survive the
+filter, a table fingerprint equal to the host pass, masked values equal
+to hashlib's HMAC on a sample, and device counters that show the chip
+did the work.  Any failed check, or any exception, is a non-zero exit.
+
+The script REFUSES to run unless JAX resolves a TPU: it exits non-zero
+and prints no result.  `--rehearsal` runs the same legs on the CPU
+backend at a tiny size to debug the script itself; it says so, prints no
+rate and no result line, and its exit code says nothing about the chip.
+
+One process uses the chip: everything below runs in this process, the
+fake servers are threads, and the only child is the C++ compiler.
+
+    python chip_smoke.py            # on a machine with a TPU
+    python chip_smoke.py --rehearsal  # CPU, tiny, debugging only
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import hmac
+import json
+import logging
+import os
+import shutil
+import sys
+import threading
+import time
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, ROOT)
+
+FILTER = "RegionID < 400 AND ResolutionWidth >= 390"
+# masked columns per table: URL is the bench's PII column; SearchPhrase
+# stays dictionary-encoded off the parquet page, which makes it the
+# column that takes the device-pool mask route at full size
+MASKED = {"wide": ["URL", "SearchPhrase"], "ten": ["URL"]}
+SNAPSHOT_SALT = "smoke-salt"
+REPLICATION_SALT = "smoke-replication-salt"
+_TS0 = 1_790_000_000_000_000  # epoch microseconds of the first event
+# loggers whose INFO lines are part of what this script establishes: the
+# backend a worker resolved at its first fused plan
+_INFO_LOGGERS = ("transferia_tpu.runtime.backend",)
+
+
+class Checks:
+    """Named pass/fail facts; every failure is kept, none is fatal until
+    the end, so one chip run reports all of them."""
+
+    def __init__(self):
+        self.items: list[dict] = []
+
+    def check(self, name: str, ok: bool, detail: str = "") -> None:
+        self.items.append({"name": name, "ok": bool(ok), "detail": detail})
+        print(f"  [{'ok' if ok else 'FAIL'}] {name}"
+              + (f" — {detail}" if detail else ""), flush=True)
+
+    def failed(self) -> list[dict]:
+        return [c for c in self.items if not c["ok"]]
+
+
+class _PlacementLog(logging.Handler):
+    """Collects the fused steps' own placement log lines — the surface
+    an operator of `trtpu activate` sees (the loader owns the per-part
+    chains, so there is no step object to ask afterwards)."""
+
+    def __init__(self):
+        super().__init__(level=logging.INFO)
+        self.lines: list[str] = []
+
+    def emit(self, record: logging.LogRecord) -> None:
+        msg = record.getMessage()
+        if "placement:" in msg:
+            self.lines.append(msg)
+
+    def attach(self, logger: logging.Logger) -> None:
+        """INFO into this handler only — one "fused N steps" line per
+        part would bury the run's own output on stderr."""
+        self._restore = (logger.level, logger.propagate)
+        logger.setLevel(logging.INFO)
+        logger.propagate = False
+        logger.addHandler(self)
+
+    def detach(self, logger: logging.Logger) -> None:
+        if self in logger.handlers:
+            logger.removeHandler(self)
+            logger.setLevel(self._restore[0])
+            logger.propagate = self._restore[1]
+
+
+def _phase(title: str) -> None:
+    print(f"\n== {title}", flush=True)
+
+
+def _cache_entries(path: str) -> int:
+    if not os.path.isdir(path):
+        return 0
+    return sum(1 for n in os.listdir(path) if not n.endswith("-atime"))
+
+
+# -- data ----------------------------------------------------------------------
+
+def generate(args, data_dir: str) -> dict:
+    import bench
+
+    wide = os.path.join(data_dir, f"hits_wide_{args.rows}.parquet")
+    ten = os.path.join(data_dir, f"hits_{args.rows10}.parquet")
+    t0 = time.perf_counter()
+    bench.generate_wide_dataset(wide, args.rows, args.batch_rows,
+                                seed=args.seed)
+    bench.generate_dataset(ten, args.rows10, args.batch_rows,
+                           seed=args.seed + 1)
+    import pyarrow.parquet as pq
+
+    out = {"seconds": round(time.perf_counter() - t0, 2), "tables": {}}
+    for name, path in (("wide", wide), ("ten", ten)):
+        meta = pq.ParquetFile(path).metadata
+        out["tables"][name] = {
+            "path": path, "rows": meta.num_rows,
+            "columns": meta.num_columns,
+            "row_groups": meta.num_row_groups,
+            "file_mb": round(os.path.getsize(path) / 1e6, 1),
+            "expected_kept": bench.expected_kept(path),
+        }
+    return out
+
+
+# -- snapshot leg ----------------------------------------------------------------
+
+def _transformation(name: str) -> dict:
+    return {"transformers": [
+        {"mask_field": {"columns": MASKED[name], "salt": SNAPSHOT_SALT}},
+        {"filter_rows": {"filter": FILTER}},
+    ]}
+
+
+def _write_transfer_yaml(path: str, transfer_id: str, name: str,
+                         parquet: str, batch_rows: int,
+                         process_count: int) -> None:
+    doc = {
+        "id": transfer_id,
+        "type": "SNAPSHOT_ONLY",
+        "src": {"type": "fs", "params": {
+            "path": parquet, "format": "parquet", "table": "hits",
+            "batch_rows": batch_rows}},
+        "dst": {"type": "memory", "params": {"sink_id": transfer_id}},
+        "transformation": _transformation(name),
+        "runtime": {"process_count": process_count},
+    }
+    import yaml
+
+    with open(path, "w") as fh:
+        yaml.safe_dump(doc, fh)
+
+
+def _mask_sample_mismatches(source, masked_cols: list[str], batch,
+                            n: int = 2048) -> int:
+    """Independent reference for the mask: hashlib's HMAC-SHA256 of the
+    SOURCE table's values for a sample of landed rows, joined by
+    WatchID.  `source` is the file's (WatchID, masked columns) table."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    n = min(n, batch.n_rows)
+    ids = batch.column("WatchID").to_pylist()[:n]
+    hit = source.filter(pc.is_in(
+        source["WatchID"], value_set=pa.array(ids, type=pa.int64())))
+    row_of = {wid: i for i, wid in enumerate(hit["WatchID"].to_pylist())}
+    key = SNAPSHOT_SALT.encode()
+    bad = 0
+    for col in masked_cols:
+        plain = hit[col].to_pylist()
+        got = batch.column(col).to_pylist()[:n]
+        for wid, masked in zip(ids, got):
+            want = hmac.new(key, plain[row_of[wid]].encode(),
+                            hashlib.sha256).hexdigest()
+            if isinstance(masked, bytes):
+                masked = masked.decode()
+            bad += masked != want
+    return bad
+
+
+def snapshot_pass(table: dict, name: str, placement: str, tag: str,
+                  args, work_dir: str, source) -> dict:
+    """One `trtpu activate` of the table under one placement mode."""
+    from transferia_tpu.abstract.interfaces import is_columnar
+    from transferia_tpu.cli.main import main as trtpu
+    from transferia_tpu.columnar.batch import reset_intern_cache
+    from transferia_tpu.ops.rowhash import TableFingerprinter
+    from transferia_tpu.providers.memory import get_store
+    from transferia_tpu.providers.parquet_native import reset_file_caches
+    from transferia_tpu.stats.trace import TELEMETRY
+    from transferia_tpu.transform.fused import set_placement
+
+    transfer_id = f"smoke-{name}-{tag}"
+    yaml_path = os.path.join(work_dir, f"{transfer_id}.yaml")
+    _write_transfer_yaml(yaml_path, transfer_id, name, table["path"],
+                         args.batch_rows, args.process_count)
+    store = get_store(transfer_id)
+    store.clear()
+    # dict pools are shared for the process — per decoded dict page and
+    # per (file, column) content — and carry their hashed form as a
+    # memo: without this the host pass's memo would answer for the chip
+    # in every later pass
+    reset_file_caches()
+    reset_intern_cache()
+    set_placement(placement)
+    TELEMETRY.reset()
+    t0 = time.perf_counter()
+    try:
+        rc = trtpu(["--log-level", "warning", "activate",
+                    "--transfer", yaml_path])
+    finally:
+        set_placement(None)
+    seconds = time.perf_counter() - t0
+    tel = TELEMETRY.snapshot()
+    fp = TableFingerprinter(backend="host")
+    first = None
+    for b in store.batches:
+        if is_columnar(b) and b.n_rows:
+            first = first or b
+            fp.push(b)
+    out = {
+        "placement": placement, "rc": rc,
+        "rows_landed": store.row_count(),
+        "seconds": round(seconds, 3),
+        "fingerprint": fp.result().digest(),
+        "mask_sample_mismatches": (
+            _mask_sample_mismatches(source, MASKED[name], first)
+            if first is not None else -1),
+        "telemetry": tel,
+    }
+    store.clear()
+    return out
+
+
+def snapshot_leg(name: str, table: dict, args, work_dir: str,
+                 checks: Checks, rehearsal: bool) -> dict:
+    _phase(f"snapshot leg: {name} table, {table['rows']} rows x "
+           f"{table['columns']} cols, {table['row_groups']} row groups")
+    import pyarrow.parquet as pq
+
+    placement_log = _PlacementLog()
+    fused_logger = logging.getLogger("transferia_tpu.transform.fused")
+    source = pq.read_table(table["path"],
+                           columns=["WatchID"] + MASKED[name])
+    out: dict = {"passes": {}}
+    for tag, placement in (("host", "host"), ("device", "device"),
+                           ("device2", "device"), ("auto", "auto")):
+        if tag == "auto":
+            placement_log.attach(fused_logger)
+        try:
+            res = snapshot_pass(table, name, placement, tag, args,
+                                work_dir, source)
+        finally:
+            placement_log.detach(fused_logger)
+        out["passes"][tag] = res
+        tel = res["telemetry"]
+        line = (f"  {tag:8s} rows={res['rows_landed']} "
+                f"launches={tel['device_launches']} "
+                f"h2d={tel['h2d_bytes']} d2h={tel['d2h_bytes']} "
+                f"compiles={tel['compile_events']} "
+                f"routes(flat/pool/host_subset)="
+                f"{tel['mask_rows_device_flat']}/"
+                f"{tel['mask_rows_device_pool']}/"
+                f"{tel['mask_rows_host_subset']}")
+        if not rehearsal:
+            line += (f" {res['seconds']:.2f}s "
+                     f"compile={tel['compile_seconds']:.1f}s")
+        print(line, flush=True)
+    host, dev, dev2, auto = (out["passes"][k] for k in
+                             ("host", "device", "device2", "auto"))
+    want = table["expected_kept"]
+    for tag, res in out["passes"].items():
+        checks.check(f"{name}/{tag}: activate exits 0 and lands "
+                     f"expected_kept rows",
+                     res["rc"] == 0 and res["rows_landed"] == want,
+                     f"landed {res['rows_landed']}, want {want}")
+        checks.check(f"{name}/{tag}: masked {'+'.join(MASKED[name])} "
+                     f"sample equals hashlib HMAC",
+                     res["mask_sample_mismatches"] == 0,
+                     f"{res['mask_sample_mismatches']} mismatches")
+        if tag != "host":
+            checks.check(f"{name}/{tag}: fingerprint equals the host pass",
+                         res["fingerprint"] == host["fingerprint"],
+                         f"{res['fingerprint']} vs {host['fingerprint']}")
+    checks.check(f"{name}/host: the host pass never touched the device",
+                 host["telemetry"]["device_launches"] == 0)
+    dtel = dev["telemetry"]
+    checks.check(f"{name}/device: device_launches, h2d_bytes, d2h_bytes > 0",
+                 min(dtel["device_launches"], dtel["h2d_bytes"],
+                     dtel["d2h_bytes"]) > 0,
+                 f"{dtel['device_launches']} launches, "
+                 f"{dtel['h2d_bytes']} B in, {dtel['d2h_bytes']} B out")
+    if len(MASKED[name]) > 1:
+        checks.check(f"{name}/device: the chip hashed the dictionary "
+                     f"pool itself (pool uploads > 0)",
+                     dtel["dict_pool_uploads"] > 0,
+                     f"{dtel['dict_pool_uploads']} uploads, "
+                     f"{dtel['dict_pool_hits']} memo hits")
+    checks.check(f"{name}/device2: a repeated device pass compiles nothing",
+                 dev2["telemetry"]["compile_events"] == 0,
+                 f"{dev2['telemetry']['compile_events']} compile events")
+    out["auto_placement_log"] = placement_log.lines
+    atel = auto["telemetry"]
+    print(f"  auto: {len(placement_log.lines)} placement decisions logged, "
+          f"{atel['device_launches']} device launches", flush=True)
+    for msg in placement_log.lines[:8]:
+        print(f"    {msg}", flush=True)
+    return out
+
+
+# -- one long-lived chain under auto placement -------------------------------------
+
+def chain_probe(name: str, table: dict, args, n_batches: int,
+                rehearsal: bool, checks: Checks) -> dict:
+    """`auto` on ONE chain that lives for n_batches (what a replication
+    stream has, and a snapshot part only when it spans several row
+    groups): the fused step's own placement_summary() with the host and
+    device ns/row behind it — report only.  With more than one device
+    it also pins one batch to the device and holds the mesh program's
+    psum'd kept count to the host strategy's."""
+    from transferia_tpu.abstract.schema import TableID
+    from transferia_tpu.abstract.table import TableDescription
+    from transferia_tpu.factories import new_storage
+    from transferia_tpu.models import Transfer
+    from transferia_tpu.providers.file import FileSourceParams
+    from transferia_tpu.providers.stdout import NullTargetParams
+    from transferia_tpu.transform.chain import build_chain
+    from transferia_tpu.transform.fused import (
+        DeviceFusedStep,
+        set_placement,
+    )
+
+    transformation = _transformation(name)
+    transfer = Transfer(
+        id=f"smoke-probe-{name}",
+        src=FileSourceParams(path=table["path"], format="parquet",
+                             table="hits", batch_rows=args.batch_rows),
+        dst=NullTargetParams(), transformation=transformation)
+    batches: list = []
+
+    class _Enough(Exception):
+        pass
+
+    def collect(batch):
+        batches.append(batch)
+        if len(batches) >= n_batches:
+            raise _Enough()
+
+    try:
+        new_storage(transfer).load_table(
+            TableDescription(id=TableID("fs", "hits")), collect)
+    except _Enough:
+        pass
+    set_placement("auto")
+    try:
+        chain = build_chain(transformation)
+        for b in batches:
+            chain.apply(b)
+        plan = chain.plan_for(batches[0].table_id, batches[0].schema)
+        steps = [s for s in plan.steps if isinstance(s, DeviceFusedStep)]
+        summaries = [s.placement_summary() for s in steps]
+        if any(s.sharded_program is not None for s in steps):
+            set_placement("host")
+            host_kept = chain.apply(batches[0]).n_rows
+            set_placement("device")
+            dev_kept = chain.apply(batches[0]).n_rows
+            for s in steps:
+                sp = s.sharded_program
+                checks.check(
+                    f"mesh/{name}: psum'd kept count over {sp.n_dev} "
+                    f"devices equals the host strategy's",
+                    sp.last_kept == dev_kept == host_kept,
+                    f"psum {sp.last_kept}, device {dev_kept}, "
+                    f"host {host_kept}")
+    finally:
+        set_placement(None)
+    out = {"batches": len(batches), "steps": []}
+    for s, summary in zip(steps, summaries):
+        if rehearsal:  # the decision, not the CPU host's ns/row
+            summary = summary.split()[0]
+        entry = {"step": s.describe(), "summary": summary,
+                 "sharded_program": s.sharded_program is not None}
+        sp = s.sharded_program
+        if sp is not None:
+            entry["mesh_devices"] = sp.n_dev
+            entry["last_kept"] = sp.last_kept
+        out["steps"].append(entry)
+        print(f"  {name}: {entry['step']}: {entry['summary']}"
+              + (f" mesh={sp.n_dev}" if sp is not None else ""),
+              flush=True)
+    return out
+
+
+# -- replication leg -----------------------------------------------------------------
+
+def replication_leg(args, checks: Checks, rehearsal: bool) -> dict:
+    """examples/kafka2ch.yaml, as `trtpu replicate` runs it (activate is
+    a no-op for INCREMENT_ONLY; then run_replication), fake broker ->
+    fake ClickHouse.  Messages arrive in waves of very different sizes,
+    so the polls are ragged and the row buckets get exercised."""
+    from tests.recipes.fake_clickhouse import FakeCH
+    from tests.recipes.fake_kafka import FakeKafka
+    from transferia_tpu.cli.config import load_transfer
+    from transferia_tpu.coordinator import MemoryCoordinator
+    from transferia_tpu.providers.kafka.client import KafkaClient, Record
+    from transferia_tpu.runtime import run_replication
+    from transferia_tpu.stats.trace import TELEMETRY
+    from transferia_tpu.transform.fused import set_placement
+
+    waves = args.waves
+    _phase(f"replication leg: examples/kafka2ch.yaml, waves {waves}")
+    n_partitions = 4
+    srv = FakeKafka(n_partitions=n_partitions).start()
+    ch = FakeCH().start()
+    stop = threading.Event()
+    th = None
+    set_placement("device")
+    TELEMETRY.reset()
+    try:
+        os.environ["KAFKA_BROKERS"] = f"127.0.0.1:{srv.port}"
+        os.environ["CH_HOST"] = "127.0.0.1"
+        os.environ["MASK_SALT"] = REPLICATION_SALT
+        transfer = load_transfer(
+            os.path.join(ROOT, "examples", "kafka2ch.yaml"))
+        transfer.dst.port = ch.port      # the example pins CH's 8123
+        transfer.dst.bufferer = None     # push per poll, as bench.py does
+        srv.create_topic("events")
+        cp = MemoryCoordinator()
+        th = threading.Thread(
+            target=run_replication, args=(transfer, cp),
+            kwargs={"stop_event": stop, "backoff": 0.2}, daemon=True)
+        th.start()
+        producer = KafkaClient([f"127.0.0.1:{srv.port}"])
+        emails: dict[int, str] = {}
+        produced = 0
+        t0 = time.perf_counter()
+        for wave, size in enumerate(waves):
+            recs: list[list] = [[] for _ in range(n_partitions)]
+            for i in range(produced, produced + size):
+                emails[i] = f"user{i}.w{wave}@mail{i % 977}.example"
+                recs[i % n_partitions].append(Record(
+                    key=b"", value=json.dumps({
+                        "id": i, "user_email": emails[i],
+                        "amount": (i % 1000) / 8.0,
+                        "ts": _TS0 + i,
+                    }).encode()))
+            for p in range(n_partitions):
+                if recs[p]:
+                    producer.produce("events", p, recs[p])
+            produced += size
+            deadline = time.monotonic() + 300
+            while ch.total_rows() < produced and \
+                    time.monotonic() < deadline:
+                time.sleep(0.02)
+        seconds = time.perf_counter() - t0
+        producer.close()
+    finally:
+        stop.set()
+        if th is not None:
+            th.join(timeout=30)
+        set_placement(None)
+        srv.stop()
+        ch.stop()
+    tel = TELEMETRY.snapshot()
+    landed = ch.total_rows()
+    tables = sorted(n for n in ch.tables if not n.startswith("__trtpu"))
+    key = REPLICATION_SALT.encode()
+    bad = ids_seen = 0
+    for name in tables:
+        for row in ch.rows(name):
+            ids_seen += 1
+            i = int(row["id"])
+            want = hmac.new(key, emails[i].encode(),
+                            hashlib.sha256).hexdigest().encode()
+            bad += (row["user_email"] != want
+                    or row["amount"] != (i % 1000) / 8.0
+                    or row["ts"] != _TS0 + i)
+    out = {"waves": list(waves), "produced": produced, "landed": landed,
+           "tables": tables, "telemetry": tel}
+    line = (f"  produced={produced} landed={landed} tables={tables} "
+            f"launches={tel['device_launches']} "
+            f"compiles={tel['compile_events']} "
+            f"routes(flat/pool/host_subset)="
+            f"{tel['mask_rows_device_flat']}/{tel['mask_rows_device_pool']}/"
+            f"{tel['mask_rows_host_subset']}")
+    if not rehearsal:
+        out["seconds"] = round(seconds, 3)
+        line += (f" {seconds:.2f}s compile={tel['compile_seconds']:.1f}s")
+    print(line, flush=True)
+    checks.check("replication: rows produced == rows landed",
+                 landed == produced, f"{landed} of {produced}")
+    checks.check("replication: the worker thread stopped",
+                 th is not None and not th.is_alive())
+    checks.check("replication: rename landed rows in events_clean",
+                 tables == ["events_clean"], str(tables))
+    checks.check("replication: every landed row equals the produced one, "
+                 "user_email as hashlib's HMAC",
+                 bad == 0 and ids_seen == produced,
+                 f"{bad} mismatches over {ids_seen} rows")
+    checks.check("replication: device_launches > 0",
+                 tel["device_launches"] > 0,
+                 f"{tel['device_launches']} launches")
+    return out
+
+
+# -- kernels alone, beside the placement models' constants ---------------------------
+
+def kernel_rates() -> dict:
+    import bench
+    from transferia_tpu.ops.rowhash import DEVICE_FINGERPRINT_ROWS_PER_S
+    from transferia_tpu.transform.fused import DEVICE_MASK_ROWS_PER_S
+
+    _phase("kernels alone (resident buffers), beside the model constants")
+    mask = bench.measure_device_kernel()
+    fprint = bench.measure_device_fingerprint()
+    out = {
+        "mask_rows_per_s": mask["value"],
+        "mask_model_constant": DEVICE_MASK_ROWS_PER_S,
+        "fingerprint_rows_per_s": fprint["value"],
+        "fingerprint_model_constant": DEVICE_FINGERPRINT_ROWS_PER_S,
+    }
+    print(f"  mask kernel {mask['value']:,} rows/s "
+          f"(model: {DEVICE_MASK_ROWS_PER_S:,.0f}); fingerprint "
+          f"{fprint['value']:,} rows/s "
+          f"(model: {DEVICE_FINGERPRINT_ROWS_PER_S:,.0f})", flush=True)
+    return out
+
+
+# -- main -----------------------------------------------------------------------------
+
+def parse_args(argv=None):
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--rehearsal", action="store_true",
+                   help="CPU backend, tiny sizes, no rates, no result "
+                        "line: debugs this script, says nothing about "
+                        "the chip")
+    p.add_argument("--seed", type=int, default=7)
+    p.add_argument("--rows", type=int, default=None,
+                   help="wide (73-col) table rows (default 10,000,000, "
+                        "bench.py's headline; rehearsal 60,000)")
+    p.add_argument("--rows10", type=int, default=None,
+                   help="10-col table rows (default 2,000,000; "
+                        "rehearsal 60,000)")
+    p.add_argument("--batch-rows", type=int, default=None,
+                   help="rows per row group (default 131,072; "
+                        "rehearsal 16,384)")
+    p.add_argument("--process-count", type=int, default=4,
+                   help="snapshot part threads (runtime.process_count)")
+    p.add_argument("--out", default=os.path.join(ROOT, "chiprun_out"),
+                   help="directory for chip_smoke.json")
+    p.add_argument("--data-dir", default=os.path.join(ROOT, ".chip_smoke"),
+                   help="scratch directory for generated data "
+                        "(emptied at exit)")
+    args = p.parse_args(argv)
+    small = args.rehearsal
+    args.rows = args.rows or (60_000 if small else 10_000_000)
+    args.rows10 = args.rows10 or (60_000 if small else 2_000_000)
+    # 16,384-row groups keep ~11k rows after scan pushdown: enough to
+    # take the mesh route when the rehearsal sees 8 virtual CPU devices
+    args.batch_rows = args.batch_rows or (16_384 if small else 131_072)
+    args.waves = ((300, 1700, 60, 900) if small
+                  else (3_000, 17_000, 600, 40_000))
+    return args
+
+
+def run(args, summary: dict, checks: Checks) -> None:
+    """Every phase, in order, filling `summary` and `checks` as it goes.
+    Exceptions propagate — a crashed phase is a crashed run."""
+    import jax
+
+    from transferia_tpu.runtime.backend import (
+        describe_backend,
+        require_tpu,
+        setup_compile_cache,
+    )
+
+    rehearsal = args.rehearsal
+    summary.update(rehearsal=rehearsal, seed=args.seed)
+    t_start = time.perf_counter()
+
+    _phase("backend")
+    cache_dir = setup_compile_cache()
+    cache_before = _cache_entries(cache_dir)
+    cache_events = {"hits": 0, "misses": 0}
+
+    def on_event(event: str, **kw) -> None:
+        if event.endswith("/cache_hits"):
+            cache_events["hits"] += 1
+        elif event.endswith("/cache_misses"):
+            cache_events["misses"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    backend = describe_backend() if rehearsal else require_tpu()
+    summary["backend"] = backend
+    print("  " + json.dumps(backend), flush=True)
+    if rehearsal:
+        print("  REHEARSAL on the CPU backend: no rate below is printed "
+              "and none is meant; there is no result line", flush=True)
+    print(f"  compile cache: {cache_dir} ({cache_before} entries)",
+          flush=True)
+
+    _phase("native library: forced build from the tracked sources")
+    from transferia_tpu import native
+
+    so = native.build(force=True)
+    lib = native.lib()
+    checks.check("native: built from source and loaded",
+                 lib is not None and os.path.exists(so),
+                 os.path.basename(str(so)))
+    summary["native"] = os.path.basename(str(so))
+
+    _phase("data: generated from the seed")
+    shutil.rmtree(args.data_dir, ignore_errors=True)
+    os.makedirs(args.data_dir)
+    data = generate(args, args.data_dir)
+    summary["data"] = data
+    for name, t in data["tables"].items():
+        print(f"  {name}: {t['rows']} rows x {t['columns']} cols, "
+              f"{t['row_groups']} row groups, {t['file_mb']} MB, "
+              f"expected_kept={t['expected_kept']}", flush=True)
+    checks.check("data: wide table is 73 columns wide",
+                 data["tables"]["wide"]["columns"] == 73)
+
+    _phase("link")
+    from transferia_tpu.ops.fused import _chunk_rows
+    from transferia_tpu.ops.linkprobe import probe_link
+
+    link = probe_link()
+    summary["link"] = {
+        "describe": link.describe(), "measured": link.measured,
+        "launch_overhead_s": link.launch_overhead_s,
+        "h2d_bytes_per_s": link.h2d_bytes_per_s,
+        "d2h_bytes_per_s": link.d2h_bytes_per_s,
+        "chunk_rows": _chunk_rows(),
+    }
+    print(f"  {link.describe()} chunk_rows={_chunk_rows()}", flush=True)
+    if not rehearsal:
+        checks.check("link: profile is measured, not pinned",
+                     link.measured, link.describe())
+
+    summary["snapshot"] = {}
+    for name in ("wide", "ten"):
+        summary["snapshot"][name] = snapshot_leg(
+            name, data["tables"][name], args, args.data_dir, checks,
+            rehearsal)
+
+    _phase("auto placement on one long-lived chain")
+    summary["chain_probe"] = {
+        name: chain_probe(name, data["tables"][name], args, 8, rehearsal,
+                          checks)
+        for name in ("wide", "ten")}
+    if backend["device_count"] > 1:
+        for name, probe in summary["chain_probe"].items():
+            checks.check(
+                f"mesh/{name}: fused steps built the sharded program",
+                all(s["sharded_program"] for s in probe["steps"]))
+        peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                 for d in jax.devices()]
+        summary["device_peak_bytes"] = peaks
+        print(f"  peak bytes in use per device: {peaks}", flush=True)
+        if not rehearsal:
+            checks.check("mesh: every device held data (peak > 1 MiB)",
+                         min(peaks) > (1 << 20), str(peaks))
+
+    summary["replication"] = replication_leg(args, checks, rehearsal)
+
+    if not rehearsal:
+        summary["kernels"] = kernel_rates()
+
+    cache_after = _cache_entries(cache_dir)
+    summary["compile_cache"] = {
+        "dir": cache_dir, "entries_before": cache_before,
+        "entries_written": cache_after - cache_before,
+        "persistent_hits": cache_events["hits"],
+        "persistent_misses": cache_events["misses"],
+    }
+    _phase("compile cache")
+    print(f"  {cache_after - cache_before} entries written to {cache_dir} "
+          f"({cache_before} found at start); persistent-cache hits="
+          f"{cache_events['hits']} misses={cache_events['misses']}",
+          flush=True)
+    if not rehearsal:
+        compile_s = sum(
+            p["telemetry"]["compile_seconds"]
+            for leg in summary["snapshot"].values()
+            for p in leg["passes"].values()
+        ) + summary["replication"]["telemetry"]["compile_seconds"]
+        summary["compile_seconds_legs"] = round(compile_s, 2)
+        summary["compile_cache_was_warm"] = cache_before > 0
+        summary["wall_seconds"] = round(time.perf_counter() - t_start, 1)
+        print(f"  compile seconds inside the legs: {compile_s:.1f} "
+              f"({'warm' if cache_before else 'cold'} cache); wall "
+              f"{summary['wall_seconds']}s", flush=True)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    logging.basicConfig(
+        level=logging.WARNING, stream=sys.stderr,
+        format="%(asctime)s %(levelname).1s %(name)s: %(message)s")
+    for name in _INFO_LOGGERS:
+        logging.getLogger(name).setLevel(logging.INFO)
+    summary: dict = {}
+    checks = Checks()
+    try:
+        run(args, summary, checks)
+    finally:
+        # whatever happened, leave what was learned on disk — this does
+        # not swallow the exception
+        shutil.rmtree(args.data_dir, ignore_errors=True)
+        failed = checks.failed()
+        summary["checks"] = checks.items
+        summary["ok"] = bool(checks.items) and not failed
+        summary["claim"] = None
+        os.makedirs(args.out, exist_ok=True)
+        with open(os.path.join(args.out, "chip_smoke.json"), "w") as fh:
+            json.dump(summary, fh, indent=1, default=str)
+    if failed:
+        print(f"\nFAILED: {len(failed)} of {len(checks.items)} checks",
+              file=sys.stderr)
+        for c in failed:
+            print(f"  {c['name']}: {c['detail']}", file=sys.stderr)
+        return 1
+    if args.rehearsal:
+        print(f"\nrehearsal: all {len(checks.items)} checks passed on the "
+              f"CPU backend — no result", flush=True)
+        return 0
+    b = summary["backend"]
+    print(json.dumps({"ok": True, "device": {
+        "platform": b["platform"], "kind": b["device_kind"],
+        "count": b["device_count"]}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

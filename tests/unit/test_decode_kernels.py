@@ -525,48 +525,26 @@ def test_pipelined_stage_overlaps_launches():
     assert events[2] == ("launch", 256)
 
 
-# -- link re-probe ----------------------------------------------------------
+# -- link probe failure ------------------------------------------------------
 
-def test_degraded_link_reprobes_after_n_reads(monkeypatch):
-    good = linkprobe.LinkProfile(
-        backend="tpu", launch_overhead_s=0.001,
-        h2d_bytes_per_s=1e9, d2h_bytes_per_s=1e9, measured=True)
-    calls = []
+def test_failed_link_probe_on_accelerator_raises(monkeypatch):
+    """No made-up profile: a probe that raises on an accelerator backend
+    surfaces, and nothing is cached for the next caller to trust."""
+    import jax
 
-    def fake_measure(backend):
-        calls.append(backend)
-        return good
+    def broken(backend):
+        raise RuntimeError("device lost")
 
-    monkeypatch.setattr(linkprobe, "_measure", fake_measure)
-    monkeypatch.setenv("TRANSFERIA_TPU_LINK_REPROBE", "3")
+    monkeypatch.setattr(linkprobe, "_measure", broken)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("TRANSFERIA_TPU_LINK", raising=False)
     linkprobe.reset_link_cache()
-    wedged = linkprobe.LinkProfile(
-        backend="tpu", launch_overhead_s=0.1,
-        h2d_bytes_per_s=1e7, d2h_bytes_per_s=1e6,
-        measured=False, degraded=True)
-    linkprobe._cached = wedged
-    assert linkprobe.probe_link() is wedged      # read 1
-    assert linkprobe.probe_link() is wedged      # read 2
-    assert linkprobe.probe_link() is good        # read 3: re-measured
-    assert not calls or calls == ["tpu"]
-    assert linkprobe.probe_link() is good        # stays healthy
-
-
-def test_degraded_link_survives_failed_reprobe(monkeypatch):
-    def still_wedged(backend):
-        raise RuntimeError("wedged")
-
-    monkeypatch.setattr(linkprobe, "_measure", still_wedged)
-    monkeypatch.setenv("TRANSFERIA_TPU_LINK_REPROBE", "2")
-    linkprobe.reset_link_cache()
-    wedged = linkprobe.LinkProfile(
-        backend="tpu", launch_overhead_s=0.1,
-        h2d_bytes_per_s=1e7, d2h_bytes_per_s=1e6,
-        measured=False, degraded=True)
-    linkprobe._cached = wedged
-    for _ in range(5):  # failed re-probes keep the fallback, no raise
-        assert linkprobe.probe_link() is wedged
-    assert "degraded" in wedged.describe()
+    try:
+        with pytest.raises(RuntimeError, match="device lost"):
+            linkprobe.probe_link()
+        assert linkprobe._cached is None
+    finally:
+        linkprobe.reset_link_cache()
 
 
 # -- telemetry + chaos -------------------------------------------------------

@@ -177,7 +177,7 @@ class TestDigestParity:
         from transferia_tpu import native as native_pkg
 
         monkeypatch.setattr(native_pkg, "_lib", None)
-        monkeypatch.setattr(native_pkg, "_tried", True)
+        monkeypatch.setenv("TRANSFERIA_TPU_NO_NATIVE", "1")
         pool2 = _pool([b"one", b"two-longer", b""])  # fresh: no memo
         col2 = _dict_col("s", CanonicalType.UTF8, pool2, codes)
         dict_b2, _ = _batches(col2)
@@ -331,7 +331,7 @@ class TestGatherVarNative:
         from transferia_tpu import native as native_pkg
 
         monkeypatch.setattr(native_pkg, "_lib", None)
-        monkeypatch.setattr(native_pkg, "_tried", True)
+        monkeypatch.setenv("TRANSFERIA_TPU_NO_NATIVE", "1")
         want_d, want_o = _gather_varwidth(data, offsets, idx)
         np.testing.assert_array_equal(got_d, want_d)
         np.testing.assert_array_equal(got_o, want_o)

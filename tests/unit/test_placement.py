@@ -5,7 +5,7 @@ device program and the host path (predicate pushdown + C++ SHA-NI).  The
 auto placement mode measures both on real batches and keeps the winner
 (transform/fused.py); the link profile (ops/linkprobe.py) informs device
 chunk sizing.  No reference analogue: the reference assumes a local
-accelerator; this framework must also run well against tunneled devices.
+accelerator; this framework measures the link it has.
 """
 
 import binascii

@@ -84,7 +84,7 @@ def test_sampler_tags_native_bound_frames():
     """A sample landing while the thread is inside a (marked) native
     call must blame the tagged native symbol, not the caller's Python
     line — the mis-attribution that inflated mask.py:104 with pure C++
-    time in BENCH_r05."""
+    time in a CPU-host headline profile."""
     from transferia_tpu.stats.profiler import NATIVE_TAG, native_call
 
     stop = threading.Event()

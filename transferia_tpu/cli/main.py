@@ -370,7 +370,9 @@ def _setup(args) -> None:
         format="%(asctime)s %(levelname).1s %(name)s: %(message)s",
     )
     from transferia_tpu.runtime import knobs
+    from transferia_tpu.runtime.backend import setup_compile_cache
 
+    setup_compile_cache()  # before the first jit
     if knobs.env_str("TRANSFERIA_TPU_TRACE", "") not in (
             "", "0", "false", "no"):
         # headless span capture: worker processes in a fleet can't be

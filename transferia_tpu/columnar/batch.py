@@ -76,7 +76,7 @@ def _gather_varwidth(data: np.ndarray, offsets: np.ndarray,
         # reading stray memory — same guard as gather_fixed
         if int(idx.min()) < 0 or int(idx.max()) >= len(offsets) - 1:
             cdll = None
-    if cdll is not None and hasattr(cdll, "gather_var_offsets") and n:
+    if cdll is not None and n:
         src_off = np.ascontiguousarray(offsets, dtype=np.int32)
         out_offsets = np.empty(n + 1, dtype=np.int32)
         total = cdll.gather_var_offsets(src_off, idx, n, out_offsets)
@@ -137,7 +137,7 @@ def _gather_fixed(data: np.ndarray, indices: np.ndarray) -> np.ndarray:
     n = len(indices)
     cdll = _native_lib()
     width = data.dtype.itemsize
-    if (cdll is None or not hasattr(cdll, "gather_fixed") or n == 0
+    if (cdll is None or n == 0
             or not data.flags.c_contiguous
             or not isinstance(indices, np.ndarray)
             or indices.dtype.kind not in "iu"):

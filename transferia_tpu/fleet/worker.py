@@ -33,6 +33,7 @@ autoscaler (fleet/autoscaler.py) drives.
 from __future__ import annotations
 
 import logging
+import os
 import subprocess
 import sys
 import threading
@@ -609,6 +610,15 @@ class WorkerSupervisor:
     collects exited workers; `scale_to(n)` spawns or drains toward a
     target; a crashed (not drained) worker is replaced on the next
     `scale_to`/`ensure` because it no longer counts as live.
+
+    One process per chip: a JAX process claims every accelerator its
+    host shows it, so of the `process`-mode children on one host only
+    ONE may come up on the accelerator platform — the first live one.
+    Every other child is started with JAX_PLATFORMS=cpu on purpose (and
+    says so in the log); without that it fails at its first fused plan
+    with "The TPU is already in use by process …".
+    The supervisor itself must stay off JAX, or it holds the chip and
+    no child gets it.
     """
 
     def __init__(self, mode: str = "thread",
@@ -638,6 +648,8 @@ class WorkerSupervisor:
         self._handles: list[_Handle] = []
         self._next_index = 0
         self.spawn_log: list[int] = []
+        # process mode: the child that was left the host's accelerator
+        self._chip_owner: Optional[_Handle] = None
 
     # -- spawn / retire ------------------------------------------------------
     def spawn(self) -> int:
@@ -660,8 +672,23 @@ class WorkerSupervisor:
                                  stop=stop)
                 th.start()
             else:
-                proc = subprocess.Popen(self.spawn_argv(index))
-                handle = _Handle(index, proc=proc)
+                # decide and start under one lock hold: two racing
+                # spawns must not both be left the accelerator
+                with self._lock:
+                    owner = self._chip_owner
+                    env = None
+                    if owner is not None and owner.alive():
+                        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+                        logger.warning(
+                            "supervisor %s: worker %d starts on the CPU "
+                            "platform — worker %d holds this host's "
+                            "accelerator (one process per chip)",
+                            self.name, index, owner.index)
+                    proc = subprocess.Popen(self.spawn_argv(index),
+                                            env=env)
+                    handle = _Handle(index, proc=proc)
+                    if env is None:
+                        self._chip_owner = handle
             with self._lock:
                 self._handles.append(handle)
                 self.spawn_log.append(index)

@@ -1,17 +1,20 @@
 """Native host-ops loader (C++ via ctypes).
 
-`lib()` returns the loaded library or None; callers keep numpy fallbacks.
-The shared object builds once per environment into this package directory
-(`python -m transferia_tpu.native.build`, or lazily on first use when a
-compiler is present).
+The shared object is generated code: it is built from the sources
+committed next to this file into `libhostops-<source hash>.so`
+(`python -m transferia_tpu.native.build`, or on first use), so a library
+on disk always matches the sources beside it — a copied tree has
+arbitrary mtimes, a content hash does not.  A build or load failure
+raises; `lib()` returns None only when TRANSFERIA_TPU_NO_NATIVE=1 asks
+for the numpy paths.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
-
 import pathlib
 import threading
 from typing import Optional
@@ -21,10 +24,26 @@ from transferia_tpu.runtime import knobs
 logger = logging.getLogger(__name__)
 
 _DIR = pathlib.Path(__file__).parent
-_SO = _DIR / "libhostops.so"
+# translation units, then the parts they #include (hashed, not compiled)
+_SOURCES = ("hostops.cpp", "parquetdec.cpp")
+_INCLUDES = ("parquetdec_ba.inc",)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
+_failure: Optional["NativeBuildError"] = None  # the first, re-raised
+
+
+class NativeBuildError(RuntimeError):
+    """The host-ops library could not be built or loaded."""
+
+
+def so_path() -> pathlib.Path:
+    """Where the library for the sources on disk lives (may not exist
+    yet): the name carries a hash of every source file's bytes."""
+    h = hashlib.sha256()
+    for name in _SOURCES + _INCLUDES:
+        h.update(name.encode())
+        h.update((_DIR / name).read_bytes())
+    return _DIR / f"libhostops-{h.hexdigest()[:16]}.so"
 
 
 def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
@@ -34,6 +53,7 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
     u8 = npc.ndpointer(np.uint8, flags="C_CONTIGUOUS")
     i32 = npc.ndpointer(np.int32, flags="C_CONTIGUOUS")
     i64 = npc.ndpointer(np.int64, flags="C_CONTIGUOUS")
+    u32 = npc.ndpointer(np.uint32, flags="C_CONTIGUOUS")
     u64 = npc.ndpointer(np.uint64, flags="C_CONTIGUOUS")
     cdll.leb128_encode.argtypes = [u64, ctypes.c_int64, u8, i32]
     cdll.leb128_encode.restype = ctypes.c_int64
@@ -41,25 +61,20 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
     cdll.scatter_bytes.restype = None
     cdll.gather_varwidth.argtypes = [u8, i32, i64, ctypes.c_int64, u8, i32]
     cdll.gather_varwidth.restype = ctypes.c_int64
-    # two-pass var-width gather is newer than some prebuilt .so files
-    if hasattr(cdll, "gather_var_offsets"):
-        cdll.gather_var_offsets.argtypes = [i32, i64, ctypes.c_int64, i32]
-        cdll.gather_var_offsets.restype = ctypes.c_int64
-        cdll.gather_var_bytes.argtypes = [
-            u8, i32, i64, ctypes.c_int64, i32, u8,
-        ]
-        cdll.gather_var_bytes.restype = None
-    # fixed-width gather is newer than some prebuilt .so files
-    if hasattr(cdll, "gather_fixed"):
-        cdll.gather_fixed.argtypes = [
-            u8, i64, ctypes.c_int64, ctypes.c_int32, u8,
-        ]
-        cdll.gather_fixed.restype = None
+    cdll.gather_var_offsets.argtypes = [i32, i64, ctypes.c_int64, i32]
+    cdll.gather_var_offsets.restype = ctypes.c_int64
+    cdll.gather_var_bytes.argtypes = [
+        u8, i32, i64, ctypes.c_int64, i32, u8,
+    ]
+    cdll.gather_var_bytes.restype = None
+    cdll.gather_fixed.argtypes = [
+        u8, i64, ctypes.c_int64, ctypes.c_int32, u8,
+    ]
+    cdll.gather_fixed.restype = None
     cdll.pack_sha_blocks.argtypes = [
         u8, i32, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32, u8, i32,
     ]
     cdll.pack_sha_blocks.restype = None
-    u32 = npc.ndpointer(np.uint32, flags="C_CONTIGUOUS")
     cdll.hmac_sha256_hex.argtypes = [
         u8, i32, ctypes.c_int64, u32, u32, ctypes.c_void_p, u8,
     ]
@@ -70,107 +85,109 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
         u8, i32, ctypes.c_int64, u32, u32, u32, u32,
     ]
     cdll.polyhash_varcol.restype = None
-    # fused fingerprint lane kernels (newer than some prebuilt .so)
-    if hasattr(cdll, "rowhash_mix_fixed"):
-        cdll.rowhash_mix_fixed.argtypes = [
-            u32, u32, ctypes.c_int64, ctypes.c_uint32, ctypes.c_uint32,
-            u32, u32,
-        ]
-        cdll.rowhash_mix_fixed.restype = None
-        cdll.rowhash_mix_var.argtypes = [
-            u32, u32, ctypes.c_int64, ctypes.c_uint32, ctypes.c_uint32,
-            u32, u32,
-        ]
-        cdll.rowhash_mix_var.restype = None
-        cdll.rowhash_dict_lanes.argtypes = [
-            u32, u32, i32, ctypes.c_int64, ctypes.c_uint32,
-            ctypes.c_uint32, u32, u32,
-        ]
-        cdll.rowhash_dict_lanes.restype = None
-        cdll.rowhash_accum.argtypes = [
-            u32, u32, ctypes.c_int64, u32, u32,
-        ]
-        cdll.rowhash_accum.restype = None
-    if hasattr(cdll, "crc32c_batch"):
-        cdll.crc32c_batch.argtypes = [u8, i64, ctypes.c_int64, u32]
-        cdll.crc32c_batch.restype = None
-    if hasattr(cdll, "kafka_scan_records"):
-        cdll.kafka_scan_records.argtypes = [
-            u8, ctypes.c_int64, i64, ctypes.c_int64,
-        ]
-        cdll.kafka_scan_records.restype = ctypes.c_int64
-    if hasattr(cdll, "avro_decode_flat"):
-        cdll.avro_decode_flat.argtypes = [
-            u8, i64, ctypes.c_int64, u8, u8, u8, ctypes.c_int64, i64,
-        ]
-        cdll.avro_decode_flat.restype = ctypes.c_int64
-    if hasattr(cdll, "crc32c_buf"):
-        cdll.crc32c_buf.argtypes = [u8, ctypes.c_int64, ctypes.c_uint32]
-        cdll.crc32c_buf.restype = ctypes.c_uint32
-        cdll.kafka_encode_records.argtypes = [
-            u8, i64, ctypes.c_void_p, u8, i64, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.c_int64, u8, ctypes.c_int64,
-        ]
-        cdll.kafka_encode_records.restype = ctypes.c_int64
-    # parquet-decoder symbols are OPTIONAL: a prebuilt .so from an older
-    # source must keep serving the ops above rather than failing the load
-    if hasattr(cdll, "pq_decode_fixed"):
-        cdll.pq_decode_fixed.argtypes = [
-            u8, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
-            ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
-            ctypes.c_void_p,
-        ]
-        cdll.pq_decode_fixed.restype = ctypes.c_int64
-        cdll.pq_decode_bytearray.argtypes = [
-            u8, ctypes.c_int64, ctypes.c_int32, ctypes.c_int64,
-            ctypes.c_int32, u8, ctypes.c_int64, i32, ctypes.c_void_p,
-            ctypes.c_void_p, ctypes.POINTER(ctypes.c_int32),
-            ctypes.POINTER(ctypes.c_int64),
-        ]
-        cdll.pq_decode_bytearray.restype = ctypes.c_int64
-    if hasattr(cdll, "pq_decode_rowgroup"):
-        cdll.pq_decode_rowgroup.argtypes = [
-            u8, ctypes.c_int64, i64, ctypes.c_int64,
-        ]
-        cdll.pq_decode_rowgroup.restype = ctypes.c_int64
-        cdll.pq_codec_supported.argtypes = [ctypes.c_int32]
-        cdll.pq_codec_supported.restype = ctypes.c_int32
+    # fused fingerprint lane kernels
+    cdll.rowhash_mix_fixed.argtypes = [
+        u32, u32, ctypes.c_int64, ctypes.c_uint32, ctypes.c_uint32,
+        u32, u32,
+    ]
+    cdll.rowhash_mix_fixed.restype = None
+    cdll.rowhash_mix_var.argtypes = [
+        u32, u32, ctypes.c_int64, ctypes.c_uint32, ctypes.c_uint32,
+        u32, u32,
+    ]
+    cdll.rowhash_mix_var.restype = None
+    cdll.rowhash_dict_lanes.argtypes = [
+        u32, u32, i32, ctypes.c_int64, ctypes.c_uint32,
+        ctypes.c_uint32, u32, u32,
+    ]
+    cdll.rowhash_dict_lanes.restype = None
+    cdll.rowhash_accum.argtypes = [
+        u32, u32, ctypes.c_int64, u32, u32,
+    ]
+    cdll.rowhash_accum.restype = None
+    cdll.crc32c_batch.argtypes = [u8, i64, ctypes.c_int64, u32]
+    cdll.crc32c_batch.restype = None
+    cdll.kafka_scan_records.argtypes = [
+        u8, ctypes.c_int64, i64, ctypes.c_int64,
+    ]
+    cdll.kafka_scan_records.restype = ctypes.c_int64
+    cdll.avro_decode_flat.argtypes = [
+        u8, i64, ctypes.c_int64, u8, u8, u8, ctypes.c_int64, i64,
+    ]
+    cdll.avro_decode_flat.restype = ctypes.c_int64
+    cdll.crc32c_buf.argtypes = [u8, ctypes.c_int64, ctypes.c_uint32]
+    cdll.crc32c_buf.restype = ctypes.c_uint32
+    cdll.kafka_encode_records.argtypes = [
+        u8, i64, ctypes.c_void_p, u8, i64, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_int64, u8, ctypes.c_int64,
+    ]
+    cdll.kafka_encode_records.restype = ctypes.c_int64
+    # parquet decoder (parquetdec.cpp)
+    cdll.pq_decode_fixed.argtypes = [
+        u8, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int64, ctypes.c_int32, ctypes.c_void_p,
+        ctypes.c_void_p,
+    ]
+    cdll.pq_decode_fixed.restype = ctypes.c_int64
+    cdll.pq_decode_bytearray.argtypes = [
+        u8, ctypes.c_int64, ctypes.c_int32, ctypes.c_int64,
+        ctypes.c_int32, u8, ctypes.c_int64, i32, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.POINTER(ctypes.c_int32),
+        ctypes.POINTER(ctypes.c_int64),
+    ]
+    cdll.pq_decode_bytearray.restype = ctypes.c_int64
+    cdll.pq_decode_rowgroup.argtypes = [
+        u8, ctypes.c_int64, i64, ctypes.c_int64,
+    ]
+    cdll.pq_decode_rowgroup.restype = ctypes.c_int64
+    cdll.pq_codec_supported.argtypes = [ctypes.c_int32]
+    cdll.pq_codec_supported.restype = ctypes.c_int32
     return cdll
 
 
-def build(force: bool = False) -> bool:
-    """Compile the shared library; returns True on success."""
+def build(force: bool = False) -> pathlib.Path:
+    """Compile the library for the sources on disk (a no-op when it is
+    already there and `force` is off); returns its path.  Raises
+    NativeBuildError when there is no compiler or the compile fails."""
     import shutil
     import subprocess
 
-    srcs = [_DIR / "hostops.cpp", _DIR / "parquetdec.cpp"]
-    srcs = [s for s in srcs if s.exists()]
-    if not srcs:
-        # sources pruned from the deployment: use a prebuilt .so as-is
-        return _SO.exists()
-    # staleness must consider #included parts too, not just the TUs
-    deps = srcs + [p for p in [_DIR / "parquetdec_ba.inc"] if p.exists()]
-    if (_SO.exists() and not force
-            and _SO.stat().st_mtime >= max(s.stat().st_mtime
-                                           for s in deps)):
-        return True
+    so = so_path()
+    if so.exists() and not force:
+        return so
     cxx = shutil.which("g++") or shutil.which("clang++")
     if cxx is None:
-        # no compiler: a stale-but-working prebuilt .so beats no library
-        return _SO.exists()
+        raise NativeBuildError(
+            "no C++ compiler (g++/clang++) to build the host-ops "
+            "library; set TRANSFERIA_TPU_NO_NATIVE=1 to run the numpy "
+            "paths on purpose")
+    # compile beside the target, then rename: concurrent builders (part
+    # threads are serialized by lib()'s lock, worker PROCESSES are not)
+    # never see a half-written library
+    tmp = so.with_name(f"{so.name}.{os.getpid()}.tmp")
     # NOTE: -march=native was tried and measured SLOWER on the v5e bench
     # box (AVX-512 codegen/downclocking on the byte-wise hot loops);
     # plain -O3 with the runtime SSE4.2/SHA-NI dispatch stays the build
     try:
         subprocess.run(
-            [cxx, "-O3", "-shared", "-fPIC", "-o", str(_SO)]
-            + [str(s) for s in srcs] + ["-ldl"],
-            check=True, capture_output=True, timeout=120,
+            [cxx, "-O3", "-shared", "-fPIC", "-o", str(tmp)]
+            + [str(_DIR / s) for s in _SOURCES] + ["-ldl"],
+            check=True, capture_output=True, timeout=300,
         )
-        return True
-    except (subprocess.CalledProcessError, subprocess.TimeoutExpired) as e:
-        logger.warning("hostops build failed: %s", e)
-        return False
+        os.replace(tmp, so)
+    except subprocess.CalledProcessError as e:
+        raise NativeBuildError(
+            f"host-ops build failed (rc={e.returncode}): "
+            f"{e.stderr.decode(errors='replace')[-2000:]}") from e
+    except subprocess.TimeoutExpired as e:
+        raise NativeBuildError(f"host-ops build timed out: {e}") from e
+    finally:
+        tmp.unlink(missing_ok=True)
+    # libraries of earlier source versions are dead weight
+    for old in _DIR.glob("libhostops*.so"):
+        if old != so:
+            old.unlink(missing_ok=True)
+    return so
 
 
 class _ProfiledLib:
@@ -209,23 +226,28 @@ class _ProfiledLib:
 
 
 def lib() -> Optional[ctypes.CDLL]:
-    """Load (building if needed); None when unavailable."""
-    global _lib, _tried
-    if _lib is not None or _tried:
+    """The loaded library (built first when the one for these sources is
+    missing).  None only under TRANSFERIA_TPU_NO_NATIVE=1; a failed
+    build or load raises NativeBuildError — on every call, so no caller
+    drops to its numpy path without a word."""
+    global _lib, _failure
+    if _lib is not None:
         return _lib
     with _lock:
-        if _lib is not None or _tried:
+        if _lib is not None:
             return _lib
-        _tried = True
         if knobs.env_str("TRANSFERIA_TPU_NO_NATIVE", "") == "1":
             return None
-        if not build():  # no-op when the .so is newer than the source
-            return None
+        if _failure is not None:  # one compile attempt per process
+            raise _failure
         try:
-            _lib = _ProfiledLib(_bind(ctypes.CDLL(str(_SO))))
+            so = build()
+            _lib = _ProfiledLib(_bind(ctypes.CDLL(str(so))))
+        except NativeBuildError as e:
+            _failure = e
+            raise
         except (OSError, AttributeError) as e:
-            # AttributeError: a prebuilt .so from an older source without
-            # the newer symbols — honor the "None when unavailable" contract
-            logger.warning("hostops load failed: %s", e)
-            _lib = None
+            _failure = NativeBuildError(
+                f"host-ops library failed to load: {e}")
+            raise _failure from e
     return _lib

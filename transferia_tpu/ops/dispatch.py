@@ -1,11 +1,10 @@
 """Compressed device dispatch: ship encoded columns, decode on device.
 
-The slow host↔device link is the governing bottleneck of the device
-plane (BENCH_r03/r04: h2d ≈ 21 MB/s, every fused step link-gated to
-`placement=host` while the mask kernel idles at 12-14M rows/s).  The
-fix is not a faster kernel — it is fewer bytes: columns cross the link
-in their compact encodings and the decode kernels in ops/decode.py
-reconstruct them on device, byte-identical to host decode.
+The device plane pays for every byte it moves across the host↔device
+link, both ways, on every batch.  Fewer bytes is the lever a faster
+kernel cannot replace: columns cross the link in their compact
+encodings and the decode kernels in ops/decode.py reconstruct them on
+device, byte-identical to host decode.
 
 Encodings (selection is per column, per batch, host-side):
 

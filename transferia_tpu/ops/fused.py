@@ -70,12 +70,12 @@ def _chunk_rows() -> int:
             from transferia_tpu.ops.linkprobe import probe_link
 
             link = probe_link()
-            if link.backend in ("cpu", "none"):
+            if link.backend == "cpu":
                 _chunk_rows_cached = 0
             elif link.launch_overhead_s > 0.005:
-                # high-latency link (tunneled device): per-chunk launches
-                # cost more than the overlap they buy — one launch per
-                # batch, overlap rides across batches instead
+                # launches this expensive cost more per chunk than the
+                # overlap buys — one launch per batch, overlap rides
+                # across batches instead
                 _chunk_rows_cached = 0
             else:
                 _chunk_rows_cached = 32768
@@ -100,18 +100,12 @@ def _pallas_pack_enabled() -> bool:
     is historical — the implementation is XLA after hardware profiling
     retired the Pallas kernel, see that module's docstring).
 
-    Off by default: it halves H2D bytes for short strings on
-    PCIe-attached devices, but costs an extra launch — through a
-    high-latency tunnel the host C++ pack + padded H2D wins.
+    Off by default: it halves H2D bytes for short strings but costs an
+    extra launch; which side wins on the chip is not measured.
     """
     if knobs.env_str("TRANSFERIA_TPU_PALLAS_PACK", "") != "1":
         return False
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def pow2_blocks(max_len: int) -> int:

@@ -22,10 +22,9 @@ rows on the same chip, i.e. at HBM-bandwidth, with byte parity against
 the C++ host pack.  Hand-scheduling lost to the compiler; keep the
 compiler (it replaced ops/ragged_pallas.py outright).
 
-Opt-in (TRANSFERIA_TPU_PALLAS_PACK=1, historical name): on PCIe-attached
-devices it halves H2D traffic for short strings; through a high-latency
-tunnel the extra launch costs more than the bytes saved, and the
-placement tuner keeps the whole mask on the host anyway.
+Opt-in (TRANSFERIA_TPU_PALLAS_PACK=1, historical name): it halves H2D
+traffic for short strings at the cost of one extra launch per batch.
+Which side wins end to end on the chip is not measured (ROADMAP D3).
 """
 
 from __future__ import annotations

@@ -321,14 +321,11 @@ def _var_accs_host(col: _PreppedColumn,
 
 
 def _lanes_lib():
-    """The native lib iff it carries the fused lane kernels (a prebuilt
-    .so from an older source keeps the numpy chain)."""
+    """The native lib (fused lane kernels), or None when
+    TRANSFERIA_TPU_NO_NATIVE=1 keeps the numpy chain."""
     from transferia_tpu.native import lib as native_lib
 
-    cdll = native_lib()
-    if cdll is not None and hasattr(cdll, "rowhash_mix_fixed"):
-        return cdll
-    return None
+    return native_lib()
 
 
 def _col_lanes_host(col: _PreppedColumn, n_rows: int
@@ -716,6 +713,14 @@ class DeviceFingerprintProgram:
         return agg
 
 
+# The backend model's compute term: rows/s the chip sustains on the
+# fingerprint reduction with data resident (one int64 + one 64-byte
+# var-width column, 1M-row launches).  A v5e ("TPU v5 lite") figure from
+# the builder's own 2026-07 runs, never in a driver record;
+# chip_smoke.py prints the rate it measures beside it.
+DEVICE_FINGERPRINT_ROWS_PER_S = 20e6
+
+
 class TableFingerprinter:
     """Streaming fingerprint over batches, backend chosen by measurement.
 
@@ -744,10 +749,9 @@ class TableFingerprinter:
         jax-on-CPU shares the host cores and adds jit overhead."""
         try:
             import jax
-
-            return jax.default_backend() not in ("cpu",)
-        except Exception:
+        except ImportError:  # host-only install: jax is an extra
             return False
+        return jax.default_backend() != "cpu"
 
     def _choose(self, n_rows: int, row_bytes: int) -> str:
         if self.backend in ("host", "device"):
@@ -764,7 +768,7 @@ class TableFingerprinter:
         link = probe_link()
         pred_s = (2 * link.launch_overhead_s
                   + n_rows * row_bytes / link.h2d_bytes_per_s
-                  + n_rows / 20e6)
+                  + n_rows / DEVICE_FINGERPRINT_ROWS_PER_S)
         pred_ns = pred_s * 1e9 / max(n_rows, 1)
         self._decided = ("device" if pred_ns < self._host_ns_row
                          else "host")

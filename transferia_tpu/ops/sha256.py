@@ -111,9 +111,7 @@ def _bytes_to_words(blocks_u8):
     n, nb = blocks_u8.shape[0], blocks_u8.shape[1]
     u32 = jax.lax.bitcast_convert_type(
         blocks_u8.reshape(n, nb, 16, 4), jnp.uint32
-    )
-    if u32.ndim == 4:  # some backends keep a trailing singleton
-        u32 = u32[..., 0]
+    )  # narrowing->wide bitcast consumes the trailing dim: (N, nb, 16)
     # little-endian load -> big-endian SHA word
     return (((u32 & 0xFF) << 24) | ((u32 & 0xFF00) << 8)
             | ((u32 >> 8) & 0xFF00) | (u32 >> 24))
@@ -233,10 +231,8 @@ def _hmac_key_states(key: bytes) -> tuple[np.ndarray, np.ndarray]:
 
     @jax.jit
     def one_compress(block):
-        # jitted even for this 1-row call: eager lax execution of the
-        # compression degrades subsequent dispatch latency on some remote
-        # TPU runtimes (observed ~0.03ms -> ~72ms per dispatch after one
-        # eager run)
+        # jitted even for this 1-row call: op-by-op execution would
+        # compile each lax primitive of the 64 rounds separately
         words = _bytes_to_words(block.reshape(1, 1, 64))
         h = jnp.broadcast_to(jnp.asarray(_H0), (1, 8))
         return _compress_batch(h, words[:, 0])

@@ -32,6 +32,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from transferia_tpu.columnar.batch import bucket_rows
@@ -43,19 +44,6 @@ from transferia_tpu.ops.fused import (
 from transferia_tpu.ops.sha256 import _hmac_key_states, hmac_device_core
 from transferia_tpu.stats import stagetimer, trace
 from transferia_tpu.stats.trace import TELEMETRY
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map as sm
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as sm
-    try:  # jax >= 0.8 renamed check_rep -> check_vma
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 def default_mesh(devices=None) -> Mesh:
@@ -112,9 +100,25 @@ class ShardedFusedProgram:
     and the digest shard histogram (`last_kept`, `last_shard_hist`).
     """
 
+    # jitted programs shared across instances, like
+    # FusedMaskFilterProgram._jit_cache and for the same reason: the
+    # snapshot loader builds one chain per part, and a per-instance jit
+    # made every part of a multi-chip snapshot pay a full XLA compile.
+    # Keyed by everything the traced program closes over (predicate AST
+    # repr, mesh devices and shape, shard count) plus the per-call
+    # statics; the HMAC key states are traced ARGUMENTS.  Bounded FIFO.
+    _jit_cache: dict = {}
+    _JIT_CACHE_MAX = 64
+
     def __init__(self, mask_keys: Sequence[bytes], pred_node,
                  mesh: Optional[Mesh] = None, n_shards: int = 16):
         self.mesh = mesh or default_mesh()
+        self._program_key = (
+            repr(pred_node),
+            tuple(d.id for d in self.mesh.devices.flat),
+            tuple(self.mesh.shape.items()),
+            n_shards,
+        )
         self.n_dev = int(np.prod(list(self.mesh.shape.values())))
         self.n_shards = n_shards
         self._states = []
@@ -212,8 +216,15 @@ class ShardedFusedProgram:
         n_mask = len(routes)
         n_flat = sum(1 for r in routes if r == "flat")
         n_dict = n_mask - n_flat
+        shared = ShardedFusedProgram._jit_cache
         with self._lock:
             fn = self._compiled.get(key)
+            if fn is None:
+                # an equal (predicate, mesh, shard count) traces to an
+                # identical program, so another instance's jit is ours
+                fn = shared.get(self._program_key + key)
+                if fn is not None:
+                    self._compiled[key] = fn
             if fn is None:
                 row_axes = tuple(self.mesh.axis_names)
                 rows = P(row_axes)
@@ -244,20 +255,24 @@ class ShardedFusedProgram:
                 def wrapper(blocks_t, nblocks_t, states_t, codes_t,
                             digs_t, pred_arrays, valid_arr,
                             max_blocks_t, bucket):
-                    body = _shard_map(
+                    body = shard_map(
                         lambda b, nb, st, cd, dg, pa, v:
                         self._per_device(
                             b, nb, st, cd, dg, pa, v, max_blocks_t,
                             pred_specs, valid_mode, bucket, routes),
-                        self.mesh,
-                        in_specs,
-                        out_specs,
+                        mesh=self.mesh,
+                        in_specs=in_specs,
+                        out_specs=out_specs,
+                        check_vma=False,
                     )
                     return body(blocks_t, nblocks_t, states_t, codes_t,
                                 digs_t, pred_arrays, valid_arr)
 
                 fn = jax.jit(wrapper, static_argnums=(7, 8))
                 self._compiled[key] = fn
+                while len(shared) >= ShardedFusedProgram._JIT_CACHE_MAX:
+                    shared.pop(next(iter(shared)), None)
+                shared[self._program_key + key] = fn
         return fn
 
     def run(self, mask_cols: Sequence,

@@ -25,6 +25,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from transferia_tpu.ops.sha256 import (
@@ -84,11 +85,6 @@ def sharded_transform_step(mesh: Mesh, max_blocks: int = 2,
     histogram psum crosses 'data' so every device sees global shard counts
     (what a sharded CH writer needs to balance inserts).
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     inner_np, outer_np = _hmac_key_states(key)
     inner = jnp.asarray(inner_np[0])
     outer = jnp.asarray(outer_np[0])
@@ -117,12 +113,8 @@ def sharded_transform_step(mesh: Mesh, max_blocks: int = 2,
         P(),                        # histogram (fully replicated)
         P(),                        # total kept
     )
-    try:  # jax >= 0.8 renamed check_rep -> check_vma
-        fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
-    except TypeError:
-        fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
+    fn = shard_map(per_device, mesh=mesh, in_specs=in_specs,
+                   out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 

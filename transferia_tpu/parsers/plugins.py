@@ -446,7 +446,7 @@ class ConfluentSRParser(Parser):
         from transferia_tpu.native import lib as native_lib
 
         cdll = native_lib()
-        if cdll is None or not hasattr(cdll, "avro_decode_flat"):
+        if cdll is None:
             return None
         spec = self._flat_spec(avro)
         if spec is None:

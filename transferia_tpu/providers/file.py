@@ -421,7 +421,7 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         failpoint("storage.file.open")
         # footer metadata memoizes per (path, mtime, size): a multi-part
         # load re-opens the same file once per part, and the thrift
-        # footer parse was 3.9% of the BENCH_r05 profile
+        # footer parse was 3.9% of a CPU-host headline profile
         pf = parquet_file_cached(path)
         groups = self._prune_row_groups(pf, list(range(lo, hi)), tid)
         from transferia_tpu.stats import trace

@@ -44,26 +44,20 @@ except ImportError:
     def crc32c(data: bytes) -> int:
         global _crc_impl
         if _crc_impl is None:
-            _crc_impl = _crc_py
-            try:
-                from transferia_tpu.native import lib as _native_lib
+            from transferia_tpu.native import lib as _native_lib
 
-                cdll = _native_lib()
-                if cdll is not None and hasattr(cdll, "crc32c_buf"):
-                    import numpy as _np
+            cdll = _native_lib()
+            if cdll is None:  # TRANSFERIA_TPU_NO_NATIVE=1
+                _crc_impl = _crc_py
+            else:
+                import numpy as _np
 
-                    def _crc_native(data: bytes,
-                                    _c=cdll.crc32c_buf, _np=_np) -> int:
-                        return int(_c(_np.frombuffer(data, _np.uint8),
-                                      len(data), 0))
+                def _crc_native(data: bytes,
+                                _c=cdll.crc32c_buf, _np=_np) -> int:
+                    return int(_c(_np.frombuffer(data, _np.uint8),
+                                  len(data), 0))
 
-                    _crc_impl = _crc_native
-            except Exception as e:  # pragma: no cover - python fallback
-                import logging
-
-                logging.getLogger(__name__).debug(
-                    "native crc32c unavailable (%s); using python "
-                    "fallback", e)
+                _crc_impl = _crc_native
         return _crc_impl(data)
 
 
@@ -172,13 +166,10 @@ def _encode_records_native(records: list[Record], now: int,
                            base_ts: int) -> Optional[bytes]:
     """Record section via the C encoder (hostops.cpp); None when out of
     envelope (per-record headers) or the native lib is absent."""
-    try:
-        from transferia_tpu.native import lib as native_lib
+    from transferia_tpu.native import lib as native_lib
 
-        cdll = native_lib()
-    except Exception:  # pragma: no cover
-        return None
-    if cdll is None or not hasattr(cdll, "kafka_encode_records"):
+    cdll = native_lib()
+    if cdll is None:
         return None
     if any(r.headers for r in records):
         return None
@@ -311,13 +302,10 @@ def _finish_record_batch(records: list[Record], recs: bytes,
 def _scan_records_native(data: bytes) -> Optional[list[Record]]:
     """C fast path (hostops.cpp kafka_scan_records): zero-copy scan of
     uncompressed, header-less frames; None defers to the Python walk."""
-    try:
-        from transferia_tpu.native import lib as native_lib
+    from transferia_tpu.native import lib as native_lib
 
-        cdll = native_lib()
-    except Exception:  # pragma: no cover
-        return None
-    if cdll is None or not hasattr(cdll, "kafka_scan_records"):
+    cdll = native_lib()
+    if cdll is None:
         return None
     import numpy as np
 

@@ -252,7 +252,7 @@ class KafkaSinker(Sinker, StagedSinker):
 
         cdll = native_lib()
         keys = [bytes(k or b"") for k, _ in pairs]
-        if cdll is not None and hasattr(cdll, "crc32c_batch"):
+        if cdll is not None:
             data = np.frombuffer(b"".join(keys), dtype=np.uint8)
             offs = np.zeros(len(keys) + 1, dtype=np.int64)
             np.cumsum([len(k) for k in keys], out=offs[1:])

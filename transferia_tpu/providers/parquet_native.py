@@ -43,9 +43,9 @@ logger = logging.getLogger(__name__)
 #
 # Multi-part loads open the SAME file once per part: sharding reads the
 # footer to enumerate row groups, then every part re-runs
-# `ParquetFile.__init__` (a full thrift footer parse — 3.9% of the
-# BENCH_r05 profile) and every NativeParquetReader re-creates the file
-# memmap (1.6%).  Both are pure functions of (path, mtime_ns, size), so
+# `ParquetFile.__init__` (a full thrift footer parse — 3.9% of a
+# CPU-host headline profile) and every NativeParquetReader re-creates
+# the file memmap (1.6%).  Both are pure functions of (path, mtime_ns, size), so
 # they memoize under that key; a rewritten file gets a fresh entry.
 # Bounded FIFO; the lock guards the loader's concurrent part threads.
 
@@ -239,7 +239,7 @@ class NativeParquetReader:
         if knobs.env_str("TRANSFERIA_TPU_NATIVE_PARQUET", "1") == "0":
             return None
         cdll = native_lib()
-        if cdll is None or not hasattr(cdll, "pq_decode_rowgroup"):
+        if cdll is None:
             return None
         if pf.metadata.num_row_groups == 0:
             return None

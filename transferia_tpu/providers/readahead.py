@@ -4,7 +4,7 @@ pipeline.
 The snapshot hot loop was strictly serial per part: decode row group g
 to completion, push its batches through filter -> transform -> sink,
 only then touch g+1 — so host decode never overlapped device dispatch
-or sink I/O (r05 profile: `source_decode` 68% of wall).  The decode
+or sink I/O (a CPU-host profile: `source_decode` 68% of wall).  The decode
 calls release the GIL (ctypes into the C++ chunk decoder, arrow C++
 reads), so a single background thread decoding g+1 while g's batches
 flow downstream buys genuine overlap without processes.

@@ -57,8 +57,8 @@ def _qualname(code) -> str:
 # A ctypes call into the C++ hostops kernels creates no Python frame:
 # a sample landing mid-kernel shows the CALLER's line, so profiles
 # silently inflated Python lines that were really C++ time (e.g.
-# `_native_hmac_hex (mask.py:104)` at 22.5% of BENCH_r05 was almost
-# entirely inside hmac_sha256_hex).  The native bindings
+# `_native_hmac_hex (mask.py:104)` at 22.5% of a CPU-host headline
+# profile was almost entirely inside hmac_sha256_hex).  The native bindings
 # (native/__init__.py) publish "thread T is inside native symbol S"
 # around every exported call; the sampler reads the marker and tags
 # the sample explicitly instead of blaming the Python line.

@@ -668,8 +668,7 @@ class DeviceTelemetry:
     The sampling profiler cannot see any of these: device waits look
     like idle, H2D/D2H time hides inside jnp.asarray/np.asarray calls,
     and a jit recompile inside a measured window silently poisons it.
-    On the measured v5e link (~70 ms/launch, 5-40 MB/s D2H) these
-    counters ARE the performance model's inputs.
+    These counters are the placement model's inputs.
     """
 
     def __init__(self):
@@ -708,6 +707,14 @@ class DeviceTelemetry:
             # has a leak in a code-aware fast path
             self.lazy_dict_preserved = 0
             self.dict_flat_materializations = 0
+            # rows per mask route inside the DEVICE strategy
+            # (transform/fused.py _apply_device): per-row SHA blocks
+            # hashed on the chip, codes rebound to a chip-hashed pool,
+            # or the referenced pool subset hashed on the HOST — a
+            # "device" pass whose rows all took the last route left the
+            # chip nothing but the predicate
+            self.mask_route_rows = {"device_flat": 0, "device_pool": 0,
+                                    "host_subset": 0}
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -781,6 +788,10 @@ class DeviceTelemetry:
         with self._lock:
             self.dict_flat_materializations += 1
 
+    def record_mask_route(self, route: str, n_rows: int) -> None:
+        with self._lock:
+            self.mask_route_rows[route] += int(n_rows)
+
     def record_kernel(self, seconds: float) -> None:
         _ledger().add(kernel_seconds=seconds)
         with self._lock:
@@ -816,6 +827,8 @@ class DeviceTelemetry:
                 "lazy_dict_preserved": self.lazy_dict_preserved,
                 "dict_flat_materializations":
                     self.dict_flat_materializations,
+                **{f"mask_rows_{route}": n
+                   for route, n in self.mask_route_rows.items()},
             }
 
     def fold_into(self, metrics) -> None:

@@ -13,8 +13,8 @@ Registered callables are referenced by dotted path or passed directly via
 `register_lambda`.
 
 Two schedule-level protections make user jit functions safe in streaming
-replication (where batch sizes are ragged and the accelerator may sit
-behind a high-latency tunneled link — see ops/linkprobe.py):
+replication (where batch sizes are ragged and every poll pays the
+host↔device link — see ops/linkprobe.py):
 
   - shape bucketing (columns/mask modes): inputs pad to the next
     power-of-2 row count before the call and outputs slice back, so a
@@ -26,7 +26,7 @@ behind a high-latency tunneled link — see ops/linkprobe.py):
   - link-aware placement (same policy as the fused mask/filter step):
     the fn runs on the host CPU backend or the accelerator, whichever
     measures faster per row, with the accelerator probe gated by the
-    link model so a ~70ms-RTT tunneled device never eats a probe batch.
+    link model so a link that cannot win never eats a probe batch.
     TRANSFERIA_TPU_PLACEMENT=device|host pins it.
 """
 
@@ -123,15 +123,17 @@ class LambdaTransformer(Transformer):
     # -- placement + bucketing ------------------------------------------------
     def _predict_device_ns_row(self, n_rows: int, in_bytes: int) -> float:
         """Link-model estimate: two syncs plus moving the input columns
-        over and a similar volume back (cheap next to a local chip,
-        ruinous through a tunneled link)."""
+        over and a similar volume back; compute is charged at the
+        fused step's DEVICE_MASK_ROWS_PER_S (a v5e figure — a user fn
+        is usually cheaper than the HMAC it was measured on)."""
         from transferia_tpu.ops.linkprobe import probe_link
+        from transferia_tpu.transform.fused import DEVICE_MASK_ROWS_PER_S
 
         link = probe_link()
         s = (2 * link.launch_overhead_s
              + in_bytes / link.h2d_bytes_per_s
              + in_bytes / link.d2h_bytes_per_s
-             + n_rows / 10e6)
+             + n_rows / DEVICE_MASK_ROWS_PER_S)
         return s * 1e9 / max(n_rows, 1)
 
     def _pick_strategy(self, n_rows: int, in_bytes: int) -> str:
