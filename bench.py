@@ -54,10 +54,19 @@ def _auto_process_count() -> int:
     return max(1, min(4, int(_effective_cpus())))
 
 
+def _part_path(path: str, i: int) -> str:
+    return os.path.join(path, f"part-{i:05d}.parquet")
+
+
 def generate_dataset(path: str = PARQUET, rows: int = ROWS,
-                     batch_rows: int = BATCH_ROWS, seed: int = 42) -> None:
+                     batch_rows: int = BATCH_ROWS, seed: int = 42,
+                     max_file_rows: Optional[int] = None) -> None:
     """The 10-column table: URLs are near-unique (~10M distinct paths),
-    so a masked URL crosses the link as per-row SHA blocks."""
+    so a masked URL crosses the link as per-row SHA blocks.
+
+    With `max_file_rows`, `path` is a directory of part files of at most
+    that many rows each (the same rows, in the same order) — for a
+    machine that limits the size of one file."""
     import pyarrow as pa
     import pyarrow.parquet as pq
 
@@ -109,8 +118,15 @@ def generate_dataset(path: str = PARQUET, rows: int = ROWS,
         "Title": pa.array(titles.tolist(), type=pa.string()),
         "SearchPhrase": pa.array(phrases.tolist(), type=pa.string()),
     })
-    pq.write_table(table, path, row_group_size=batch_rows,
-                   compression="snappy")
+    if max_file_rows:
+        os.makedirs(path)
+        for i, lo in enumerate(range(0, n, max_file_rows)):
+            pq.write_table(table.slice(lo, max_file_rows),
+                           _part_path(path, i), row_group_size=batch_rows,
+                           compression="snappy")
+    else:
+        pq.write_table(table, path, row_group_size=batch_rows,
+                       compression="snappy")
     # ground truth for the bench's completeness check: rows the transfer
     # chain keeps (make_transfer's filter) — catches silent row loss in
     # pushdown/transform regardless of where rows get dropped
@@ -181,12 +197,17 @@ def _string_pool(rng, n: int, prefix: str, lo: int, hi: int) -> "object":
 def generate_wide_dataset(path: str = WIDE_PARQUET,
                           rows: int = WIDE_ROWS,
                           batch_rows: int = BATCH_ROWS,
-                          seed: int = 7) -> None:
+                          seed: int = 7,
+                          max_file_rows: Optional[int] = None) -> None:
     """ClickBench-shaped wide dataset: ~70 cols, `rows` rows, written
     chunk-at-a-time so generation stays inside a few hundred MB of RAM.
     Strings sample from pools (URLs/titles repeat in real weblogs); the
     two filter columns keep the 10-col set's predicate semantics so the
-    same transfer spec drives both datasets."""
+    same transfer spec drives both datasets.
+
+    With `max_file_rows`, `path` is a directory of part files of at most
+    that many rows each, rolled between chunks: at 500,000 rows or more
+    per file the rows are those of the one-file table."""
     import pyarrow as pa
     import pyarrow.parquet as pq
 
@@ -221,10 +242,15 @@ def generate_wide_dataset(path: str = WIDE_PARQUET,
 
     writer = None
     kept = 0
-    chunk = 500_000
+    chunk = min(500_000, max_file_rows or 500_000)
+    n_files = file_rows = 0
     try:
         for lo in range(0, rows, chunk):
             n = min(chunk, rows - lo)
+            if max_file_rows and writer is not None \
+                    and file_rows + n > max_file_rows:
+                writer.close()
+                writer = None
             cols: dict[str, object] = {}
             for name, dt, bound in _WIDE_NUM_COLS:
                 if name == "ResolutionWidth":
@@ -258,9 +284,16 @@ def generate_wide_dataset(path: str = WIDE_PARQUET,
                          & (cols["ResolutionWidth"] >= 390)).sum())
             tbl = pa.table(cols)
             if writer is None:
-                writer = pq.ParquetWriter(path, tbl.schema,
+                out = path
+                if max_file_rows:
+                    os.makedirs(path, exist_ok=True)
+                    out = _part_path(path, n_files)
+                writer = pq.ParquetWriter(out, tbl.schema,
                                           compression="snappy")
+                n_files += 1
+                file_rows = 0
             writer.write_table(tbl, row_group_size=batch_rows)
+            file_rows += n
     finally:
         if writer is not None:
             writer.close()

@@ -7,7 +7,8 @@ points a user calls and checks what comes out:
                  `fs` parquet source -> mask_field(URL) + filter_rows ->
                  memory sink, once per placement (host = the reference,
                  device, device again, auto), on the ClickBench-shaped
-                 73-column table and on the 10-column table.  At these
+                 73-column table and on the 10-column table, each a
+                 directory of four-row-group part files.  At these
                  sizes parquet's writer gives up on a dictionary for the
                  URL column, so URLs cross the link as per-row SHA
                  blocks on both; the wide leg also masks SearchPhrase
@@ -42,6 +43,7 @@ import hmac
 import json
 import logging
 import os
+import resource
 import shutil
 import sys
 import threading
@@ -121,25 +123,33 @@ def _cache_entries(path: str) -> int:
 # -- data ----------------------------------------------------------------------
 
 def generate(args, data_dir: str) -> dict:
+    """Both tables as directories of part files of at most
+    `args.file_rows` rows: the machine that checks this script limits the
+    size of one file (the wide table is 1.6 GB), and a directory of parts
+    is what the `fs` source is pointed at in deployment anyway."""
     import bench
 
-    wide = os.path.join(data_dir, f"hits_wide_{args.rows}.parquet")
-    ten = os.path.join(data_dir, f"hits_{args.rows10}.parquet")
+    wide = os.path.join(data_dir, f"hits_wide_{args.rows}")
+    ten = os.path.join(data_dir, f"hits_{args.rows10}")
     t0 = time.perf_counter()
     bench.generate_wide_dataset(wide, args.rows, args.batch_rows,
-                                seed=args.seed)
+                                seed=args.seed, max_file_rows=args.file_rows)
     bench.generate_dataset(ten, args.rows10, args.batch_rows,
-                           seed=args.seed + 1)
+                           seed=args.seed + 1, max_file_rows=args.file_rows)
     import pyarrow.parquet as pq
 
     out = {"seconds": round(time.perf_counter() - t0, 2), "tables": {}}
     for name, path in (("wide", wide), ("ten", ten)):
-        meta = pq.ParquetFile(path).metadata
+        files = sorted(os.path.join(path, f) for f in os.listdir(path))
+        metas = [pq.ParquetFile(f).metadata for f in files]
+        sizes = [os.path.getsize(f) for f in files]
         out["tables"][name] = {
-            "path": path, "rows": meta.num_rows,
-            "columns": meta.num_columns,
-            "row_groups": meta.num_row_groups,
-            "file_mb": round(os.path.getsize(path) / 1e6, 1),
+            "path": path, "files": len(files),
+            "rows": sum(m.num_rows for m in metas),
+            "columns": metas[0].num_columns,
+            "row_groups": sum(m.num_row_groups for m in metas),
+            "file_mb": round(sum(sizes) / 1e6, 1),
+            "largest_file_mb": round(max(sizes) / 1e6, 1),
             "expected_kept": bench.expected_kept(path),
         }
     return out
@@ -155,17 +165,20 @@ def _transformation(name: str) -> dict:
 
 
 def _write_transfer_yaml(path: str, transfer_id: str, name: str,
-                         parquet: str, batch_rows: int,
-                         process_count: int) -> None:
+                         parquet: str, args) -> None:
     doc = {
         "id": transfer_id,
         "type": "SNAPSHOT_ONLY",
+        # one part per file: left to itself the source cuts files this
+        # small into one-group parts, and a fused step that sees a single
+        # batch never gets to weigh the device under `auto`
         "src": {"type": "fs", "params": {
             "path": parquet, "format": "parquet", "table": "hits",
-            "batch_rows": batch_rows}},
+            "batch_rows": args.batch_rows,
+            "rowgroups_per_part": args.file_rows // args.batch_rows}},
         "dst": {"type": "memory", "params": {"sink_id": transfer_id}},
         "transformation": _transformation(name),
-        "runtime": {"process_count": process_count},
+        "runtime": {"process_count": args.process_count},
     }
     import yaml
 
@@ -214,8 +227,7 @@ def snapshot_pass(table: dict, name: str, placement: str, tag: str,
 
     transfer_id = f"smoke-{name}-{tag}"
     yaml_path = os.path.join(work_dir, f"{transfer_id}.yaml")
-    _write_transfer_yaml(yaml_path, transfer_id, name, table["path"],
-                         args.batch_rows, args.process_count)
+    _write_transfer_yaml(yaml_path, transfer_id, name, table["path"], args)
     store = get_store(transfer_id)
     store.clear()
     # dict pools are shared for the process — per decoded dict page and
@@ -257,7 +269,8 @@ def snapshot_pass(table: dict, name: str, placement: str, tag: str,
 def snapshot_leg(name: str, table: dict, args, work_dir: str,
                  checks: Checks, rehearsal: bool) -> dict:
     _phase(f"snapshot leg: {name} table, {table['rows']} rows x "
-           f"{table['columns']} cols, {table['row_groups']} row groups")
+           f"{table['columns']} cols, {table['row_groups']} row groups "
+           f"in {table['files']} files")
     import pyarrow.parquet as pq
 
     placement_log = _PlacementLog()
@@ -580,6 +593,9 @@ def parse_args(argv=None):
     # 16,384-row groups keep ~11k rows after scan pushdown: enough to
     # take the mesh route when the rehearsal sees 8 virtual CPU devices
     args.batch_rows = args.batch_rows or (16_384 if small else 131_072)
+    # four row groups to a part file (wide: ~80 MB); two in the rehearsal,
+    # so that it reads more than one file too
+    args.file_rows = args.batch_rows * (2 if small else 4)
     args.waves = ((300, 1700, 60, 900) if small
                   else (3_000, 17_000, 600, 40_000))
     return args
@@ -620,6 +636,11 @@ def run(args, summary: dict, checks: Checks) -> None:
               "and none is meant; there is no result line", flush=True)
     print(f"  compile cache: {cache_dir} ({cache_before} entries)",
           flush=True)
+    fsize = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    summary["file_size_limit_bytes"] = (
+        None if fsize == resource.RLIM_INFINITY else fsize)
+    print(f"  file size limit: {summary['file_size_limit_bytes']} bytes; "
+          f"data part files hold {args.file_rows} rows at most", flush=True)
 
     _phase("native library: forced build from the tracked sources")
     from transferia_tpu import native
@@ -638,7 +659,8 @@ def run(args, summary: dict, checks: Checks) -> None:
     summary["data"] = data
     for name, t in data["tables"].items():
         print(f"  {name}: {t['rows']} rows x {t['columns']} cols, "
-              f"{t['row_groups']} row groups, {t['file_mb']} MB, "
+              f"{t['row_groups']} row groups in {t['files']} files, "
+              f"{t['file_mb']} MB (largest file {t['largest_file_mb']} MB), "
               f"expected_kept={t['expected_kept']}", flush=True)
     checks.check("data: wide table is 73 columns wide",
                  data["tables"]["wide"]["columns"] == 73)

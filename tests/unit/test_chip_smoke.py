@@ -11,12 +11,22 @@ from __future__ import annotations
 
 import json
 import os
+import resource
 import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 SCRIPT = os.path.join(ROOT, "chip_smoke.py")
+
+
+# the rehearsal's 73-column table is 10.8 MB: one file of it would not fit
+FILE_SIZE_LIMIT = 8 << 20
+
+
+def _limit_file_size():
+    resource.setrlimit(resource.RLIMIT_FSIZE,
+                       (FILE_SIZE_LIMIT, FILE_SIZE_LIMIT))
 
 
 def _run(args, tmp_path, **env):
@@ -27,7 +37,7 @@ def _run(args, tmp_path, **env):
         [sys.executable, SCRIPT, "--out", str(tmp_path / "out"),
          "--data-dir", str(tmp_path / "data"), *args],
         capture_output=True, text=True, timeout=600, env=child_env,
-        cwd=str(tmp_path))
+        cwd=str(tmp_path), preexec_fn=_limit_file_size)
 
 
 def _result_lines(stdout: str) -> list[str]:
@@ -65,6 +75,12 @@ def test_rehearsal_drives_every_leg_and_prints_no_result(tmp_path):
     assert dev["telemetry"]["device_launches"] > 0
     assert summary["replication"]["landed"] == \
         summary["replication"]["produced"] > 0
+    # the machine that checks the script limits the size of one file:
+    # both tables are directories of part files, read as one table
+    assert summary["file_size_limit_bytes"] == FILE_SIZE_LIMIT
+    for table in summary["data"]["tables"].values():
+        assert table["files"] > 1
+        assert table["largest_file_mb"] * 1e6 < FILE_SIZE_LIMIT
     # the cache went where the variable said, and nowhere else
     assert not (tmp_path / ".jax_cache").exists()
     assert summary["compile_cache"]["dir"] == str(tmp_path / "jaxcache")

@@ -136,9 +136,10 @@ def probe_stream_link(force: bool = False) -> StreamProfile:
     """The process-wide substream profile (measured once, cached).
 
     A DEGRADED profile (probe failed) re-measures after every
-    TRANSFERIA_TPU_STREAM_REPROBE reads (default 256), same contract
-    as `ops/linkprobe.probe_link` — a transiently wedged allocator
-    must not pin single-stream puts forever."""
+    TRANSFERIA_TPU_STREAM_REPROBE reads (default 256) — a transiently
+    wedged allocator must not pin single-stream puts forever.  (This
+    prices a network wire; the chip's link, `ops/linkprobe.probe_link`,
+    has no such fallback: a failed probe there is an error.)"""
     global _cached, _degraded_reads
     if _cached is not None and not force:
         if not _cached.degraded:
