@@ -815,7 +815,7 @@ def measure_kafka2ch(n_partitions: int = 16,
     from transferia_tpu.providers.kafka.client import KafkaClient, Record
     from transferia_tpu.providers.kafka.provider import KafkaSourceParams
     from transferia_tpu.runtime.local import run_replication
-    from transferia_tpu.stats import stagetimer
+    from transferia_tpu.stats import trace
 
     srv = FakeKafka(n_partitions=n_partitions).start()
     ch = FakeCH().start()
@@ -854,8 +854,10 @@ def measure_kafka2ch(n_partitions: int = 16,
         expected = sum(1 for _ in range(n_partitions)
                        for i in range(msgs_per_partition)
                        if i % 500 < 400)
-        stagetimer.collect_samples("transform")
-        stagetimer.reset()
+        # per-batch transform latency: the `transform` spans' durations
+        was_tracing = trace.enabled()
+        trace.reset()
+        trace.enable(True)
         stop = threading.Event()
         th = threading.Thread(
             target=run_replication, args=(t, cp),
@@ -874,7 +876,9 @@ def measure_kafka2ch(n_partitions: int = 16,
         stop.set()
         th.join(timeout=10)
         rows = ch_rows()
-        lat = sorted(stagetimer.samples("transform"))
+        trace.enable(was_tracing)
+        lat = sorted(s[4] for s in trace.spans()
+                     if s[0] == "transform" and s[6] >= 0)
         out = {
             "metric": "kafka2ch_transform_p99_ms",
             "unit": "ms",
@@ -1764,7 +1768,7 @@ def main() -> int:
         require_tpu,
         setup_compile_cache,
     )
-    from transferia_tpu.stats import stagetimer
+    from transferia_tpu.stats import trace as _trace
 
     against, candidate, tolerance = _against_args()
     if against and candidate:
@@ -1932,16 +1936,13 @@ def main() -> int:
     parquet_native.reset_fallback_stats()
     readahead.reset_stats()
     trace_out = _trace_out_path()
-    if trace_out:
-        from transferia_tpu.stats import trace as _trace
-
-        _trace.reset()
-        _trace.enable(True)
-    stagetimer.enable(True)
-    stagetimer.reset()
+    # the stage line and the exported trace are one recording
+    _trace.reset()
+    _trace.enable(True)
     with cpu_profile() as prof:
         rows, dt = run_pipeline(parquet=WIDE_PARQUET, total_rows=WIDE_ROWS)
-    stage_note = stagetimer.format_breakdown(dt)
+    _trace.enable(False)
+    stage_note = _trace.format_summary(dt, one_line=True)
     ra = readahead.snapshot_stats()
     if ra["prefetched_groups"]:
         # queue-depth evidence that decode overlapped downstream work —
@@ -1953,9 +1954,6 @@ def main() -> int:
             f" readahead_inflight_mb_max="
             f"{ra['max_inflight_bytes'] / 1e6:.0f}")
     if trace_out:
-        from transferia_tpu.stats import trace as _trace
-
-        _trace.enable(False)
         n_events = _trace.write_chrome_trace(trace_out)
         print(f"# trace: {n_events} events -> {trace_out}",
               file=sys.stderr)
@@ -1965,7 +1963,6 @@ def main() -> int:
     rps = rows / dt
     # second shape: the 10-col near-unique-URL dataset (own warmup so its
     # differently-shaped programs never compile inside the timed window)
-    stagetimer.enable(False)
     run_pipeline(limit_rows=BATCH_ROWS * 2)
     rows10, dt10 = run_pipeline()
     latencies = measure_transform_latency()

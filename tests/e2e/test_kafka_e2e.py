@@ -75,6 +75,29 @@ def test_client_produce_fetch(broker):
     client.close()
 
 
+def test_kafka_roundtrip_span_tells_the_wait_from_the_read(broker):
+    from transferia_tpu.stats import trace
+
+    client = KafkaClient([f"127.0.0.1:{broker.port}"])
+    client.produce("t2", 0, [Record(key=b"a", value=b"x" * 500)])
+    trace.reset()
+    trace.enable(True)
+    try:
+        records, _high = client.fetch("t2", 0, 0)
+        rec = [s for s in trace.spans() if s[0] == "kafka_roundtrip"]
+    finally:
+        trace.enable(False)
+        trace.reset()
+        client.close()
+    assert len(records) == 1
+    fetch = rec[-1]
+    # up to the first response byte (the broker's long poll), and what
+    # the response carried
+    assert 0 <= fetch[7]["wait_s"] <= fetch[4]
+    assert fetch[7]["bytes"] > 500
+    assert all({"api", "wait_s", "bytes"} <= set(s[7]) for s in rec)
+
+
 def test_kafka_replication_to_memory(broker):
     client = KafkaClient([f"127.0.0.1:{broker.port}"])
     for i in range(100):

@@ -143,3 +143,42 @@ def test_self_compare_of_committed_artifact_passes():
     assert len(metrics) == 4, "the fixture carries four metric lines"
     regs, _ = bench.compare_against(metrics, metrics)
     assert regs == []
+
+
+# -- the stages line (trace.format_summary's one-line mode) ------------------
+
+def test_stage_note_is_self_time_share_of_wall_largest_first(monkeypatch):
+    from transferia_tpu.stats import trace
+
+    summary = {"wall_s": 10.0, "overlap_factor": 1.25, "waits": {},
+               "stages": {"sink": {"self_s": 7.5},
+                          "transform": {"self_s": 5.0}}}
+    monkeypatch.setattr(trace, "stage_summary", lambda wall=None: summary)
+    assert trace.format_summary(10.0, one_line=True) == \
+        "sink=7.50s(75%) transform=5.00s(50%) overlap_factor=1.25"
+    summary.update(wall_s=0.0, overlap_factor=0.0, stages={})
+    assert trace.format_summary(one_line=True) == ""
+
+
+def test_stage_note_reads_recorded_spans_and_leaves_waits_out():
+    import time
+
+    from transferia_tpu.stats import trace
+
+    trace.reset()
+    trace.enable(True)
+    try:
+        with trace.span("batch"):
+            with trace.span("transform"):
+                time.sleep(0.02)
+            # a passive wait far longer than the wall: no stage share
+            trace.complete("queue_wait", time.perf_counter() - 5.0, 5.0)
+    finally:
+        trace.enable(False)
+    note = trace.format_summary(0.04, one_line=True)
+    table = trace.format_summary(0.04)
+    trace.reset()
+    assert note.startswith("transform=0.02s(")
+    assert " batch=0.00s(" in note and "overlap_factor=" in note
+    assert "queue_wait" not in note
+    assert "~queue_wait" in table

@@ -600,3 +600,52 @@ def test_take_dict_codes_gather_stays_lazy():
     assert out.dict_enc.pool is pool
     expect = [batch.column("url").value(int(i)) for i in idx]
     assert out.to_pylist() == expect
+
+
+# -- stable names in a profiler trace ----------------------------------------
+
+def _op_names(lowered) -> set:
+    import re
+
+    return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
+
+
+def test_fused_program_carries_named_scopes_under_its_old_name():
+    """The parts of the fused program are named in the compiled HLO's
+    op metadata (what a profiler trace shows beside `%while.220`); the
+    module keeps its name, which the benchmark's roofline matches."""
+    from transferia_tpu.columnar.batch import bucket_rows
+    from transferia_tpu.ops.fused import FusedMaskFilterProgram
+
+    n = 300
+    bufs = [f"https://d{i}.io/x".encode() for i in range(n)]
+    data = np.frombuffer(b"".join(bufs), dtype=np.uint8).copy()
+    offsets = _offsets_from_lengths([len(b) for b in bufs])
+    region = np.arange(n, dtype=np.int32)
+    prog = FusedMaskFilterProgram([b"salt"], parse("region < 400"))
+    blocks, nblocks, pred, states, spec, _rows, _h2d = prog._stage(
+        [(data, offsets)], {"region": (region, None)}, n, bucket_rows(n))
+    lowered = prog._jit.lower(blocks, nblocks, states, pred, spec)
+    names = _op_names(lowered)
+    assert all(nm.startswith("jit(program)/") for nm in names
+               if "/" in nm)
+    for scope in ("mask_hmac/hmac_inner/", "mask_hmac/hmac_outer/",
+                  "sha256_rounds/", "sha256_schedule/", "sha256_words/",
+                  "pred_decode/", "predicate/", "keep_pack/"):
+        assert any(scope in nm for nm in names), scope
+
+
+@pytest.mark.parametrize("fn,args,scope", [
+    ("unpack_bits", (np.zeros(4, np.uint32), 4, 32), "unpack_bits/"),
+    ("delta_decode", (np.zeros(4, np.uint32), np.int32(0), 4, 32),
+     "delta_decode/"),
+    ("for_decode", (np.zeros(4, np.uint32), np.zeros(1, np.int32), 4, 32,
+                    32), "for_decode/"),
+    ("decode_dict_run", (np.zeros(4, np.uint32), np.zeros(16, np.int32),
+                         4, 32), "dict_gather/"),
+])
+def test_decode_kernels_carry_named_scopes(fn, args, scope):
+    from transferia_tpu.ops import decode
+
+    names = _op_names(getattr(decode, fn).lower(*args))
+    assert any(scope in nm for nm in names), names

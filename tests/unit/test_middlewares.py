@@ -151,3 +151,42 @@ def test_statistician_counts():
     s.push(cb(5))
     assert stats.m.value("sinker_pushed_rows") == 5.0
     assert stats.table_rows[str(TID)] == 5
+
+
+def _flush_with_trigger(trigger):
+    """Make the bufferer flush for one reason; returns the flush spans."""
+    from transferia_tpu.stats import trace
+
+    cap = Capture()
+    cfg = {
+        "rows": BuffererConfig(trigger_rows=4, trigger_interval=0),
+        "bytes": BuffererConfig(trigger_rows=10**9, trigger_bytes=1,
+                                trigger_interval=0),
+        "interval": BuffererConfig(trigger_rows=10**9,
+                                   trigger_interval=0.05),
+    }.get(trigger, BuffererConfig(trigger_rows=10**9, trigger_interval=0))
+    trace.reset()
+    trace.enable(True)
+    try:
+        buf = Bufferer(cap, cfg)
+        f = buf.async_push(cb(4))
+        if trigger == "control":
+            buf.async_push([done_table_load(TID, SCHEMA)]).result(timeout=5)
+        if trigger == "close":
+            buf.close()
+        f.result(timeout=5)
+        buf.close()
+        return [s for s in trace.spans() if s[0] == "bufferer_flush"]
+    finally:
+        trace.enable(False)
+        trace.reset()
+
+
+@pytest.mark.parametrize(
+    "trigger", ["rows", "bytes", "interval", "control", "close"])
+def test_bufferer_flush_span_says_what_triggered_it(trigger):
+    flushes = _flush_with_trigger(trigger)
+    assert len(flushes) == 1
+    args = flushes[0][7]
+    assert args["trigger"] == trigger
+    assert args["rows"] == 4 and args["units"] == 1 and args["bytes"] > 0

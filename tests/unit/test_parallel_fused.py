@@ -11,6 +11,7 @@ import numpy as np
 
 import jax
 
+from tests.unit.test_decode_kernels import _op_names
 from tests.unit.test_fused_device import (
     CONFIG,
     TID,
@@ -255,3 +256,33 @@ def test_sharded_encoders_roundtrip_host():
             np.asarray(dd), d2[d].astype(np.int64))
         np.testing.assert_array_equal(
             np.asarray(vv), validity.reshape(4, 512)[d])
+
+
+def test_sharded_program_names_its_body_and_its_psum():
+    """The sharded body's parts and the two psums carry named scopes in
+    the compiled HLO's op metadata (what a profiler trace shows)."""
+    prog = ShardedFusedProgram([b"k"], parse("region < 400"))
+    lowered = {}
+    get_compiled = prog._get_compiled
+
+    def spy(*key):
+        fn = get_compiled(*key)
+
+        def call(*args):
+            lowered["names"] = _op_names(fn.lower(*args))
+            return fn(*args)
+
+        return call
+
+    prog._get_compiled = spy
+    n = 8 * 1024
+    vals = [f"v{i}".encode() for i in range(n)]
+    data = np.frombuffer(b"".join(vals), dtype=np.uint8)
+    offsets = np.zeros(n + 1, dtype=np.int32)
+    np.cumsum([len(v) for v in vals], out=offsets[1:])
+    region = (np.arange(n) % 500).astype(np.int32)
+    prog.run([(data, offsets)], {"region": (region, None)}, n)
+    names = lowered["names"]
+    for scope in ("mask_hmac/hmac_inner/", "pred_decode/", "predicate/",
+                  "shard_hist/", "mesh_psum/"):
+        assert any(scope in nm for nm in names), scope

@@ -179,3 +179,113 @@ def test_chunk_sizing_follows_launch_overhead(monkeypatch, launch_ms,
         assert ops_fused._chunk_rows() == expect
     finally:
         ops_fused.set_chunk_rows(None)
+
+
+# -- why a batch went where it went (DeviceTelemetry placement_*) -----------
+
+def _placements():
+    from transferia_tpu.stats import trace
+
+    tel = trace.TELEMETRY.snapshot()
+    return {r: tel[f"placement_{r}"] for r in trace.PLACEMENT_REASONS
+            if tel[f"placement_{r}"]}
+
+
+@pytest.fixture
+def fast_link(monkeypatch):
+    from transferia_tpu.ops import linkprobe as lp
+    from transferia_tpu.stats import trace
+
+    fast = lp.LinkProfile(backend="tpu", launch_overhead_s=1e-7,
+                          h2d_bytes_per_s=1e13, d2h_bytes_per_s=1e13,
+                          measured=True)
+    monkeypatch.setattr(lp, "probe_link", lambda force=False: fast)
+    trace.TELEMETRY.reset()
+    set_device_fusion(True)
+    set_placement("auto")
+
+
+def test_first_four_batches_of_a_fresh_chain(fast_link):
+    chain = build_chain(CONFIG)
+    for _ in range(4):
+        chain.apply(make_batch())
+    got = _placements()
+    winner = {k: v for k, v in got.items() if k.startswith("winner_")}
+    # host first; the device twice, the first of the two carrying the
+    # compile and so not measured; then whoever won
+    assert got == {"host_first": 1, "device_explore": 2, **winner}
+    assert sum(winner.values()) == 1 and len(winner) == 1
+
+
+def test_pinned_gated_and_reprobed_batches_are_counted(monkeypatch,
+                                                       fast_link):
+    from transferia_tpu.ops import linkprobe as lp
+
+    chain = build_chain(CONFIG)
+    step = chain.plan_for(TID, make_batch(4).schema).steps[0]
+    step._ns_row = {"host": 50_000.0, "device": 90_000.0}
+    step._batch_no = DeviceFusedStep.REPROBE_EVERY - 1
+    assert step._pick_strategy(4096) == "device"
+    slow = lp.LinkProfile(backend="tpu", launch_overhead_s=0.07,
+                          h2d_bytes_per_s=20e6, d2h_bytes_per_s=20e6,
+                          measured=True)
+    monkeypatch.setattr(lp, "probe_link", lambda force=False: slow)
+    step._ns_row = {"host": 200.0, "device": -1.0}
+    assert step._pick_strategy(2048) == "host"        # never explored
+    step._ns_row = {"host": 200.0, "device": 25_000.0}
+    assert step._pick_strategy(2048) == "host"        # re-probe refused
+    set_placement("device")
+    assert step._pick_strategy(2048) == "device"
+    assert _placements() == {"reprobe": 1, "link_gated": 2, "pinned": 1}
+
+
+def test_an_unlisted_reason_is_counted_and_never_raises(fast_link):
+    import ast
+    import inspect
+    import textwrap
+
+    from transferia_tpu.stats import trace
+
+    # every reason _decide can return today is in the tuple ...
+    tree = ast.parse(textwrap.dedent(
+        inspect.getsource(DeviceFusedStep._decide)))
+    reasons = set()
+    for ret in (n for n in ast.walk(tree) if isinstance(n, ast.Return)):
+        reason = ret.value.elts[1]
+        if isinstance(reason, ast.Constant):
+            reasons.add(reason.value)
+        else:   # f"winner_{winner}"
+            reasons |= {"winner_host", "winner_device"}
+    assert reasons == set(trace.PLACEMENT_REASONS)
+    # ... and one it adds later is counted, not a KeyError in the batch
+    trace.TELEMETRY.record_placement("brand_new")
+    assert trace.TELEMETRY.snapshot()["placement_brand_new"] == 1
+
+
+def test_placement_instant_lands_on_the_transform_span(fast_link):
+    from transferia_tpu.middlewares.sync import Transformation
+    from transferia_tpu.stats import trace
+
+    class Null:
+        def push(self, batch):
+            pass
+
+        def close(self):
+            pass
+
+    trace.reset()
+    trace.enable(True)
+    try:
+        Transformation(Null(), build_chain(CONFIG)).push(make_batch())
+        rec = trace.spans()
+    finally:
+        trace.enable(False)
+        trace.reset()
+    transform = next(s for s in rec if s[0] == "transform")
+    inst = [s for s in rec if s[0] == "placement"]
+    assert len(inst) == 1 and inst[0][6] == -1
+    assert inst[0][10] == transform[9]        # fired on that span
+    assert inst[0][7] == {
+        "strategy": "host", "reason": "host_first", "rows": 257,
+        "host_ns_row": -1.0, "device_ns_row": -1.0,
+        "predicted_device_ns_row": -1.0}

@@ -44,6 +44,32 @@ def test_ordering_preserved_under_jitter():
     assert got == [(g, g * 10) for g in groups]
 
 
+def test_consumer_stall_is_a_decode_wait_span_of_its_own():
+    from transferia_tpu.stats import trace
+
+    def decode(g):
+        time.sleep(0.03)
+        return g
+
+    trace.reset()
+    trace.enable(True)
+    try:
+        with trace.span("part"):
+            with RowGroupReadahead(range(3), decode, max_groups=2) as ra:
+                assert [g for g, _ in ra] == [0, 1, 2]
+        rec = trace.spans()
+    finally:
+        trace.enable(False)
+        trace.reset()
+    waits = [s for s in rec if s[0] == "decode_wait"]
+    part = next(s for s in rec if s[0] == "part")
+    assert waits and sum(s[4] for s in waits) >= 0.03
+    # recorded once it ended: on the part's trace, but the part keeps
+    # all of its own time
+    assert all(s[10] == part[9] and s[5] == s[4] for s in waits)
+    assert part[5] == part[4]
+
+
 def test_worker_error_propagates_to_consumer():
     def decode(g):
         if g == 3:

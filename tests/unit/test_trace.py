@@ -19,6 +19,7 @@ import time
 import urllib.request
 
 import numpy as np
+import pytest
 
 from transferia_tpu.stats import trace
 
@@ -218,7 +219,7 @@ def test_device_telemetry_wired_in_fused_path():
     assert tel["device_launches"] > 0
     assert tel["h2d_bytes"] > 0 and tel["h2d_transfers"] > 0
     assert tel["d2h_bytes"] > 0 and tel["d2h_transfers"] > 0
-    assert tel["kernel_seconds"] > 0
+    assert tel["device_wait_seconds"] > 0
     # the timeline carries the matching spans with byte args (chain
     # applied directly here, so no middleware "transform" span)
     names = {s[0] for s in trace.spans()}
@@ -331,3 +332,289 @@ def test_capture_seconds_preserves_a_live_session():
     assert any(e["name"] == "precious" for e in doc["traceEvents"]
                if e["ph"] == "X"), "pre-capture spans must survive"
     assert any(s[0] == "precious" for s in trace.spans())
+
+
+# -- the profiler's clock ----------------------------------------------------
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: records what the
+    spans do to it, on which thread."""
+
+    made: list = []
+
+    def __init__(self, name):
+        self.name = name
+        self.events = []
+        _FakeAnnotation.made.append(self)
+
+    def __enter__(self):
+        self.events.append(("enter", threading.get_ident()))
+        return self
+
+    def __exit__(self, *exc):
+        self.events.append(("exit", threading.get_ident()))
+        return False
+
+
+def test_spans_enter_a_profiler_annotation_when_tracing_is_on(
+        monkeypatch):
+    _FakeAnnotation.made = []
+    trace.enable(True)
+    monkeypatch.setattr(trace, "_annotation", _FakeAnnotation)
+    with trace.span("outer"):
+        with trace.span("inner", rows=3):
+            # the annotation is open while the span's body runs
+            assert [a.events[0][0] for a in _FakeAnnotation.made] == \
+                ["enter", "enter"]
+            assert all(len(a.events) == 1 for a in _FakeAnnotation.made)
+    me = threading.get_ident()
+    assert [a.name for a in _FakeAnnotation.made] == ["outer", "inner"]
+    for a in _FakeAnnotation.made:
+        assert a.events == [("enter", me), ("exit", me)]
+    # the span's own record is unchanged by the mirror
+    assert [s[0] for s in trace.spans()] == ["inner", "outer"]
+
+
+def test_no_annotation_is_constructed_when_tracing_is_off(monkeypatch):
+    _FakeAnnotation.made = []
+    monkeypatch.setattr(trace, "_annotation", _FakeAnnotation)
+    assert not trace.enabled()
+    with trace.span("hot", rows=1):
+        pass
+    trace.instant("placement", reason="pinned")
+    trace.complete("queue_wait", time.perf_counter(), 0.01)
+    assert _FakeAnnotation.made == []
+    assert trace.spans() == []
+
+
+def test_enable_finds_the_real_annotation_class():
+    import jax
+
+    trace.enable(True)
+    assert trace._annotation is jax.profiler.TraceAnnotation
+    with trace.span("real"):   # the real class takes a bare name
+        pass
+    assert [s[0] for s in trace.spans()] == ["real"]
+
+
+# -- compile or load ---------------------------------------------------------
+
+_HIT = "/jax/compilation_cache/cache_hits"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def _compile_counts():
+    tel = trace.TELEMETRY.snapshot()
+    return (tel["compile_events"], tel["compile_cache_hits"],
+            tel["compile_seconds"], tel["compile_cache_seconds"])
+
+
+def test_cache_hit_then_duration_counts_one_load_and_one_event():
+    from jax import monitoring
+
+    trace.enable(True)   # installs the hooks
+    trace.TELEMETRY.reset()
+    monitoring.record_event(_HIT)
+    monitoring.record_event_duration_secs(_COMPILE, 0.25)
+    assert _compile_counts() == (1, 1, 0.25, 0.25)
+    inst = [s for s in trace.spans() if s[0] == "xla_compile"]
+    assert len(inst) == 1 and inst[0][7]["cache_hit"] is True
+
+
+def test_duration_alone_counts_a_compile_and_no_load():
+    from jax import monitoring
+
+    trace.enable(True)
+    trace.TELEMETRY.reset()
+    monitoring.record_event_duration_secs(_COMPILE, 1.5)
+    # the flag of an earlier load does not leak into the next compile
+    monitoring.record_event(_HIT)
+    monitoring.record_event_duration_secs(_COMPILE, 0.5)
+    monitoring.record_event_duration_secs(_COMPILE, 2.0)
+    assert _compile_counts() == (3, 1, 4.0, 0.5)
+    hits = [s[7]["cache_hit"] for s in trace.spans()
+            if s[0] == "xla_compile"]
+    assert hits == [False, True, False]
+
+
+def test_a_load_on_one_thread_is_not_a_load_on_another():
+    from jax import monitoring
+
+    trace.enable(True)
+    trace.TELEMETRY.reset()
+    step = threading.Barrier(2)
+
+    def loader():
+        monitoring.record_event(_HIT)        # inside its interval ...
+        step.wait(timeout=5)
+        step.wait(timeout=5)                 # ... while the other ends
+        monitoring.record_event_duration_secs(_COMPILE, 0.2)
+
+    def compiler():
+        step.wait(timeout=5)
+        monitoring.record_event_duration_secs(_COMPILE, 17.0)
+        step.wait(timeout=5)
+
+    threads = [threading.Thread(target=loader),
+               threading.Thread(target=compiler)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+    assert _compile_counts() == (2, 1, 17.2, 0.2)
+
+
+def test_new_counters_are_present_at_zero_from_the_start():
+    trace.TELEMETRY.reset()
+    tel = trace.TELEMETRY.snapshot()
+    for key in ("compile_cache_hits", "compile_cache_seconds",
+                "device_wait_seconds",
+                *(f"placement_{r}" for r in trace.PLACEMENT_REASONS)):
+        assert tel[key] == 0, key
+    assert "kernel_seconds" not in tel
+
+
+# -- inside the ClickHouse sink ----------------------------------------------
+
+def _ch_batch(n=64):
+    from transferia_tpu.abstract.schema import TableID
+    from transferia_tpu.providers.sample import make_batch
+
+    return make_batch("iot", TableID("sample", "events"), 0, n, 7)
+
+
+def _sink_tree(recorded):
+    """(the `sink` span, its direct children) of one recorded push."""
+    sink = [s for s in recorded if s[0] == "sink"]
+    assert len(sink) == 1
+    kids = [s for s in recorded if s[6] >= 0 and s[10] == sink[0][9]]
+    return sink[0], kids
+
+
+@pytest.mark.parametrize("staged", [False, True],
+                         ids=["push", "stage_push"])
+def test_ch_sink_splits_into_serialize_and_sink_push(staged):
+    from tests.recipes.fake_clickhouse import FakeCH
+    from transferia_tpu.middlewares.sync import Statistician
+    from transferia_tpu.providers.clickhouse.provider import (
+        CHSinker,
+        CHTargetParams,
+    )
+    from transferia_tpu.stats.registry import SinkerStats
+
+    srv = FakeCH().start()
+    inner = CHSinker(CHTargetParams(host="127.0.0.1", port=srv.port,
+                                    bufferer=None))
+    sink = Statistician(inner, SinkerStats())
+    batch = _ch_batch()
+    try:
+        if staged:
+            inner.begin_part("op/sample.events/0", 1)
+        trace.enable(True)
+        trace.reset()
+        sink.push(batch)
+        trace.enable(False)
+        recorded = trace.spans()
+        if staged:
+            assert inner.publish_part("op/sample.events/0", 1) == 64
+        assert srv.total_rows() >= 64
+    finally:
+        sink.close()
+        srv.stop()
+    top, kids = _sink_tree(recorded)
+    by_name = {s[0]: s for s in kids}
+    # two spans an insert, both directly under `sink`, nothing per column
+    assert [s[0] for s in recorded].count("serialize") == 1
+    assert [s[0] for s in recorded].count("sink_push") == 1
+    ser, push = by_name["serialize"], by_name["sink_push"]
+    assert ser[6] == push[6] == top[6] + 1
+    assert ser[7]["format"] == "rowbinary" and ser[7]["rows"] == 64
+    assert ser[7]["columns"] == len(batch.columns)
+    assert push[7] == {"direction": "clickhouse_http",
+                       "bytes": ser[7]["bytes"]}
+    assert ser[7]["bytes"] > 64
+    # what `sink` alone read before the split: its duration less the
+    # children it had then (everything but the two new spans)
+    others = sum(s[4] for s in kids
+                 if s[0] not in ("serialize", "sink_push"))
+    old_sink_self = top[4] - others
+    assert top[5] + ser[5] + push[5] == pytest.approx(old_sink_self,
+                                                      abs=1e-6)
+    assert ser[5] > 0 and push[5] > 0
+
+
+# -- the push loop's queue ---------------------------------------------------
+
+class _SlowSink:
+    def __init__(self, seconds):
+        self.seconds = seconds
+        self.pushed = []
+
+    def async_push(self, batch):
+        import concurrent.futures
+
+        time.sleep(self.seconds)
+        self.pushed.append(batch)
+        fut = concurrent.futures.Future()
+        fut.set_result(None)
+        return fut
+
+    def close(self):
+        pass
+
+
+def _run_parsequeue(n_items, seconds=0.03):
+    from transferia_tpu.parsequeue.queue import ParseQueue
+
+    sink = _SlowSink(seconds)
+    acked = []
+    q = ParseQueue(2, sink, parse_fn=tuple,
+                   ack_fn=lambda raw, err: acked.append((raw, err)))
+    for i in range(n_items):
+        q.add([i] * (i + 1))
+    q.wait()
+    q.close()
+    assert [e for _r, e in acked] == [None] * n_items
+    return sink
+
+
+def test_queue_wait_is_recorded_behind_a_slow_sink():
+    trace.enable(True)
+    _run_parsequeue(4)
+    rec = trace.spans()
+    waits = [s for s in rec if s[0] == "queue_wait"]
+    assert len(waits) == 4
+    assert [s[7]["rows"] for s in waits] == [1, 2, 3, 4]
+    # item k waits for the k pushes before it
+    durs = [s[4] for s in waits]
+    assert durs[3] >= 2 * 0.03 and durs[3] > durs[0]
+    # recorded after the fact: a root with all its time its own, so it
+    # takes self time from no span - `sink_wait` least of all
+    assert all(s[6] == trace.WAIT_DEPTH and s[5] == s[4] and s[10] == 0
+               for s in waits)
+    pushes = [s for s in rec if s[0] == "sink_wait"]
+    assert len(pushes) == 4
+    assert all(s[5] == s[4] and s[4] >= 0.03 for s in pushes)
+
+
+def test_waits_stay_out_of_the_stage_shares_and_the_overlap_factor():
+    trace.enable(True)
+    with trace.span("sink"):
+        time.sleep(0.01)
+    before = trace.stage_summary(1.0)
+    # a consumer stall and a queued item, each longer than the wall
+    trace.complete("decode_wait", time.perf_counter() - 3.0, 3.0)
+    trace.complete("queue_wait", time.perf_counter() - 9.0, 9.0, rows=7)
+    after = trace.stage_summary(1.0)
+    assert after["overlap_factor"] == before["overlap_factor"]
+    assert after["stages"] == before["stages"] and before["waits"] == {}
+    assert list(after["waits"]) == ["queue_wait", "decode_wait"]
+    assert after["waits"]["queue_wait"]["self_s"] == pytest.approx(9.0)
+    # a span reader that filters on depth >= 0 still finds them
+    assert sum(1 for s in trace.spans() if s[6] >= 0) == 3
+
+
+def test_queue_wait_costs_nothing_when_tracing_is_off():
+    sink = _run_parsequeue(3, seconds=0.0)
+    assert len(sink.pushed) == 3
+    assert trace.spans() == []

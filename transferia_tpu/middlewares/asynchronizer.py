@@ -217,7 +217,7 @@ class Bufferer(AsyncSink):
                 return
             with self._lock:
                 if self._buf:
-                    self._flush_locked()
+                    self._flush_locked("interval")
 
     @staticmethod
     def _mergeable(a: Batch, b: Batch) -> bool:
@@ -229,7 +229,9 @@ class Bufferer(AsyncSink):
             )
         return not is_columnar(a) and not is_columnar(b)
 
-    def _flush_locked(self) -> None:
+    def _flush_locked(self, trigger: str) -> None:
+        """`trigger`: what asked for the flush - `rows`, `bytes`,
+        `interval`, `control` or `close`; it rides the span."""
         buf, self._buf = self._buf, []
         rows, self._rows = self._rows, 0
         nbytes, self._bytes = self._bytes, 0
@@ -239,7 +241,8 @@ class Bufferer(AsyncSink):
             return
         sp = trace.span("bufferer_flush")
         if sp:
-            sp.add(rows=rows, bytes=nbytes, units=len(buf))
+            sp.add(rows=rows, bytes=nbytes, units=len(buf),
+                   trigger=trigger)
         with sp:
             self._flush_groups(buf)
 
@@ -291,7 +294,7 @@ class Bufferer(AsyncSink):
                 return fut
             if is_control_batch(batch):
                 # flush pending data, then push the control batch standalone
-                self._flush_locked()
+                self._flush_locked("control")
                 try:
                     self.inner.push(batch)
                     fut.set_result(None)
@@ -303,20 +306,17 @@ class Bufferer(AsyncSink):
             self._bytes += batch_bytes(batch)
             self.stats.buffered_rows.set(self._rows)
             self.stats.buffered_bytes.set(self._bytes)
-            if (self._rows >= self.cfg.trigger_rows
-                    or self._bytes >= self.cfg.trigger_bytes):
-                self._flush_locked()
+            if self._rows >= self.cfg.trigger_rows:
+                self._flush_locked("rows")
+            elif self._bytes >= self.cfg.trigger_bytes:
+                self._flush_locked("bytes")
         return fut
-
-    def flush(self) -> None:
-        with self._lock:
-            self._flush_locked()
 
     def close(self) -> None:
         with self._lock:
             if self._closed:
                 return
-            self._flush_locked()
+            self._flush_locked("close")
             self._closed = True
         self._wake.set()
         if self._ticker:

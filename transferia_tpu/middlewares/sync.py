@@ -284,12 +284,10 @@ class Transformation(_Wrap):
         self.chain = chain
 
     def push(self, batch: Batch) -> None:
-        from transferia_tpu.stats import stagetimer
-
         sp = trace.span("transform")
         if sp:
             sp.add(rows=batch_len(batch))
-        with stagetimer.stage("transform"), sp:
+        with sp:
             failpoint("transform.chain")
             out = self.chain.apply(batch)
         if batch_len(out) or not batch_len(batch):

@@ -46,9 +46,11 @@ def decode_dict_run(words: jax.Array, pool: jax.Array, bit_width: int,
     """Bit-unpack n dictionary codes and gather their pool values —
     the device half of an RLE_DICTIONARY data page."""
     codes = unpack_bits(words, bit_width, n)
-    return jnp.take(pool, codes, axis=0, mode="clip")
+    with jax.named_scope("dict_gather"):
+        return jnp.take(pool, codes, axis=0, mode="clip")
 
 
+@jax.named_scope("pool_acc_gather")
 def gather_pool_accumulators(accs: jax.Array,
                              codes: jax.Array) -> jax.Array:
     """Dict-native fingerprint gather (ops/rowhash.py device backend):
@@ -70,6 +72,7 @@ def unpack_validity(words: jax.Array, n: int) -> jax.Array:
     return _unpack_core(words, 1, n).astype(jnp.bool_)
 
 
+@jax.named_scope("delta_decode")
 def delta_prefix_sum(words: jax.Array, base: jax.Array, bit_width: int,
                      n: int) -> jax.Array:
     """Zigzag-delta decode: values[i] = base + Σ deltas[0..i], int32.
@@ -88,6 +91,7 @@ def delta_prefix_sum(words: jax.Array, base: jax.Array, bit_width: int,
 delta_decode = jax.jit(delta_prefix_sum, static_argnums=(2, 3))
 
 
+@jax.named_scope("for_decode")
 def for_frame_decode(words: jax.Array, mins: jax.Array, bit_width: int,
                      frame: int, n: int) -> jax.Array:
     """Frame-of-reference decode: values[i] = mins[i // frame] + rel[i].
@@ -111,6 +115,7 @@ def for_frame_decode(words: jax.Array, mins: jax.Array, bit_width: int,
 for_decode = jax.jit(for_frame_decode, static_argnums=(2, 3, 4))
 
 
+@jax.named_scope("keep_pack")
 def pack_mask_words(bits: jax.Array, n: int) -> jax.Array:
     """(n,) bool -> packed little-endian uint32 words (device side).
 
@@ -133,12 +138,14 @@ def decode_dict_loop(words: jax.Array, pool: jax.Array, bit_width: int,
     def body(i, acc):
         w = words ^ (acc & jnp.uint32(1))
         codes = _unpack_core(w, bit_width, n)
-        vals = jnp.take(pool, codes, axis=0, mode="clip")
+        with jax.named_scope("dict_gather"):
+            vals = jnp.take(pool, codes, axis=0, mode="clip")
         return acc + vals.sum().astype(jnp.uint32)
 
     return jax.lax.fori_loop(0, iters, body, jnp.uint32(0))
 
 
+@jax.named_scope("unpack_bits")
 def _unpack_core(w: jax.Array, bit_width: int, n: int) -> jax.Array:
     """unpack_bits body without the jit wrapper (traced inline).
 

@@ -523,6 +523,7 @@ def _device_row_lanes(sig_kinds, fixed_lo, fixed_hi, var_blocks,
     device lane math (mix chains, var-width polynomial blocks, dict
     accumulator gathers, null constants), so the two entry points
     cannot drift apart.  Returns the finalized (r1, r2) u32 vectors."""
+    import jax
     import jax.numpy as jnp
 
     def mix(x):
@@ -541,8 +542,9 @@ def _device_row_lanes(sig_kinds, fixed_lo, fixed_hi, var_blocks,
             null = (nulls1 if lane == 0 else nulls2)[idx]
             if kind == "fixed":
                 lo, hi = fixed_lo[fi], fixed_hi[fi]
-                h = mix(lo ^ seed)
-                h = mix(h + mix(hi ^ (~seed)))
+                with jax.named_scope("rowhash_fixed"):
+                    h = mix(lo ^ seed)
+                    h = mix(h + mix(hi ^ (~seed)))
             elif kind == "dict":
                 # codes + per-pool-entry accumulators crossed the
                 # link (4 + 4·k/n bytes/row, not the padded block
@@ -553,13 +555,15 @@ def _device_row_lanes(sig_kinds, fixed_lo, fixed_hi, var_blocks,
                 )
 
                 acc = (dict_accs1 if lane == 0 else dict_accs2)[di]
-                h = mix(gather_pool_accumulators(
-                    acc, dict_codes[di]) ^ seed)
+                with jax.named_scope("rowhash_dict"):
+                    h = mix(gather_pool_accumulators(
+                        acc, dict_codes[di]) ^ seed)
             else:
                 pw = (powers1 if lane == 0 else powers2)[vi]
-                b = var_blocks[vi].astype(jnp.uint32)
-                h = mix((b * pw[None, :]).sum(
-                    axis=1, dtype=jnp.uint32) ^ seed)
+                with jax.named_scope("rowhash_var"):
+                    b = var_blocks[vi].astype(jnp.uint32)
+                    h = mix((b * pw[None, :]).sum(
+                        axis=1, dtype=jnp.uint32) ^ seed)
             v = validities[idx]
             if v is not None:
                 h = jnp.where(v, h, null ^ seed)
@@ -670,11 +674,14 @@ class DeviceFingerprintProgram:
                 sig_kinds, fixed_lo, fixed_hi, var_blocks, dict_codes,
                 dict_accs1, dict_accs2, validities, seeds1, seeds2,
                 nulls1, nulls2, powers1, powers2, rowmask.shape[0])
-            r1 = jnp.where(rowmask, r1, 0)
-            r2 = jnp.where(rowmask, r2, 0)
-            return (r1.sum(dtype=jnp.uint32), r2.sum(dtype=jnp.uint32),
-                    jnp.bitwise_xor.reduce(r1), jnp.bitwise_xor.reduce(r2),
-                    rowmask.sum(dtype=jnp.int32))
+            with jax.named_scope("rowhash_reduce"):
+                r1 = jnp.where(rowmask, r1, 0)
+                r2 = jnp.where(rowmask, r2, 0)
+                return (r1.sum(dtype=jnp.uint32),
+                        r2.sum(dtype=jnp.uint32),
+                        jnp.bitwise_xor.reduce(r1),
+                        jnp.bitwise_xor.reduce(r2),
+                        rowmask.sum(dtype=jnp.int32))
 
         sig_kinds = [k for k, _ in sig]
         fn = jax.jit(program)

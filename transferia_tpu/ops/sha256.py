@@ -78,7 +78,8 @@ def _compress_batch(h, block_words):
         s1 = _rotr(x2, 17) ^ _rotr(x2, 19) ^ (x2 >> 10)
         return w.at[i].set(w[i - 16] + s0 + w[i - 7] + s1)
 
-    w = jax.lax.fori_loop(16, 64, sched, w, unroll=8)
+    with jax.named_scope("sha256_schedule"):
+        w = jax.lax.fori_loop(16, 64, sched, w, unroll=8)
     k = jnp.asarray(_K)
 
     def round_fn(i, state):
@@ -92,15 +93,17 @@ def _compress_batch(h, block_words):
         return (t1 + t2, a, b, c, d + t1, e, f, g)
 
     state = tuple(h[:, i] for i in range(8))
-    a, b, c, d, e, f, g, hh = jax.lax.fori_loop(
-        0, 64, round_fn, state, unroll=8
-    )
+    with jax.named_scope("sha256_rounds"):
+        a, b, c, d, e, f, g, hh = jax.lax.fori_loop(
+            0, 64, round_fn, state, unroll=8
+        )
     return jnp.stack([
         h[:, 0] + a, h[:, 1] + b, h[:, 2] + c, h[:, 3] + d,
         h[:, 4] + e, h[:, 5] + f, h[:, 6] + g, h[:, 7] + hh,
     ], axis=1)
 
 
+@jax.named_scope("sha256_words")
 def _bytes_to_words(blocks_u8):
     """(N, n_blocks, 64) uint8 -> (N, n_blocks, 16) uint32 big-endian.
 
@@ -136,10 +139,11 @@ def _sha256_padded(blocks_u8, n_blocks_per_row, max_blocks: int):
         active = (idx < n_blocks_per_row)[:, None]
         return jnp.where(active, new_h, h), None
 
-    h, _ = jax.lax.scan(
-        step, h,
-        (jnp.moveaxis(words, 1, 0), jnp.arange(max_blocks)),
-    )
+    with jax.named_scope("sha256_blocks"):
+        h, _ = jax.lax.scan(
+            step, h,
+            (jnp.moveaxis(words, 1, 0), jnp.arange(max_blocks)),
+        )
     return h
 
 
@@ -263,9 +267,10 @@ def _hmac_inner_outer_impl(blocks_u8, n_blocks_per_row, states,
         active = (idx < n_blocks_per_row)[:, None]
         return jnp.where(active, new_h, h), None
 
-    h, _ = jax.lax.scan(
-        step, h, (jnp.moveaxis(words, 1, 0), jnp.arange(max_blocks))
-    )
+    with jax.named_scope("hmac_inner"):
+        h, _ = jax.lax.scan(
+            step, h, (jnp.moveaxis(words, 1, 0), jnp.arange(max_blocks))
+        )
     # outer: H(K^opad || inner_digest); inner digest is 32 bytes -> 1 block.
     # Built by concat, not .at[].set — column scatters lower terribly on TPU.
     pad_words = np.zeros(8, dtype=np.uint32)
@@ -274,8 +279,9 @@ def _hmac_inner_outer_impl(blocks_u8, n_blocks_per_row, states,
     outer_block = jnp.concatenate(
         [h, jnp.broadcast_to(jnp.asarray(pad_words), (n, 8))], axis=1
     )
-    return _compress_batch(jnp.broadcast_to(outer_state, (n, 8)),
-                           outer_block)
+    with jax.named_scope("hmac_outer"):
+        return _compress_batch(jnp.broadcast_to(outer_state, (n, 8)),
+                               outer_block)
 
 
 _hmac_inner_outer = functools.partial(

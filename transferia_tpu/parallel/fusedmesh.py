@@ -42,7 +42,7 @@ from transferia_tpu.ops.fused import (
     pow2_blocks,
 )
 from transferia_tpu.ops.sha256 import _hmac_key_states, hmac_device_core
-from transferia_tpu.stats import stagetimer, trace
+from transferia_tpu.stats import trace
 from transferia_tpu.stats.trace import TELEMETRY
 
 
@@ -153,11 +153,12 @@ class ShardedFusedProgram:
                 valid = valid_in[0]
             else:
                 valid = unpack_validity(valid_in[0], bucket)
-            pred_cols = {
-                name: decode_pred_device_sharded(
-                    spec, pred_arrays[name], bucket)
-                for name, spec in pred_specs
-            }
+            with jax.named_scope("pred_decode"):
+                pred_cols = {
+                    name: decode_pred_device_sharded(
+                        spec, pred_arrays[name], bucket)
+                    for name, spec in pred_specs
+                }
             rows_local = bucket
             # raw digest words leave the device (32 B/row, host LUT hex
             # expansion — same contract as FusedMaskFilterProgram).
@@ -165,16 +166,18 @@ class ShardedFusedProgram:
             # columns GATHER per-row digest words from the replicated
             # pool digest matrix by their sharded int32 codes — equal
             # bytes hash equal, so the outputs are byte-identical
-            flat_digests = [
-                hmac_device_core(b, nb, st[0], st[1], mb)
-                for b, nb, st, mb in zip(
-                    blocks_t, nblocks_t, states_t, max_blocks_t
-                )
-            ]
-            dict_digests = [
-                jnp.take(dg, cd, axis=0, mode="clip")
-                for cd, dg in zip(codes_t, digs_t)
-            ]
+            with jax.named_scope("mask_hmac"):
+                flat_digests = [
+                    hmac_device_core(b, nb, st[0], st[1], mb)
+                    for b, nb, st, mb in zip(
+                        blocks_t, nblocks_t, states_t, max_blocks_t
+                    )
+                ]
+            with jax.named_scope("dict_digest_gather"):
+                dict_digests = [
+                    jnp.take(dg, cd, axis=0, mode="clip")
+                    for cd, dg in zip(codes_t, digs_t)
+                ]
             fi = di = 0
             ordered = []
             for r in routes:  # reassemble the caller's column order
@@ -186,18 +189,21 @@ class ShardedFusedProgram:
                     fi += 1
             digests = tuple(ordered)
             if self._pred_fn is not None:
-                keep = self._pred_fn(pred_cols, rows_local) & valid
+                with jax.named_scope("predicate"):
+                    keep = self._pred_fn(pred_cols, rows_local) & valid
             else:
                 keep = valid
             # cross-chip collectives: global kept count + target-shard
             # histogram over the first masked column's digest words
             # (digests[0] is already computed above — XLA CSEs the reuse)
-            shard = (digests[0][:, 0] % jnp.uint32(self.n_shards)).astype(
-                jnp.int32)
-            hist = jnp.zeros((self.n_shards,), dtype=jnp.int32).at[
-                shard].add(keep.astype(jnp.int32))
-            hist = jax.lax.psum(hist, axis_name=row_axes)
-            kept = jax.lax.psum(keep.sum(), axis_name=row_axes)
+            with jax.named_scope("shard_hist"):
+                shard = (digests[0][:, 0]
+                         % jnp.uint32(self.n_shards)).astype(jnp.int32)
+                hist = jnp.zeros((self.n_shards,), dtype=jnp.int32).at[
+                    shard].add(keep.astype(jnp.int32))
+            with jax.named_scope("mesh_psum"):
+                hist = jax.lax.psum(hist, axis_name=row_axes)
+                kept = jax.lax.psum(keep.sum(), axis_name=row_axes)
             out_keep = (keep if self._pred_fn is not None
                         else jnp.zeros((0,), dtype=jnp.bool_))
             return digests, out_keep, hist, kept
@@ -298,10 +304,8 @@ class ShardedFusedProgram:
         encoded = encoding_enabled()
         blocks_t, nblocks_t, mb_t, flat_states = [], [], [], []
         codes_t, digs_t, routes = [], [], []
-        pack_t0 = None
         import time as _time
 
-        pack_t0 = _time.perf_counter()
         raw_equiv = 0
         for i, entry in enumerate(mask_cols):
             if isinstance(entry, DictMaskInput):
@@ -351,7 +355,6 @@ class ShardedFusedProgram:
         valid_arr = encode_validity_sharded(v2) if encoded else v2
         valid_mode = "bits" if encoded else "raw"
         raw_equiv += total  # the flat bool run-validity mask
-        stagetimer.add("pack", _time.perf_counter() - pack_t0)
         fn = self._get_compiled(tuple(routes), tuple(pred_key),
                                 valid_mode)
         stage_tree = (tuple(blocks_t), tuple(nblocks_t),
@@ -368,16 +371,14 @@ class ShardedFusedProgram:
             stage_h2d(stage_tree, raw_equiv_bytes=raw_equiv,
                       what="mesh", put=False)
         TELEMETRY.record_launch()
-        with stagetimer.stage("device_dispatch"), \
-                trace.span("device_dispatch", bytes=h2d, rows=n_rows,
-                           mesh=self.n_dev):
+        with trace.span("device_dispatch", bytes=h2d, rows=n_rows,
+                        mesh=self.n_dev):
             digests_dev, keep_dev, hist, kept = fn(
                 blocks_s, nblocks_s, tuple(flat_states), codes_s,
                 digs_s, pred_s, valid_s, tuple(mb_t), per_dev,
             )
         t_wait0 = _time.perf_counter()
-        with stagetimer.stage("device_wait"), \
-                trace.span("device_wait") as sp:
+        with trace.span("device_wait") as sp:
             hexes = [digests_to_hex(np.asarray(h)[:n_rows])
                      for h in digests_dev]
             keep = (np.asarray(keep_dev)[:n_rows]
@@ -391,5 +392,5 @@ class ShardedFusedProgram:
             if sp:  # args must attach before the span ends
                 sp.add(bytes=d2h, rows=n_rows)
         TELEMETRY.record_d2h(d2h)
-        TELEMETRY.record_kernel(_time.perf_counter() - t_wait0)
+        TELEMETRY.record_device_wait(_time.perf_counter() - t_wait0)
         return hexes, keep

@@ -5,6 +5,7 @@ from __future__ import annotations
 import concurrent.futures
 import logging
 import threading
+import time
 from typing import Any, Callable, Generic, Optional, Sequence, TypeVar
 
 from transferia_tpu.abstract.interfaces import AsyncSink, Batch
@@ -57,8 +58,11 @@ class ParseQueue(Generic[T]):
             raise RuntimeError("parsequeue closed")
         self._inflight.acquire()
         parse_fut = self._pool.submit(self._safe_parse, raw)
+        # the enqueue time rides the tuple: the wait between here and
+        # the push loop taking the item is staleness the program adds
+        t_add = time.perf_counter() if trace.enabled() else 0.0
         with self._cv:
-            self._queue.append((raw, parse_fut))
+            self._queue.append((raw, parse_fut, t_add))
             self._outstanding += 1
             self._cv.notify_all()
 
@@ -94,7 +98,11 @@ class ParseQueue(Generic[T]):
                     if self._closed:
                         return
                     continue
-                raw, parse_fut = self._queue.pop(0)
+                raw, parse_fut, t_add = self._queue.pop(0)
+            if t_add:
+                trace.complete("queue_wait", t_add,
+                               time.perf_counter() - t_add,
+                               rows=_batch_len(raw))
             err: Optional[BaseException] = self._failure
             if err is None:
                 # once failed, drain without pushing — pushing N+1 after N

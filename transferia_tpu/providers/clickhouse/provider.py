@@ -44,6 +44,7 @@ from transferia_tpu.providers.registry import (
     TestResult,
     register_provider,
 )
+from transferia_tpu.stats import trace
 from transferia_tpu.transform.plugins.sharder import hash_column_to_shards
 from transferia_tpu.typesystem.rules import (
     register_source_rules,
@@ -298,18 +299,33 @@ class CHSinker(Sinker, StagedSinker):
             self._stage_push(batch)
             return
         shards = self._shard_of(batch)
-        nullable = {
-            c.name: (not c.required and not c.primary_key)
-            for c in batch.schema
-        }
         for shard_idx in np.unique(shards):
             part = batch.filter(shards == shard_idx) \
                 if len(self.shards) > 1 else batch
             self._ensure_table(int(shard_idx), part)
-            payload = encode_rowbinary(part, nullable)
-            self._client(int(shard_idx)).insert_rowbinary(
-                ch_table_name(part.table_id), list(part.columns), payload
-            )
+            self._insert(int(shard_idx), ch_table_name(part.table_id),
+                         part)
+
+    def _insert(self, shard_idx: int, table: str,
+                batch: ColumnBatch) -> None:
+        """One insert, two spans: the RowBinary encoding (`serialize`)
+        and the HTTP POST that waits for the server (`sink_push`)."""
+        nullable = {
+            c.name: (not c.required and not c.primary_key)
+            for c in batch.schema
+        }
+        sp = trace.span("serialize")
+        with sp:
+            payload = encode_rowbinary(batch, nullable)
+            if sp:
+                sp.add(format="rowbinary", rows=batch.n_rows,
+                       columns=len(batch.columns), bytes=len(payload))
+        sp = trace.span("sink_push")
+        if sp:
+            sp.add(direction="clickhouse_http", bytes=len(payload))
+        with sp:
+            self._client(shard_idx).insert_rowbinary(
+                table, list(batch.columns), payload)
 
     def _apply_cleanup(self, table: TableID, kind: Kind) -> None:
         stmt = "TRUNCATE TABLE IF EXISTS" if kind == Kind.TRUNCATE \
@@ -373,14 +389,8 @@ class CHSinker(Sinker, StagedSinker):
                 partition_by=META_COLUMN))
         if staged.n_rows == 0:
             return
-        nullable = {
-            c.name: (not c.required and not c.primary_key)
-            for c in staged.schema
-        }
         try:
-            payload = encode_rowbinary(staged, nullable)
-            self._client(0).insert_rowbinary(
-                stage.table, list(staged.columns), payload)
+            self._insert(0, stage.table, staged)
         except BaseException:
             # the staging write died after the dedup window recorded
             # this batch: only a full part restage is safe

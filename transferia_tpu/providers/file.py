@@ -356,7 +356,6 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
             NativeParquetReader,
             slice_columns,
         )
-        from transferia_tpu.stats import stagetimer
 
         if self._has_huge_row_groups(pf, groups):
             return False  # stream huge row groups through arrow instead
@@ -365,9 +364,7 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         if reader is None:
             return False
 
-        def decode(g):
-            with stagetimer.stage("source_decode"):
-                return reader.read_row_group(g)
+        decode = reader.read_row_group
 
         def cols_nbytes(cols):
             return sum(c.nbytes() for c in cols.values())
@@ -377,12 +374,10 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
                 n = pf.metadata.row_group(g).num_rows
                 for b_lo in range(0, n, self.params.batch_rows):
                     b_hi = min(b_lo + self.params.batch_rows, n)
-                    with stagetimer.stage("pivot"):
-                        batch = ColumnBatch(
-                            tid, schema, slice_columns(cols, b_lo, b_hi))
-                        batch.read_bytes = batch.nbytes()
-                    with stagetimer.stage("source_decode"):
-                        batch = self._batch_filter(tid, batch)
+                    batch = ColumnBatch(
+                        tid, schema, slice_columns(cols, b_lo, b_hi))
+                    batch.read_bytes = batch.nbytes()
+                    batch = self._batch_filter(tid, batch)
                     if batch.n_rows:
                         pusher(batch)
         return True
@@ -392,22 +387,17 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         """Arrow decode with the same row-group readahead as the native
         path: whole-group reads release the GIL inside arrow C++, so
         dict-heavy/nested files overlap decode with downstream too."""
-        from transferia_tpu.stats import stagetimer
-
         def decode(g):
-            with stagetimer.stage("source_decode"):
-                return pf.read_row_group(g, use_threads=False)
+            return pf.read_row_group(g, use_threads=False)
 
         with self._readahead(groups, decode, lambda t: t.nbytes) as ra:
             for g, tbl in ra:
                 for rb in tbl.to_batches(
                         max_chunksize=self.params.batch_rows):
-                    with stagetimer.stage("source_decode"):
-                        rb = self._scan_filter(tid, rb)
+                    rb = self._scan_filter(tid, rb)
                     if rb.num_rows:
-                        with stagetimer.stage("pivot"):
-                            batch = ColumnBatch.from_arrow(rb, tid, schema)
-                            batch.read_bytes = rb.nbytes
+                        batch = ColumnBatch.from_arrow(rb, tid, schema)
+                        batch.read_bytes = rb.nbytes
                         pusher(batch)
 
     def _load_row_groups(self, path: str, lo: int, hi: int, tid: TableID,
@@ -416,7 +406,6 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         from transferia_tpu.providers.parquet_native import (
             parquet_file_cached,
         )
-        from transferia_tpu.stats import stagetimer
 
         failpoint("storage.file.open")
         # footer metadata memoizes per (path, mtime, size): a multi-part
@@ -448,16 +437,13 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
             it = pf.iter_batches(batch_size=self.params.batch_rows,
                                  row_groups=groups)
             while True:
-                with stagetimer.stage("source_decode"):
-                    rb = next(it, None)
-                    if rb is not None:
-                        rb = self._scan_filter(tid, rb)
+                rb = next(it, None)
                 if rb is None:
                     return
+                rb = self._scan_filter(tid, rb)
                 if rb.num_rows:
-                    with stagetimer.stage("pivot"):
-                        batch = ColumnBatch.from_arrow(rb, tid, schema)
-                        batch.read_bytes = rb.nbytes
+                    batch = ColumnBatch.from_arrow(rb, tid, schema)
+                    batch.read_bytes = rb.nbytes
                     pusher(batch)
             return
         self._load_groups_arrow(pf, groups, tid, schema, pusher)

@@ -19,6 +19,7 @@ import logging
 import socket
 import struct
 import threading
+import time
 from typing import Optional
 
 from transferia_tpu.abstract.errors import CategorizedError
@@ -206,7 +207,7 @@ class KafkaClient:
         from transferia_tpu.stats import trace
 
         failpoint("client.kafka.roundtrip")  # before the lock: may sleep
-        with trace.span("kafka_roundtrip", api=api_key), self._lock:
+        with trace.span("kafka_roundtrip", api=api_key) as sp, self._lock:
             sock = self._conn_for(node)
             self._corr += 1
             corr = self._corr
@@ -218,8 +219,14 @@ class KafkaClient:
             try:
                 sock.sendall(  # trtpu: ignore[LCK001]
                     struct.pack("!i", len(msg)) + msg)
+                t_sent = time.perf_counter() if sp else 0.0
                 size = struct.unpack(
                     "!i", recv_exact(sock, 4))[0]  # trtpu: ignore[LCK001]
+                if sp:
+                    # to the first response byte: the broker's long
+                    # poll, apart from reading the response
+                    sp.add(wait_s=round(time.perf_counter() - t_sent, 6),
+                           bytes=size)
                 payload = recv_exact(sock, size)  # trtpu: ignore[LCK001]
             except (OSError, ConnectionError) as e:
                 self._drop_conn(node)

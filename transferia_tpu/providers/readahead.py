@@ -29,13 +29,12 @@ flow downstream buys genuine overlap without processes.
   the caller's thread — zero new threads, exactly the serial behavior.
 
 Observability: each prefetch decode runs inside a `decode_readahead`
-trace span on the worker thread (stage timers taken inside the decode
-callable fold into the global stagetimer totals — per-thread accounting
-is already how overlap_factor is defined); consumer stalls are
-accounted as a `decode_wait` stage; queue depth and in-flight decoded
-bytes feed optional gauges (stats/registry.py DeviceStats) plus the
-module-level aggregate `snapshot_stats()` that `bench.py` appends to
-its stages line.
+trace span on the worker thread; a consumer stall is recorded as a
+`decode_wait` span once it ends (`trace.complete`, so it takes self
+time from no parent) and billed to the ledger; queue depth and
+in-flight decoded bytes feed optional gauges (stats/registry.py
+DeviceStats) plus the module-level aggregate `snapshot_stats()` that
+`bench.py` appends to its stages line.
 """
 
 from __future__ import annotations
@@ -213,6 +212,7 @@ class RowGroupReadahead:
         if self._thread is None:
             return self._next_inline()
         waited = 0.0
+        first_wait = 0.0
         try:
             with self._cond:
                 self._release_handed_locked()
@@ -229,14 +229,15 @@ class RowGroupReadahead:
                     if self._done:
                         raise StopIteration
                     t0 = time.perf_counter()
+                    first_wait = first_wait or t0
                     self._cond.wait()
                     waited += time.perf_counter() - t0
         finally:
             if waited:
-                from transferia_tpu.stats import stagetimer
+                from transferia_tpu.stats import trace
                 from transferia_tpu.stats.ledger import LEDGER
 
-                stagetimer.add("decode_wait", waited)
+                trace.complete("decode_wait", first_wait, waited)
                 LEDGER.add(decode_wait_seconds=waited)
         return g, item
 

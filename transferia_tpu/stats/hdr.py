@@ -1,7 +1,7 @@
 """Mergeable log-bucketed latency histograms (HDR-style).
 
-The scalar stage timers (stats/stagetimer.py totals, trace.py
-stage_summary percentiles over raw duration lists) are per-process and
+The scalar stage numbers (trace.py stage_summary totals and
+percentiles over raw duration lists) are per-process and
 un-mergeable: N workers each holding a sorted list of durations cannot
 produce a fleet p99 without shipping every sample.  This module is the
 mergeable replacement the fleet observability plane exports inside obs

@@ -65,7 +65,7 @@ FIELDS = (
     "rows_in", "rows_out", "bytes_in", "bytes_out",
     "h2d_bytes", "d2h_bytes",
     "h2d_encoded_bytes", "h2d_raw_equiv_bytes",
-    "launches", "compiles", "compile_seconds", "kernel_seconds",
+    "launches", "compiles", "compile_seconds", "device_wait_seconds",
     "decode_wait_seconds", "queue_wait_seconds",
     "retries", "lease_steals", "chaos_fires",
     # staged two-phase sink commits (abstract/commit.py): granted

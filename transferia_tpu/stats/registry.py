@@ -189,7 +189,8 @@ class TransformStats(_Bundle):
 class DeviceStats(_Bundle):
     """Device-link counters (stats/trace.py DeviceTelemetry folds its
     deltas in here so /metrics exposes the link physics: H2D/D2H bytes
-    and transfer counts, launches, XLA compiles, kernel wall time)."""
+    and transfer counts, launches, XLA compiles, the host's wait for
+    device results)."""
 
     def __init__(self, metrics: Optional[Metrics] = None):
         super().__init__(metrics)
@@ -200,7 +201,7 @@ class DeviceStats(_Bundle):
         self.launches = self.m.counter("device_launches")
         self.compiles = self.m.counter("device_xla_compiles")
         self.compile_seconds = self.m.counter("device_xla_compile_seconds")
-        self.kernel_seconds = self.m.counter("device_kernel_seconds")
+        self.device_wait_seconds = self.m.counter("device_wait_seconds")
         # decode-pipeline readahead (providers/readahead.py): prefetch
         # queue depth and in-flight decoded bytes — host-side gauges,
         # but they live with the link physics because overlapping host

@@ -1,15 +1,16 @@
 """End-to-end pipeline tracing: spans, device telemetry, Perfetto export.
 
 The sampling profiler (stats/profiler.py) answers "which frame burns
-CPU"; stagetimer answers "how much wall per stage".  Neither shows the
-*timeline*: whether device waits overlap host packing, where a batch
-stalls between parsequeue and the sink, or when an XLA recompile lands
-inside the measured window.  This module records begin/end spans into a
-bounded ring buffer and exports Chrome trace-event JSON loadable in
-Perfetto / `chrome://tracing` — the span-level attribution Thallus-style
-transport analysis needs (PAPERS.md) and the per-stage transfer
-accounting the Arrow Flight benchmarking work shows wire-speed columnar
-systems live or die on.
+CPU".  It does not show the *timeline*: whether device waits overlap
+host packing, where a batch stalls between parsequeue and the sink, or
+when an XLA recompile lands inside the measured window.  This module
+records begin/end spans into a bounded ring buffer and exports Chrome
+trace-event JSON loadable in Perfetto / `chrome://tracing` — the
+span-level attribution Thallus-style transport analysis needs
+(PAPERS.md) and the per-stage transfer accounting the Arrow Flight
+benchmarking work shows wire-speed columnar systems live or die on.
+It is the one recorder of stage time: `stage_summary()` is the per-stage
+wall breakdown the bench prints.
 
 Design constraints:
 
@@ -30,12 +31,21 @@ roots `part` / `batch` / `replication_attempt` carry identity args
 under them.  `device_dispatch`/`device_wait` carry byte counts as args.
 `decode_readahead` spans live on the prefetcher worker threads
 (providers/readahead.py) — decode running there shows as its own
-track, overlapping the part's downstream spans.
+track, overlapping the part's downstream spans.  Waits that are known
+only once they end (`queue_wait`, `decode_wait`) are recorded with
+`complete()`, which takes self time from no parent.
+
+While tracing is on every span also enters a
+`jax.profiler.TraceAnnotation` of the same name, so a `jax.profiler`
+trace taken at the same time holds the program's spans in its host
+plane, on the threads that ran them and on the device events' clock.
 
 `DeviceTelemetry` is the always-on counter half: H2D/D2H bytes and
 transfer counts, device launches, XLA compile events (hooked via jax's
 monitoring events — fired exactly on jit-cache misses that reach the
-backend compiler), and per-kernel wall time.  It folds into the
+backend compiler; persistent-cache loads are told apart from real
+compiles), the host's wait for device results, and `auto` placement's
+decisions.  It folds into the
 prometheus `Metrics` facade via `fold_into()` (stats/registry.py
 DeviceStats).
 
@@ -75,6 +85,10 @@ _tls = threading.local()
 # mark — "how many new records since my last export" — without the
 # record tuples themselves needing sequence fields.
 _recorded = 0
+# jax.profiler.TraceAnnotation, looked up once by enable(); None without
+# jax.  Entered by every Span beside its own clock (enabled path only).
+_annotation = None
+_annotation_tried = False
 
 
 class SpanContext(NamedTuple):
@@ -128,7 +142,7 @@ _NOOP = _NoopSpan()
 
 class Span:
     __slots__ = ("name", "args", "_t0", "_child",
-                 "trace_id", "span_id", "parent_id", "_token")
+                 "trace_id", "span_id", "parent_id", "_token", "_ann")
 
     def __init__(self, name: str, args: Optional[dict] = None):
         self.name = name
@@ -139,6 +153,7 @@ class Span:
         self.span_id = 0
         self.parent_id = 0
         self._token = None
+        self._ann = None
 
     def __bool__(self):
         return True
@@ -167,12 +182,18 @@ class Span:
         else:
             self.trace_id = self.span_id  # a new root starts its trace
         self._token = _ctx.set(SpanContext(self.trace_id, self.span_id))
+        if _annotation is not None:
+            self._ann = _annotation(self.name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         dur = t1 - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._token is not None:
             _ctx.reset(self._token)
             self._token = None
@@ -204,6 +225,20 @@ def enable(on: bool = True, capacity: Optional[int] = None) -> None:
     _enabled = on
     if on:
         install_jit_hooks()
+        _find_annotation()
+
+
+def _find_annotation() -> None:
+    """Import the profiler's annotation class once (no-op without jax)."""
+    global _annotation, _annotation_tried
+    if _annotation_tried:
+        return
+    _annotation_tried = True
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:  # pragma: no cover - jax optional
+        return
+    _annotation = TraceAnnotation
 
 
 def enabled() -> bool:
@@ -249,13 +284,21 @@ def instant(name: str, ctx: Optional[SpanContext] = None,
                       args or None, trace_id, 0, parent_id))
 
 
+# depth of a complete() record: it sat on no thread's stack.  >= 0 so
+# every span reader keeps it; stage_summary tells it from thread time
+WAIT_DEPTH = 1 << 20
+
+
 def complete(name: str, t0: float, dur: float,
              parent: Optional[SpanContext] = None, **args) -> None:
     """Record a span RETROACTIVELY from wall measurements already taken
     (`t0` in time.perf_counter seconds).  This is how queue-wait style
     intervals — observed only once they end, on whatever thread ends
     them — still land as real spans on the owning trace (fleet ticket
-    queue wait, admission→dispatch)."""
+    queue wait, admission→dispatch).  Such a record is an item's
+    passive wait, not what its thread was doing: it overlaps that
+    thread's real spans, deducts from no parent, and carries
+    WAIT_DEPTH so stage_summary keeps it out of the stage shares."""
     if not _enabled:
         return
     at = parent if parent is not None else _ctx.get()
@@ -267,7 +310,7 @@ def complete(name: str, t0: float, dur: float,
     with _lock:
         _recorded += 1
         _ring.append((name, t.ident, t.name, t0 - _epoch, dur,
-                      dur, 0, args or None, trace_id, span_id,
+                      dur, WAIT_DEPTH, args or None, trace_id, span_id,
                       parent_id))
 
 
@@ -471,15 +514,20 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
     """Per-stage aggregation: calls, p50/p99 ms, total and self seconds,
     bytes moved (summed from span `bytes` args), plus wall span and the
     overlap factor (sum of self-times / wall — >1 means stages overlap
-    across threads; the ratio between stages is the signal)."""
+    across threads; the ratio between stages is the signal).  Records
+    made by complete() are waits, not thread time: they come back
+    under `waits`, outside `stages` and the overlap factor."""
     recorded = [s for s in spans() if s[6] >= 0]
     per: dict[str, dict] = {}
+    wait_names = set()
     t_min, t_max = None, None
-    for name, _tid, _tn, t0, dur, self_s, _depth, args in (
+    for name, _tid, _tn, t0, dur, self_s, depth, args in (
             s[:8] for s in recorded):
         d = per.setdefault(name, {"calls": 0, "total_s": 0.0,
                                   "self_s": 0.0, "bytes": 0,
                                   "durs": []})
+        if depth == WAIT_DEPTH:
+            wait_names.add(name)
         d["calls"] += 1
         d["total_s"] += dur
         d["self_s"] += self_s
@@ -490,8 +538,7 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
         t_max = max(t_max or 0.0, t0 + dur)
     wall = wall_seconds if wall_seconds else (
         (t_max - t_min) if recorded else 0.0)
-    out: dict[str, dict] = {}
-    for name, d in per.items():
+    for d in per.values():
         durs = sorted(d.pop("durs"))
         n = len(durs)
         d["p50_ms"] = round(durs[max(0, (n + 1) // 2 - 1)] * 1000, 3)
@@ -499,38 +546,62 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
             durs[max(0, min(n - 1, int(0.99 * n)))] * 1000, 3)
         d["total_s"] = round(d["total_s"], 4)
         d["self_s"] = round(d["self_s"], 4)
-        out[name] = d
-    total_self = sum(d["self_s"] for d in out.values())
+
+    def largest_first(names) -> dict:
+        return dict(sorted(((n, per[n]) for n in names),
+                           key=lambda kv: -kv[1]["self_s"]))
+
+    stages = largest_first(per.keys() - wait_names)
+    total_self = sum(d["self_s"] for d in stages.values())
     return {
         "wall_s": round(wall, 4),
         "overlap_factor": round(total_self / wall, 3) if wall else 0.0,
-        "stages": dict(sorted(out.items(),
-                              key=lambda kv: -kv[1]["self_s"])),
+        "stages": stages,
+        "waits": largest_first(wait_names),
     }
 
 
-def format_summary(wall_seconds: Optional[float] = None) -> str:
-    """Human table for `trtpu trace` / bench output."""
+def format_summary(wall_seconds: Optional[float] = None,
+                   one_line: bool = False) -> str:
+    """Human table for `trtpu trace` / bench output; `one_line` gives
+    `stage=1.23s(45%) ... overlap_factor=x` (self seconds as a share
+    of the wall, largest first, waits left out) for bench's result."""
     s = stage_summary(wall_seconds)
+    if one_line:
+        wall = s["wall_s"]
+        parts = [
+            f"{name}={d['self_s']:.2f}s"
+            f"({100.0 * d['self_s'] / wall if wall else 0.0:.0f}%)"
+            for name, d in s["stages"].items()]
+        if not parts:
+            return ""
+        parts.append(f"overlap_factor={s['overlap_factor']:.2f}")
+        return " ".join(parts)
     lines = [
         f"wall={s['wall_s']:.2f}s overlap_factor={s['overlap_factor']}",
         f"{'stage':<18} {'calls':>7} {'p50_ms':>9} {'p99_ms':>9} "
         f"{'total_s':>8} {'self_s':>8} {'bytes':>12}",
     ]
-    for name, d in s["stages"].items():
+    for name, d in (*s["stages"].items(),
+                    *((f"~{n}", d) for n, d in s["waits"].items())):
         lines.append(
             f"{name:<18} {d['calls']:>7} {d['p50_ms']:>9.2f} "
             f"{d['p99_ms']:>9.2f} {d['total_s']:>8.2f} "
             f"{d['self_s']:>8.2f} {d['bytes']:>12}")
+    if s["waits"]:
+        lines.append("~ a wait recorded once it ended: "
+                     "in no stage share, not in overlap_factor")
     tel = TELEMETRY.snapshot()
     if tel["device_launches"] or tel["compile_events"]:
         lines.append(
             f"device: launches={tel['device_launches']} "
             f"h2d={tel['h2d_bytes']}B/{tel['h2d_transfers']}x "
             f"d2h={tel['d2h_bytes']}B/{tel['d2h_transfers']}x "
-            f"kernel={tel['kernel_seconds']:.3f}s "
+            f"device_wait={tel['device_wait_seconds']:.3f}s "
             f"compiles={tel['compile_events']} "
-            f"({tel['compile_seconds']:.2f}s)")
+            f"({tel['compile_seconds']:.2f}s, of them "
+            f"{tel['compile_cache_hits']} cache loads "
+            f"{tel['compile_cache_seconds']:.2f}s)")
     return "\n".join(lines)
 
 
@@ -661,6 +732,11 @@ def _ledger():
     return LEDGER
 
 
+PLACEMENT_REASONS = ("pinned", "host_first", "device_explore",
+                     "link_gated", "winner_host", "winner_device",
+                     "reprobe")
+
+
 class DeviceTelemetry:
     """Always-on device-side counters (increments are per-dispatch, not
     per-row — a lock'd int add is noise next to a device launch).
@@ -684,7 +760,13 @@ class DeviceTelemetry:
             self.device_launches = 0
             self.compile_events = 0
             self.compile_seconds = 0.0
-            self.kernel_seconds = 0.0
+            # of those, the ones the persistent cache answered: jax
+            # reports a 0.2 s load and a 17 s compile as the same event
+            self.compile_cache_hits = 0
+            self.compile_cache_seconds = 0.0
+            # the host's wait for device results (np.asarray in
+            # ops/fused.py::_collect), not time the device ran
+            self.device_wait_seconds = 0.0
             # compressed dispatch plane (ops/dispatch.py): actual bytes
             # staged vs what the raw wire would have shipped, plus the
             # dict-pool residency economics
@@ -715,6 +797,10 @@ class DeviceTelemetry:
             # chip nothing but the predicate
             self.mask_route_rows = {"device_flat": 0, "device_pool": 0,
                                     "host_subset": 0}
+            # why each batch of a fused step went where it went
+            # (transform/fused.py DeviceFusedStep._pick_strategy), one
+            # count a batch
+            self.placements = dict.fromkeys(PLACEMENT_REASONS, 0)
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -792,16 +878,25 @@ class DeviceTelemetry:
         with self._lock:
             self.mask_route_rows[route] += int(n_rows)
 
-    def record_kernel(self, seconds: float) -> None:
-        _ledger().add(kernel_seconds=seconds)
+    def record_placement(self, reason: str) -> None:
         with self._lock:
-            self.kernel_seconds += seconds
+            # .get: a reason _decide adds must not fail the batch
+            self.placements[reason] = self.placements.get(reason, 0) + 1
 
-    def record_compile(self, seconds: float) -> None:
+    def record_device_wait(self, seconds: float) -> None:
+        _ledger().add(device_wait_seconds=seconds)
+        with self._lock:
+            self.device_wait_seconds += seconds
+
+    def record_compile(self, seconds: float,
+                       cache_hit: bool = False) -> None:
         _ledger().add(compiles=1, compile_seconds=seconds)
         with self._lock:
             self.compile_events += 1
             self.compile_seconds += seconds
+            if cache_hit:
+                self.compile_cache_hits += 1
+                self.compile_cache_seconds += seconds
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -815,7 +910,11 @@ class DeviceTelemetry:
                 "device_launches": self.device_launches,
                 "compile_events": self.compile_events,
                 "compile_seconds": round(self.compile_seconds, 4),
-                "kernel_seconds": round(self.kernel_seconds, 4),
+                "compile_cache_hits": self.compile_cache_hits,
+                "compile_cache_seconds":
+                    round(self.compile_cache_seconds, 4),
+                "device_wait_seconds":
+                    round(self.device_wait_seconds, 4),
                 "h2d_encoded_bytes": self.h2d_encoded_bytes,
                 "h2d_raw_equiv_bytes": self.h2d_raw_equiv_bytes,
                 "dispatch_compression_ratio": round(ratio, 2),
@@ -829,6 +928,8 @@ class DeviceTelemetry:
                     self.dict_flat_materializations,
                 **{f"mask_rows_{route}": n
                    for route, n in self.mask_route_rows.items()},
+                **{f"placement_{reason}": n
+                   for reason, n in self.placements.items()},
             }
 
     def fold_into(self, metrics) -> None:
@@ -854,7 +955,7 @@ class DeviceTelemetry:
                 "device_launches": self.device_launches,
                 "compile_events": self.compile_events,
                 "compile_seconds": self.compile_seconds,
-                "kernel_seconds": self.kernel_seconds,
+                "device_wait_seconds": self.device_wait_seconds,
                 "h2d_encoded_bytes": self.h2d_encoded_bytes,
                 "h2d_raw_equiv_bytes": self.h2d_raw_equiv_bytes,
                 "dict_pool_hits": self.dict_pool_hits,
@@ -875,7 +976,7 @@ class DeviceTelemetry:
                 ("device_launches", ds.launches),
                 ("compile_events", ds.compiles),
                 ("compile_seconds", ds.compile_seconds),
-                ("kernel_seconds", ds.kernel_seconds),
+                ("device_wait_seconds", ds.device_wait_seconds),
                 ("h2d_encoded_bytes", ds.h2d_encoded_bytes),
                 ("h2d_raw_equiv_bytes", ds.h2d_raw_equiv_bytes),
                 ("dict_pool_hits", ds.dict_pool_hits),
@@ -909,7 +1010,12 @@ def install_jit_hooks() -> None:
     (+ a trace instant).  The backend-compile event fires exactly when a
     jit cache miss reaches the XLA compiler — the recompile signal a
     bucketed-shape engine must watch (ARCHITECTURE.md shape
-    discipline).  Idempotent; silently a no-op without jax."""
+    discipline).  jax times `compile_or_get_cached` as a whole, so a
+    persistent-cache load fires the same event as a compile; the
+    cache's own `cache_hits` event fires inside that interval on the
+    same thread, and a thread-local flag carries it to the duration
+    event that closes the interval.  Idempotent; silently a no-op
+    without jax."""
     global _hooks_installed
     with _hooks_lock:
         if _hooks_installed:
@@ -919,10 +1025,18 @@ def install_jit_hooks() -> None:
         except ImportError:  # pragma: no cover - jax optional
             return
 
+        def _on_event(event: str, **kw) -> None:
+            if event.endswith("/compilation_cache/cache_hits"):
+                _tls.compile_cache_hit = True
+
         def _on_duration(event: str, duration: float, **kw) -> None:
             if event.endswith("backend_compile_duration"):
-                TELEMETRY.record_compile(duration)
-                instant("xla_compile", seconds=round(duration, 4))
+                hit = getattr(_tls, "compile_cache_hit", False)
+                _tls.compile_cache_hit = False
+                TELEMETRY.record_compile(duration, cache_hit=hit)
+                instant("xla_compile", seconds=round(duration, 4),
+                        cache_hit=hit)
 
+        _mon.register_event_listener(_on_event)
         _mon.register_event_duration_secs_listener(_on_duration)
         _hooks_installed = True

@@ -36,6 +36,8 @@ from transferia_tpu.abstract.schema import (
 from transferia_tpu.runtime import knobs
 from transferia_tpu.predicate.ast import TrueNode
 from transferia_tpu.columnar.batch import Column, ColumnBatch
+from transferia_tpu.stats import trace
+from transferia_tpu.stats.trace import TELEMETRY
 from transferia_tpu.transform.base import TransformResult, Transformer
 from transferia_tpu.transform.plugins.filter import FilterRows
 from transferia_tpu.transform.plugins.mask import MaskField
@@ -271,14 +273,29 @@ class DeviceFusedStep(Transformer):
     PROBE_HEADROOM = 4.0
 
     def _pick_strategy(self, n_rows: int = 0, batch=None) -> str:
+        strategy, reason, predicted = self._decide(n_rows, batch)
+        TELEMETRY.record_placement(reason)
+        if trace.enabled():
+            trace.instant(
+                "placement", strategy=strategy, reason=reason,
+                rows=n_rows,
+                host_ns_row=round(self._ns_row["host"], 1),
+                device_ns_row=round(self._ns_row["device"], 1),
+                predicted_device_ns_row=round(predicted, 1))
+        return strategy
+
+    def _decide(self, n_rows: int, batch) -> tuple[str, str, float]:
+        """(strategy, reason, the link model's ns/row for the device or
+        -1 where it was not asked).  The reason is one of
+        stats/trace.py PLACEMENT_REASONS."""
         mode = placement_mode()
         if mode in ("device", "host"):
-            return mode
+            return mode, "pinned", -1.0
         # auto: measure each strategy once, keep the winner, re-probe the
         # loser every REPROBE_EVERY batches (links drift — see linkprobe)
         host_ns, dev_ns = self._ns_row["host"], self._ns_row["device"]
         if host_ns < 0:
-            return "host"
+            return "host", "host_first", -1.0
         if dev_ns < 0:
             predicted = self._predict_device_ns_row(max(n_rows, 1), batch)
             if predicted > host_ns * self.PROBE_HEADROOM:
@@ -288,8 +305,8 @@ class DeviceFusedStep(Transformer):
                         "fused step %s placement: host (device gated by "
                         "link model: predicted %.0fns/row vs host "
                         "%.0fns/row)", self.describe(), predicted, host_ns)
-                return "host"
-            return "device"
+                return "host", "link_gated", predicted
+            return "device", "device_explore", predicted
         winner = "host" if host_ns <= dev_ns else "device"
         if self._batch_no % self.REPROBE_EVERY == self.REPROBE_EVERY - 1:
             loser = "device" if winner == "host" else "host"
@@ -298,15 +315,16 @@ class DeviceFusedStep(Transformer):
                 predicted = self._predict_device_ns_row(max(n_rows, 1),
                                                         batch)
                 if predicted > host_ns * self.PROBE_HEADROOM:
-                    return winner
-            return loser
+                    return winner, "link_gated", predicted
+                return loser, "reprobe", predicted
+            return loser, "reprobe", -1.0
         if not self._choice_logged:
             self._choice_logged = True
             logger.info(
                 "fused step %s placement: %s (host=%.0fns/row "
                 "device=%.0fns/row)", self.describe(), winner,
                 host_ns, dev_ns)
-        return winner
+        return winner, f"winner_{winner}", -1.0
 
     def _observe(self, strategy: str, seconds: float, n_rows: int) -> None:
         self._batch_no += 1
@@ -349,7 +367,6 @@ class DeviceFusedStep(Transformer):
             encoding_enabled,
         )
         from transferia_tpu.ops.fused import hex_to_varwidth
-        from transferia_tpu.stats.trace import TELEMETRY
 
         t0 = _time.perf_counter()
         program = self.program
@@ -452,9 +469,7 @@ class DeviceFusedStep(Transformer):
                 )
         else:
             hexes, keep = [], None  # everything rode the pool route
-        from transferia_tpu.stats import stagetimer, trace
-
-        with stagetimer.stage("host_post"), trace.span("host_post"):
+        with trace.span("host_post"):
             cols = dict(batch.columns)
             for (name, preserved), hx in zip(flat_entries, hexes):
                 if preserved:
@@ -483,7 +498,6 @@ class DeviceFusedStep(Transformer):
         """
         import time as _time
 
-        from transferia_tpu.stats import stagetimer, trace
         from transferia_tpu.transform.plugins.mask import (
             _host_hmac_hex,
             mask_dict_column,
@@ -495,7 +509,7 @@ class DeviceFusedStep(Transformer):
             keep = self._host_pred_fn(batch)
             if not keep.all():
                 cur = batch.filter(keep)
-        with stagetimer.stage("host_mask"), trace.span("host_mask"):
+        with trace.span("host_mask"):
             cols = dict(cur.columns)
             for name, key in self.mask_entries:
                 col = cur.column(name)
