@@ -491,9 +491,16 @@ def _sink_tree(recorded):
     return sink[0], kids
 
 
+@pytest.mark.parametrize("path", ["native", "numpy"])
 @pytest.mark.parametrize("staged", [False, True],
                          ids=["push", "stage_push"])
-def test_ch_sink_splits_into_serialize_and_sink_push(staged):
+def test_ch_sink_splits_into_serialize_and_sink_push(staged, path,
+                                                     monkeypatch):
+    if path == "numpy":  # the repo's switch; lib() caches the library
+        from transferia_tpu import native
+
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setenv("TRANSFERIA_TPU_NO_NATIVE", "1")
     from tests.recipes.fake_clickhouse import FakeCH
     from transferia_tpu.middlewares.sync import Statistician
     from transferia_tpu.providers.clickhouse.provider import (
@@ -529,6 +536,7 @@ def test_ch_sink_splits_into_serialize_and_sink_push(staged):
     ser, push = by_name["serialize"], by_name["sink_push"]
     assert ser[6] == push[6] == top[6] + 1
     assert ser[7]["format"] == "rowbinary" and ser[7]["rows"] == 64
+    assert ser[7]["path"] == path
     assert ser[7]["columns"] == len(batch.columns)
     assert push[7] == {"direction": "clickhouse_http",
                        "bytes": ser[7]["bytes"]}

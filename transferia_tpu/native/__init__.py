@@ -55,10 +55,16 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
     i64 = npc.ndpointer(np.int64, flags="C_CONTIGUOUS")
     u32 = npc.ndpointer(np.uint32, flags="C_CONTIGUOUS")
     u64 = npc.ndpointer(np.uint64, flags="C_CONTIGUOUS")
-    cdll.leb128_encode.argtypes = [u64, ctypes.c_int64, u8, i32]
-    cdll.leb128_encode.restype = ctypes.c_int64
-    cdll.scatter_bytes.argtypes = [u8, i64, i64, i64, ctypes.c_int64, u8]
-    cdll.scatter_bytes.restype = None
+    # RowBinary writer: per-column widths, then buffer addresses as u64
+    cdll.rowbinary_size.argtypes = [
+        ctypes.c_int64, ctypes.c_int32, i32, u64, u64, u8,
+    ]
+    cdll.rowbinary_size.restype = ctypes.c_int64
+    cdll.rowbinary_write.argtypes = [
+        ctypes.c_int64, ctypes.c_int32, i32, u64, u64, u64, u8,
+        ctypes.c_char_p,  # the bytes object the rows are written into
+    ]
+    cdll.rowbinary_write.restype = ctypes.c_int64
     cdll.gather_varwidth.argtypes = [u8, i32, i64, ctypes.c_int64, u8, i32]
     cdll.gather_varwidth.restype = ctypes.c_int64
     cdll.gather_var_offsets.argtypes = [i32, i64, ctypes.c_int64, i32]

@@ -4,8 +4,7 @@
 // device (XLA) and arrow (C++) carry most of the weight, and this small
 // library covers the residual host loops that numpy can't fully vectorize
 // without large temporaries:
-//   - LEB128 varint encoding (RowBinary string length prefixes)
-//   - interleaved byte scatter (columnar -> row-major RowBinary assembly)
+//   - the RowBinary writer (columnar -> row-major, the ClickHouse sink)
 //   - var-width gather (Column.take without index temporaries)
 //
 // Build: transferia_tpu/native/build.py (g++ -O3 -shared -fPIC).  All
@@ -17,34 +16,107 @@
 
 extern "C" {
 
-// values[n] -> out varint bytes; out_lens[n] = bytes written per value.
-// Returns total bytes written.  out must be preallocated (<= 10*n).
-int64_t leb128_encode(const uint64_t* values, int64_t n,
-                      uint8_t* out, int32_t* out_lens) {
-    int64_t pos = 0;
-    for (int64_t i = 0; i < n; i++) {
-        uint64_t v = values[i];
-        int32_t len = 0;
-        do {
-            uint8_t b = v & 0x7F;
-            v >>= 7;
-            out[pos++] = v ? (b | 0x80) : b;
-            len++;
-        } while (v);
-        out_lens[i] = len;
-    }
-    return pos;
+// ---------------------------------------------------------------------------
+// RowBinary writer (ClickHouse sink): a whole batch in two calls, the
+// row-major bytes written directly from the columns' own buffers.
+//
+// Column c is described by the c-th entry of five parallel arrays:
+//   widths[c]    wire width of a fixed-width value; 0 = var-width
+//   data[c]      address of the values (widths[c] bytes a row) or of the
+//                var-width byte buffer
+//   offsets[c]   address of the (n_rows + 1) int32 offsets; 0 for fixed
+//   validity[c]  address of n_rows bytes, nonzero = present; 0 = all present
+//   nullable[c]  nonzero: Nullable(T) on the wire - a prefix byte, 0x01
+//                for a null and nothing after it
+// A null in a column that is not nullable writes zeros (fixed) or the
+// empty string (var-width).  No state outlives a call: part threads
+// write their batches side by side.
+
+static inline int64_t rb_varint_len(uint32_t v) {
+    return v < (1u << 7) ? 1 : v < (1u << 14) ? 2 : v < (1u << 21) ? 3
+         : v < (1u << 28) ? 4 : 5;
 }
 
-// Scatter per-row fields into row-major output:
-//   out[dst_offsets[i] .. +lens[i]] = src[src_offsets[i] .. +lens[i]]
-void scatter_bytes(const uint8_t* src, const int64_t* src_offsets,
-                   const int64_t* dst_offsets, const int64_t* lens,
-                   int64_t n, uint8_t* out) {
-    for (int64_t i = 0; i < n; i++) {
-        memcpy(out + dst_offsets[i], src + src_offsets[i],
-               (size_t)lens[i]);
+// Bytes the batch takes on the wire, summed column by column; -1 when a
+// var-width column's offsets decrease (nothing may be written from them).
+int64_t rowbinary_size(int64_t n_rows, int32_t n_cols,
+                       const int32_t* widths, const uint64_t* offsets,
+                       const uint64_t* validity, const uint8_t* nullable) {
+    int64_t total = 0;
+    for (int32_t c = 0; c < n_cols; c++) {
+        const uint8_t* valid = (const uint8_t*)validity[c];
+        if (widths[c]) {
+            int64_t values = n_rows;
+            if (nullable[c]) {
+                total += n_rows;
+                if (valid) {
+                    values = 0;
+                    for (int64_t r = 0; r < n_rows; r++)
+                        values += valid[r] != 0;
+                }
+            }
+            total += values * widths[c];
+            continue;
+        }
+        const int32_t* off = (const int32_t*)offsets[c];
+        for (int64_t r = 0; r < n_rows; r++) {
+            int64_t len = (int64_t)off[r + 1] - off[r];
+            if (len < 0) return -1;
+            if (valid && !valid[r])
+                total += 1;  // the null prefix, or the empty string
+            else
+                total += (nullable[c] != 0) + rb_varint_len((uint32_t)len)
+                       + len;
+        }
     }
+    return total;
+}
+
+// Write every row's fields in column order into out (rowbinary_size
+// bytes); returns the bytes written.
+int64_t rowbinary_write(int64_t n_rows, int32_t n_cols,
+                        const int32_t* widths, const uint64_t* data,
+                        const uint64_t* offsets, const uint64_t* validity,
+                        const uint8_t* nullable, uint8_t* out) {
+    uint8_t* p = out;
+    for (int64_t r = 0; r < n_rows; r++) {
+        for (int32_t c = 0; c < n_cols; c++) {
+            const uint8_t* valid = (const uint8_t*)validity[c];
+            const bool null = valid && !valid[r];
+            if (nullable[c]) {
+                *p++ = null;
+                if (null) continue;
+            }
+            const int32_t w = widths[c];
+            if (w) {
+                if (null) {
+                    memset(p, 0, (size_t)w);
+                } else {
+                    const uint8_t* src = (const uint8_t*)data[c] + r * w;
+                    switch (w) {  // a constant size is one move
+                        case 1: *p = *src; break;
+                        case 2: memcpy(p, src, 2); break;
+                        case 4: memcpy(p, src, 4); break;
+                        case 8: memcpy(p, src, 8); break;
+                        default: memcpy(p, src, (size_t)w);
+                    }
+                }
+                p += w;
+                continue;
+            }
+            const int32_t* off = (const int32_t*)offsets[c];
+            uint32_t len = null ? 0 : (uint32_t)(off[r + 1] - off[r]);
+            uint32_t v = len;
+            while (v >= 0x80) {
+                *p++ = (uint8_t)(v | 0x80);
+                v >>= 7;
+            }
+            *p++ = (uint8_t)v;
+            memcpy(p, (const uint8_t*)data[c] + off[r], len);
+            p += len;
+        }
+    }
+    return p - out;
 }
 
 // Gather var-width rows: for each index idx[i], copy

@@ -38,7 +38,10 @@ from transferia_tpu.models.endpoint import (
     register_endpoint,
 )
 from transferia_tpu.providers.clickhouse.client import CHClient, CHError
-from transferia_tpu.providers.clickhouse.rowbinary import encode_rowbinary
+from transferia_tpu.providers.clickhouse.rowbinary import (
+    encode_rowbinary,
+    encoder_path,
+)
 from transferia_tpu.providers.registry import (
     Provider,
     TestResult,
@@ -318,8 +321,9 @@ class CHSinker(Sinker, StagedSinker):
         with sp:
             payload = encode_rowbinary(batch, nullable)
             if sp:
-                sp.add(format="rowbinary", rows=batch.n_rows,
-                       columns=len(batch.columns), bytes=len(payload))
+                sp.add(format="rowbinary", path=encoder_path(),
+                       rows=batch.n_rows, columns=len(batch.columns),
+                       bytes=len(payload))
         sp = trace.span("sink_push")
         if sp:
             sp.add(direction="clickhouse_http", bytes=len(payload))

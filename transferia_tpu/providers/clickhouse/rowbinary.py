@@ -1,12 +1,17 @@
-"""Vectorized RowBinary encoder/decoder.
+"""RowBinary encoder/decoder.
 
 RowBinary is row-major (per row: each column's fixed-width value or
-varint-length-prefixed bytes), which fights columnar layouts; the encoder
-here never loops over rows in Python — per column it computes each row's
-field byte-length, derives global row offsets with cumsums, and scatters
-column bytes into the output with flat numpy gathers (the same
-repeat/arange pattern the SHA kernel prep uses).  The decoder is the
-inverse and powers the CH snapshot source.
+varint-length-prefixed bytes), which fights columnar layouts.
+`encode_rowbinary` hands the whole batch to the native writer
+(`native/hostops.cpp`: `rowbinary_size`, then `rowbinary_write` straight
+from the columns' own buffers into one output, the GIL released), and
+does per column only what has to be numpy: the cast to the wire dtype.
+The numpy encoder below it is the same bytes without the library
+(`TRANSFERIA_TPU_NO_NATIVE=1`) and the reference the tests compare the
+writer with: it never loops over rows in Python - per column it computes
+each row's field byte-length, derives global row offsets with cumsums,
+and scatters column bytes into the output with flat numpy gathers.  The
+decoder is the inverse and powers the CH snapshot source.
 
 Type wire formats (ClickHouse RowBinary):
   ints/floats: little-endian fixed width
@@ -19,6 +24,7 @@ Type wire formats (ClickHouse RowBinary):
 
 from __future__ import annotations
 
+import ctypes
 from typing import Optional
 
 import numpy as np
@@ -39,22 +45,8 @@ def _leb128_lengths(values: np.ndarray) -> np.ndarray:
 
 
 def _encode_varints(values: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """values -> (flat varint bytes, per-value byte length).
-
-    Uses the native hostops kernel when available (single pass, no
-    temporaries); numpy multi-pass otherwise.
-    """
-    from transferia_tpu.native import lib
-
+    """values -> (flat varint bytes, per-value byte length)."""
     n = len(values)
-    cdll = lib()
-    if cdll is not None and n:
-        out = np.empty(n * 10, dtype=np.uint8)
-        lens = np.empty(n, dtype=np.int32)
-        total = cdll.leb128_encode(
-            np.ascontiguousarray(values, dtype=np.uint64), n, out, lens
-        )
-        return out[:total].copy(), lens.astype(np.int64)
     vlens = _leb128_lengths(values)
     offsets = np.zeros(n + 1, dtype=np.int64)
     np.cumsum(vlens, out=offsets[1:])
@@ -205,13 +197,92 @@ def _encode_column(col: Column, nullable: bool) -> _EncodedColumn:
     return _EncodedColumn(out, field_lens)
 
 
+# a prototype of our own: ctypes.pythonapi's attribute is the process's
+_new_bytes = ctypes.PYFUNCTYPE(
+    ctypes.py_object, ctypes.c_void_p, ctypes.c_ssize_t,
+)(("PyBytes_FromStringAndSize", ctypes.pythonapi))
+
+
+def encoder_path() -> str:
+    """Which encoder `encode_rowbinary` runs, as the `serialize` span
+    names it: "native" unless the library is switched off."""
+    from transferia_tpu.native import lib
+
+    return "numpy" if lib() is None else "native"
+
+
 def encode_rowbinary(batch: ColumnBatch,
                      nullable: Optional[dict[str, bool]] = None) -> bytes:
     """ColumnBatch -> RowBinary bytes (column order = batch.columns order)."""
-    n = batch.n_rows
-    if n == 0:
+    if batch.n_rows == 0:
         return b""
+    from transferia_tpu.native import lib
+
     nullable = nullable or {}
+    cdll = lib()
+    if cdll is None:
+        return _encode_numpy(batch, nullable)
+    return _encode_native(cdll, batch, nullable)
+
+
+def _encode_native(cdll, batch: ColumnBatch,
+                   nullable: dict[str, bool]) -> bytes:
+    n = batch.n_rows
+    n_cols = len(batch.columns)
+    widths = np.zeros(n_cols, dtype=np.int32)
+    data = np.zeros(n_cols, dtype=np.uint64)
+    offsets = np.zeros(n_cols, dtype=np.uint64)
+    validity = np.zeros(n_cols, dtype=np.uint64)
+    nullable_flags = np.zeros(n_cols, dtype=np.uint8)
+    held = []  # the buffers whose addresses the writer reads
+    for c, (name, col) in enumerate(batch.columns.items()):
+        fixed = _fixed_width(col.ctype)
+        if fixed is not None:
+            dt, widths[c] = fixed
+            values = np.ascontiguousarray(col.data.astype(dt, copy=False))
+            if values.shape != (n,):
+                raise ValueError(
+                    f"column {name}: {values.shape} values in a batch of "
+                    f"{n} rows")
+        else:
+            values = np.ascontiguousarray(col.data, dtype=np.uint8)
+            off = np.ascontiguousarray(col.offsets, dtype=np.int32)
+            if off.shape != (n + 1,) or off[0] < 0 \
+                    or off[-1] > len(values):
+                raise ValueError(
+                    f"column {name}: offsets do not fit {n} rows over "
+                    f"{len(values)} bytes")
+            offsets[c] = off.ctypes.data
+            held.append(off)
+        data[c] = values.ctypes.data
+        held.append(values)
+        if col.validity is not None:
+            valid = np.ascontiguousarray(col.validity, dtype=np.bool_)
+            if valid.shape != (n,):
+                raise ValueError(
+                    f"column {name}: validity of {valid.shape} in a "
+                    f"batch of {n} rows")
+            validity[c] = valid.ctypes.data
+            held.append(valid)
+        nullable_flags[c] = nullable.get(name, col.validity is not None)
+    total = cdll.rowbinary_size(n, n_cols, widths, offsets, validity,
+                                nullable_flags)
+    if total < 0:
+        raise ValueError("a var-width column's offsets decrease")
+    # the result itself, uninitialised until the writer has filled it (a
+    # numpy buffer and tobytes() would copy the batch once more, holding
+    # the GIL)
+    out = _new_bytes(None, total)
+    written = cdll.rowbinary_write(n, n_cols, widths, data, offsets,
+                                   validity, nullable_flags, out)
+    if written != total:
+        raise RuntimeError(
+            f"rowbinary writer wrote {written} of {total} bytes")
+    return out
+
+
+def _encode_numpy(batch: ColumnBatch, nullable: dict[str, bool]) -> bytes:
+    n = batch.n_rows
     encoded = [
         _encode_column(col, nullable.get(name,
                                          col.validity is not None))
@@ -224,25 +295,15 @@ def encode_rowbinary(batch: ColumnBatch,
     np.cumsum(row_lens, out=row_offsets[1:])
     out = np.zeros(int(row_offsets[-1]), dtype=np.uint8)
     field_start = row_offsets[:-1].copy()
-    from transferia_tpu.native import lib
-
-    cdll = lib()
     for e in encoded:
         lens = e.lens
         total = int(lens.sum())
         if total:
             src_off = np.zeros(n, dtype=np.int64)
             np.cumsum(lens[:-1], out=src_off[1:])
-            if cdll is not None:
-                cdll.scatter_bytes(
-                    np.ascontiguousarray(e.data),
-                    src_off, np.ascontiguousarray(field_start),
-                    np.ascontiguousarray(lens), n, out,
-                )
-            else:
-                inner = np.arange(total) - np.repeat(src_off, lens)
-                dst = np.repeat(field_start, lens) + inner
-                out[dst] = e.data
+            inner = np.arange(total) - np.repeat(src_off, lens)
+            dst = np.repeat(field_start, lens) + inner
+            out[dst] = e.data
         field_start += lens
     return out.tobytes()
 
