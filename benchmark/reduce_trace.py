@@ -8,7 +8,9 @@ Two steps, so that the arithmetic can be checked on a small recorded trace
 Busy is the union of the intervals in which an operation ran on a device
 (the `XLA Ops` line of each `/device:TPU:n` plane; the `XLA Modules` line
 where a plane has no such line), averaged over the devices that ran
-anything.  A gap is attributed to the program span (stats/trace.py) it is
+anything (`busy_s`) and device by device (`busy_per_device_s`, one entry
+per device plane that ran anything, in the planes' order).  A gap is
+attributed to the program span (stats/trace.py) it is
 most about (see `_covering_span`), where the two clocks can be aligned.  The spans' epoch is
 known on the wall clock (`trace.epoch_unix()`), and so is the moment
 `start_trace` returned.  On the v5e the profiler stamps device events in
@@ -27,6 +29,9 @@ import re
 
 OPS_LINE, MODULES_LINE = "XLA Ops", "XLA Modules"
 TOP = 10
+# stats/trace.py::WAIT_DEPTH, the depth field of a `trace.complete()` record:
+# an item's passive wait (`queue_wait`, `decode_wait`), nothing a thread did
+WAIT_DEPTH = 1 << 20
 
 
 def load_xplane(path: str) -> dict:
@@ -129,7 +134,8 @@ def reduce(doc: dict, window_s: float, spans: list = (),
         return [[k, v] for k, v in
                 sorted(d.items(), key=lambda kv: -kv[1])[:TOP]]
 
-    return {"busy_s": busy_s, "window_s": window_s, "clock": clock,
+    return {"busy_s": busy_s, "busy_per_device_s": busy_per_device,
+            "window_s": window_s, "clock": clock,
             "devices_busy": len(busy_per_device), "modules": modules,
             "longest_gap_s": max((b - a for a, b in gaps), default=0) / 1e9,
             "breakdown": {"device_ops": top(ops), "idle_gaps": top(idle)}}
@@ -153,11 +159,14 @@ def _covering_span(a: int, b: int, walls: list) -> str:
     """The span that the gap [a, b) is most about: overlap squared over the
     span's length, so that a span lying inside the gap, or one the gap lies
     inside of and not much longer, beats a root span that covers the whole
-    window and every gap in it."""
+    window and every gap in it.  A record of an item's wait says what the
+    item did, not the host, and is passed over."""
     best, best_score = "unattributed", 0.0
-    for s0, s1, _depth, name in walls:
+    for s0, s1, depth, name in walls:
         if s0 >= b:
             break
+        if depth == WAIT_DEPTH:
+            continue
         overlap = min(b, s1) - max(a, s0)
         if overlap > 0:
             score = overlap * overlap / max(s1 - s0, 1)

@@ -229,6 +229,23 @@ def span_totals(spans: list, top: int = 30) -> dict:
     return dict(sorted(per.items(), key=lambda kv: -kv[1][1])[:top])
 
 
+def compiles(spans: list) -> list:
+    """Every compile or persistent-cache load the window saw, in order of
+    time: [seconds into the spans' epoch, seconds it took, whether the cache
+    answered, the span it fired in, that span's args] - `device_dispatch`
+    carries the rows and bytes of the chunk whose shape was new."""
+    by_id = {s[9]: s for s in spans if s[6] >= 0}
+    out = []
+    for s in spans:
+        if s[0] == "xla_compile" and s[6] < 0:
+            args = s[7] or {}
+            inside = by_id.get(s[10])
+            out.append([s[3], args.get("seconds"), args.get("cache_hit"),
+                        inside[0] if inside else None,
+                        (inside[7] if inside else None) or {}])
+    return sorted(out, key=lambda c: c[0])
+
+
 def read_per_layer(bench: dict, cell_name: str, data: dict) -> dict:
     """Every per-layer metric that lists this cell, by the reader its file
     names; a reader that finds nothing to read leaves its metric out."""
@@ -306,6 +323,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: int,
                 transfer_text, {**values, "DATA_PATH": warm_dir}, cell,
                 os.path.join(work_dir, "transfer-warm.yaml"))
 
+        if trace:
+            # spans on from warm-up on, as the traced window will run
+            from transferia_tpu.stats import trace as program_trace
+
+            program_trace.enable(True, capacity=2_000_000)
         account = kind.drive(ctx)
 
         stats = [d.memory_stats() or {} for d in jax.devices()]
@@ -320,9 +342,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: int,
         compared = world("verify", **verify_args)
         numbers = dict(kind.account_numbers(account))
         numbers.update(compared["numbers"])
-        end_to_end = kind.end_to_end(account)
+        # of what the kind measures, the metrics BENCHMARK.json gives this
+        # cell end to end
+        units = {m["name"]: m["unit"] for m in bench["end_to_end"]
+                 if name in m.get("workloads", [name])}
+        end_to_end = {k: v for k, v in kind.end_to_end(account).items()
+                      if k in units}
         end_to_end["setup_s"] = ctx.setup_s
-        units = {m["name"]: m["unit"] for m in bench["end_to_end"]}
         result = {
             "correct": all(v <= lim for v, lim in numbers.values()),
             "attempted": compared["attempted"],
@@ -349,8 +375,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: int,
             result["metrics"] = read_per_layer(bench, name, data)
             result["breakdown"] = reduced["breakdown"]
             result["span_totals"] = span_totals(ctx.spans)
+            result["compiles"] = compiles(ctx.spans)
             result["trace_check"] = {
                 k: reduced[k] for k in ("clock", "devices_busy",
+                                        "busy_per_device_s",
                                         "longest_gap_s", "modules")}
         else:
             result["metrics"] = {
@@ -362,6 +390,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: int,
         if "passes" in account:
             result["account"]["pass_seconds"] = [
                 p["seconds"] for p in account["passes"]]
+            result["account"]["pass_compile_seconds"] = [
+                p["compiled"]["compile_seconds"] for p in account["passes"]]
             result["account"]["standin_cost"] = \
                 account["passes"][-1]["standin_cost"]
         result["info"] = compared["info"]

@@ -33,6 +33,7 @@ def test_busy_is_the_union_of_the_op_intervals():
     out = rt.reduce(doc, window_s=6.0)
     assert out["devices_busy"] == 1
     assert abs(out["busy_s"] * 1e9 - busy_ns) < 1
+    assert out["busy_per_device_s"] == [out["busy_s"]]    # one chip
     assert out["busy_s"] < (hi - lo) / 1e9 < 6.0
     assert out["clock"] == "unknown"          # no wall clock was given
     assert out["breakdown"]["idle_gaps"][0][0] == "unattributed"
@@ -81,6 +82,48 @@ def test_gaps_are_attributed_on_the_trace_start_clock():
     assert abs(gaps["process"] - (6.0 - hi / 1e9)) < 1e-6
     assert "unattributed" not in gaps
     assert abs(sum(gaps.values()) + out["busy_s"] - 6.0) < 0.02
+
+
+def test_busy_is_told_device_by_device():
+    doc = _doc()
+    one = rt.reduce(doc, window_s=6.0)["busy_s"]
+    # a second chip that ran the first eight of the first one's
+    # operations, and a third whose plane holds nothing
+    some = [dict(ln, events=ln["events"][:8])
+            for ln in doc["planes"][0]["lines"]]
+    ops = next(ln["events"] for ln in some if ln["name"] == rt.OPS_LINE)
+    doc["planes"] += [{"name": "/device:TPU:1", "lines": some},
+                      {"name": "/device:TPU:2", "lines": []}]
+    out = rt.reduce(doc, window_s=6.0)
+    assert out["devices_busy"] == 2 and len(out["busy_per_device_s"]) == 2
+    assert out["busy_per_device_s"][0] == one
+    assert abs(out["busy_per_device_s"][1] * 1e9
+               - _brute_busy_ns(ops)[0]) < 1
+    assert 0 < out["busy_per_device_s"][1] < one
+    assert out["busy_s"] == sum(out["busy_per_device_s"]) / 2
+    assert rt.reduce({"planes": []}, 3.0)["busy_per_device_s"] == []
+
+
+def test_an_items_wait_names_no_gap():
+    from transferia_tpu.stats import trace
+
+    assert rt.WAIT_DEPTH == trace.WAIT_DEPTH
+    doc = _doc()
+    t0 = 1_790_000_000 * 10**9
+    epoch = 1_790_000_000.0
+    # catch-up's window: the push loop waits for the flush (`sink_wait`, a
+    # span a thread is in) while fetched batches queue (`queue_wait`,
+    # recorded afterwards by `trace.complete()`, covering the same seconds
+    # more closely)
+    spans = [("sink_wait", 1, "t", 0.0, 6.5, 6.5, 1),
+             ("queue_wait", 1, "t", 0.0, 6.0, 6.0, rt.WAIT_DEPTH)]
+    out = rt.reduce(doc, 6.0, spans, epoch, (t0, t0 + 6 * 10**9))
+    gaps = dict(out["breakdown"]["idle_gaps"])
+    assert set(gaps) == {"sink_wait"}
+    assert abs(gaps["sink_wait"] + out["busy_s"] - 6.0) < 0.02
+    # with nothing else over a gap it is unattributed, not the wait's
+    out = rt.reduce(doc, 6.0, spans[1:], epoch, (t0, t0 + 6 * 10**9))
+    assert set(dict(out["breakdown"]["idle_gaps"])) == {"unattributed"}
 
 
 def test_a_trace_in_which_nothing_ran_reads_the_chip_idle():

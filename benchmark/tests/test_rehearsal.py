@@ -70,6 +70,14 @@ def test_rehearsal_of_each_cell(cell, capsys):
                  if cell in m.get("workloads", [cell])}
         assert set(result["metrics"]) == names
         assert all(v["value"] > 0 for v in result["metrics"].values())
+    if "snapshot" in cell:
+        # set-up held a part pass and a whole one, and says what each
+        # compiled or loaded; so does every pass of the window
+        acc = result["account"]
+        assert acc["warm_part_seconds"] > 0 and acc["warm_pass_seconds"] > 0
+        assert acc["warm_part_telemetry"]["compile_events"] >= 0
+        assert acc["warm_telemetry"]["compile_events"] >= 0
+        assert len(acc["pass_compile_seconds"]) == len(acc["pass_seconds"])
     # a rehearsal prints no result line
     assert '"correct"' not in capsys.readouterr().out
 

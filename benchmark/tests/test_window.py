@@ -4,6 +4,7 @@ a stall inside the window lowers the rate and raises the tail."""
 
 import http.client
 import json
+import os
 import struct
 import threading
 import time
@@ -181,32 +182,69 @@ def test_backlog_window_closes_on_an_insert_and_a_stall_lowers_the_rate():
 def test_a_snapshot_window_is_a_whole_number_of_passes(monkeypatch):
     import transferia_tpu.cli.main as cli
 
-    calls = []
+    calls, asked = [], []
 
     def activate(argv):
         calls.append(argv)
         time.sleep(0.2)
         return 0
 
+    def world(cmd, **kw):
+        asked.append((cmd, kw, len(calls)))
+        return {"rows": 686, "tables": ["hits"], "standin_cost": {},
+                "server_errors": []}
+
     monkeypatch.setattr(cli, "main", activate)
     ctx = types.SimpleNamespace(
         cell={"params": {"min_passes": 2}}, seconds=0.5,
-        config={"table": {"rows": 1000}},
-        transfer_yaml="t.yaml", warm_yaml="w.yaml",
-        world=lambda cmd, **kw: {"rows": 686, "tables": ["hits"],
-                                 "standin_cost": {},
-                                 "server_errors": []},
-        window_open=lambda: None, window_close=lambda: None,
+        config={"table": {"rows": 1000}}, transfer_yaml="t.yaml",
+        warm_yaml="w.yaml", world=world,
+        window_open=lambda: asked.append(("open", {}, len(calls))),
+        window_close=lambda: None,
         trace_start=lambda: None, trace_stop=lambda: None)
     acc = snapshot_passes.drive(ctx)
     assert len(acc["passes"]) == 3            # the third started at 0.4 s
     assert acc["window_s"] >= 0.6
-    assert calls[0][-1] == "w.yaml" and calls[1][-1] == "t.yaml"
-    got = snapshot_passes.end_to_end(acc)["snapshot_rows_per_s"]
-    assert got == 3000 / acc["window_s"]
+    # warm-up is the first part file alone, then the transfer's own whole
+    # pass; what they landed is taken out of the world before the window
+    # opens and is in no rate
+    assert [c[-1] for c in calls] == ["w.yaml"] + ["t.yaml"] * 4
+    assert asked[:3] == [("pass_end", {"in_window": False}, 1),
+                         ("pass_end", {"in_window": False}, 2),
+                         ("open", {}, 2)]
+    assert all(kw == {"in_window": True} for _c, kw, _n in asked[3:])
+    assert 0.2 <= acc["warm_part_seconds"] < 0.4     # one call each
+    assert 0.2 <= acc["warm_pass_seconds"] < 0.4
+    for counted in (acc["warm_part_telemetry"], acc["warm_telemetry"],
+                    acc["passes"][0]["compiled"]):
+        assert set(counted) == set(snapshot_passes.COMPILE_COUNTERS)
+    got = snapshot_passes.end_to_end(acc)
+    assert got["snapshot_rows_per_s"] == 3000 / acc["window_s"]
+    # the rate per layer where the median stands end to end: the same rows
+    # over the passes' own seconds (a traced window also holds the
+    # profiler's stop, which is no pass)
+    assert acc["window_rows"] == 3000
+    assert acc["pass_seconds_sum"] == sum(
+        p["seconds"] for p in acc["passes"]) <= acc["window_s"]
+    # the median pass: the middle one of three, whatever the slowest took
+    took = sorted(p["seconds"] for p in acc["passes"])
+    assert got["snapshot_pass_p50_s"] == took[1]
+    acc["passes"][0]["seconds"] += 20.0
+    assert snapshot_passes.end_to_end(acc)["snapshot_pass_p50_s"] \
+        in took[1:]
     # a window shorter than a pass still holds `min_passes` of them
     ctx.seconds = 0.05
     assert len(snapshot_passes.drive(ctx)["passes"]) == 2
+    # and one that `--seconds` would keep open closes at `max_passes`: a
+    # cell that gives both the same number does the same work in every run
+    for seconds, params, held in ((5.0, {"min_passes": 2, "max_passes": 4}, 4),
+                                  (0.05, {"min_passes": 4, "max_passes": 4}, 4),
+                                  (0.5, {"min_passes": 1, "max_passes": 4}, 3)):
+        ctx.seconds, ctx.cell = seconds, {"params": params}
+        acc = snapshot_passes.drive(ctx)
+        assert len(acc["passes"]) == held
+        assert snapshot_passes.end_to_end(acc)["snapshot_rows_per_s"] \
+            == held * 1000 / acc["window_s"]
 
 
 def test_a_fetch_response_is_filled_to_the_requests_bytes():
@@ -238,3 +276,26 @@ def test_span_time_is_divided_by_the_rows_the_spans_worked_on():
                  "per": "consumed_in_window"}, data) == 1.5 / 4e-3
     assert read({"spans": ["pivot"]}, data) is None
     assert read({"spans": ["sink"], "per": "nothing"}, data) is None
+
+
+def test_span_time_can_be_held_to_the_spans_args():
+    from benchmark.readers import span_self_time
+
+    # one name, three callers: the ClickHouse POST, the asynchronizer's
+    # wrapper around it (no args), the arrow_ipc write
+    spans = [("sink_push", 0, 0, 0, 1.0, 0.75, 2,
+              {"direction": "clickhouse_http", "bytes": 10}),
+             ("sink_push", 0, 0, 0, 1.25, 0.25, 1, None),
+             ("sink_push", 0, 0, 0, 0.5, 0.5, 1, {"direction": "ipc"})]
+    data = {"spans": spans, "rows": 1000, "account": {}}
+    read = span_self_time.read
+    assert read({"spans": ["sink_push"]}, data) == 1.5 / 1e-3
+    where = {"direction": "clickhouse_http"}
+    assert read({"spans": ["sink_push"], "where": where}, data) == 0.75 / 1e-3
+    assert read({"spans": ["sink_push"],
+                 "where": {"direction": "flight"}}, data) is None
+    for family in ("snapshot", "pii", "catchup"):
+        with open(os.path.join(
+                os.path.dirname(span_self_time.__file__), os.pardir,
+                "metrics", f"sink_wire_s_per_mrow.{family}.json")) as fh:
+            assert json.load(fh)["params"]["where"] == where
