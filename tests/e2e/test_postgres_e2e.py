@@ -182,3 +182,113 @@ def test_pg_ddl_objects_transfer(fake_pg):
         assert sum(len(tb.rows) for tb in dst.tables.values()) > 0
     finally:
         dst.stop()
+
+
+def _lineitem(n=120):
+    import datetime
+
+    rows = []
+    for i in range(n):
+        rows.append({
+            "l_orderkey": str(1 + i // 4), "l_linenumber": str(1 + i % 4),
+            "l_quantity": f"{1 + i % 50}.00",
+            "l_discount": f"0.{i % 11:02d}",
+            "l_extendedprice": f"{900 + i * 37}.{i % 100:02d}",
+            "l_shipdate": (datetime.date(1993, 11, 1)
+                           + datetime.timedelta(days=5 * i)).isoformat(),
+            "l_comment": None if i % 13 == 0 else f"note, {i}",
+        })
+    return FakeTable("public", "lineitem", [
+        ("l_orderkey", "bigint", True, True),
+        ("l_linenumber", "integer", True, True),
+        ("l_quantity", "numeric(15,2)", False, True),
+        ("l_discount", "numeric(15,2)", False, True),
+        ("l_extendedprice", "numeric(15,2)", False, True),
+        ("l_shipdate", "date", False, True),
+        ("l_comment", "character varying(44)", False, False),
+    ], rows=rows, rows_per_page=7)
+
+
+Q6 = ("l_shipdate >= '1994-01-01' AND l_shipdate < '1995-01-01' "
+      "AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24")
+
+
+@pytest.mark.parametrize("placement", ["host", "device", "auto"])
+def test_pg_numeric_and_date_through_ctid_parts(placement):
+    """A `numeric(15,2)` and a `date` column from COPY text through ctid
+    parts and a filter_rows step on both: precision and scale reach the
+    schema, the filter keeps what Decimal and date arithmetic keep, and
+    the source's spans are recorded."""
+    import datetime
+    from decimal import Decimal
+
+    from transferia_tpu.providers.postgres.provider import PGStorage
+    from transferia_tpu.abstract.table import TableDescription
+    from transferia_tpu.stats import trace
+    from transferia_tpu.transform.fused import set_placement
+
+    table = _lineitem()
+    srv = FakePG().start()
+    srv.add_table(table)
+    src = pg_src(srv, desired_part_size_bytes=3 * 8192, batch_rows=16)
+    tid = TableID("public", "lineitem")
+    store = get_store("pg-li-" + placement)
+    store.clear()
+    trace.enable(True)
+    trace.reset()
+    set_placement(placement)
+    try:
+        storage = PGStorage(src)
+        schema = storage.table_schema(tid)
+        disc = schema.find("l_discount")
+        assert disc.original_type == "pg:numeric(15,2)"
+        assert dict(disc.properties) == {"precision": 15, "scale": 2}
+        assert schema.find("l_shipdate").data_type.value == "date"
+        parts = storage.shard_table(TableDescription(id=tid))
+        storage.close()
+        assert len(parts) == 6 and all("ctid" in p.filter for p in parts)
+        t = Transfer(
+            id="pg-li-" + placement, src=src,
+            dst=MemoryTargetParams(sink_id="pg-li-" + placement),
+            transformation={"transformers": [
+                {"filter_rows": {"filter": Q6}}]})
+        activate_delivery(t, MemoryCoordinator())
+        spans = trace.spans()
+    finally:
+        set_placement(None)
+        trace.enable(False)
+        srv.stop()
+    want = sorted(
+        (int(r["l_orderkey"]), int(r["l_linenumber"])) for r in table.rows
+        if datetime.date(1994, 1, 1)
+        <= datetime.date.fromisoformat(r["l_shipdate"])
+        < datetime.date(1995, 1, 1)
+        and Decimal("0.05") <= Decimal(r["l_discount"]) <= Decimal("0.07")
+        and Decimal(r["l_quantity"]) < 24)
+    assert 3 <= len(want) < 40
+    got = store.rows(tid)
+    assert sorted((r.value("l_orderkey"), r.value("l_linenumber"))
+                  for r in got) == want
+    by_key = {(int(r["l_orderkey"]), int(r["l_linenumber"])): r
+              for r in table.rows}
+    for r in got:
+        src_row = by_key[(r.value("l_orderkey"), r.value("l_linenumber"))]
+        # the text Postgres sent, untouched
+        assert str(r.value("l_extendedprice")) == src_row["l_extendedprice"]
+        assert str(r.value("l_discount")) == src_row["l_discount"]
+        assert r.value("l_comment") == src_row["l_comment"]
+    names = {}
+    for s in spans:
+        if s[6] >= 0:
+            names.setdefault(s[0], []).append(s[7] or {})
+    decodes = [a for a in names["source_decode"]
+               if a.get("format") == "pg_copy"]
+    assert sum(a["rows"] for a in decodes) == len(table.rows)
+    assert sum(a["bytes"] for a in names["pg_copy_read"]) == \
+        sum(a["bytes"] for a in decodes) > 0
+    assert len(names["pg_copy_read"]) >= 6
+    assert names["decimal_view"] and all(
+        a["columns"] == 1 for a in names["decimal_view"])
+    coerce, = {tuple(sorted(s[7].items())) for s in spans
+               if s[0] == "predicate_coerce" and s[6] < 0}
+    assert dict(coerce)["l_shipdate"] == "[8766, 9131]"

@@ -29,12 +29,22 @@ class _PGStateError(Exception):
 
 class FakeTable:
     def __init__(self, namespace: str, name: str, columns: list[tuple],
-                 rows: list[dict] | None = None):
+                 rows: list[dict] | None = None,
+                 rows_per_page: int | None = None):
         # columns: (name, pg_type, is_pk, notnull)
         self.namespace = namespace
         self.name = name
         self.columns = columns
         self.rows = rows or []
+        # None: a single-page table (every ctid range sees every row);
+        # n: a heap of 8 KiB pages of n rows, which pg_relation_size,
+        # relpages and ctid ranges are answered from
+        self.rows_per_page = rows_per_page
+
+    def pages(self) -> int:
+        if not self.rows_per_page:
+            return 1
+        return -(-len(self.rows) // self.rows_per_page)
 
 
 class FakePG:
@@ -51,6 +61,9 @@ class FakePG:
         self.lock = threading.RLock()
         self.port = 0
         self._srv = None
+        # rows a CopyData message of COPY TO STDOUT (csv) carries: a
+        # PostgreSQL backend sends one
+        self.copy_rows_per_message = 4096
         # replication state
         self.slots: dict[str, str] = {}          # slot -> plugin
         self.wal: list[tuple[int, bytes]] = []   # (lsn, wal2json payload)
@@ -307,9 +320,14 @@ class _Session:
             m = re.search(r"'\"?(\w+)\"?\.\"?(\w+)\"?'", sql)
             t = fake.tables.get((m.group(1), m.group(2))) if m else None
             size = len(t.rows) * 100 if t else 0
+            if t is not None and t.rows_per_page:
+                size = t.pages() * 8192
             return self.send_rows(["size"], [[size]])
         if "relpages" in low:
-            return self.send_rows(["relpages"], [[1]])
+            m = re.search(r"'\"?(\w+)\"?\.\"?(\w+)\"?'", sql)
+            t = fake.tables.get((m.group(1), m.group(2))) if m else None
+            return self.send_rows(["relpages"],
+                                  [[t.pages() if t else 1]])
         if low.startswith("copy (") and "to stdout" in low:
             return self.copy_out(sql)
         if low.startswith("copy ") and "from stdin" in low:
@@ -450,7 +468,13 @@ class _Session:
             if "random()" in cond:
                 rows = rows[::7]  # deterministic "random" subsample
             elif "ctid" in cond:
-                pass  # single-page tables: every part sees all rows
+                # single-page tables: every part sees all rows
+                mc = re.search(r"ctid >= '\((\d+),0\)'::tid AND "
+                               r"ctid < '\((\d+),0\)'::tid", cond)
+                if t.rows_per_page and mc:
+                    per = t.rows_per_page
+                    rows = rows[int(mc.group(1)) * per:
+                                int(mc.group(2)) * per]
             elif '" = ' in cond or '"=' in cond:
                 keysets = []
                 for group in re.findall(r"\(([^()]*)\)", cond):
@@ -518,17 +542,21 @@ class _Session:
         # boundaries and the client's 32MB reflush relies on it.  The
         # previous per-row Python loop capped the fake ~3x below what the
         # client under test can ingest.
+        # NULL is the unquoted empty field and the empty string is `""`,
+        # as COPY's csv mode writes them (csv.writer quotes neither: the
+        # empty string rides a placeholder).
         out = io.StringIO()
         w = csv.writer(out, lineterminator="\n")
-        chunk_rows = 4096
+        chunk_rows = self.fake.copy_rows_per_message
         for lo in range(0, len(rows), chunk_rows):
             out.seek(0)
             out.truncate()
             w.writerows(
-                [["" if row.get(c) is None else row.get(c)
+                [["" if row.get(c) is None
+                  else "\x01" if row.get(c) == "" else row.get(c)
                   for c in cols]
                  for row in rows[lo:lo + chunk_rows]])
-            payload = out.getvalue().encode()
+            payload = out.getvalue().replace("\x01", '""').encode()
             self.sock.sendall(
                 b"d" + struct.pack("!I", len(payload) + 4) + payload)
         self.send(b"c")

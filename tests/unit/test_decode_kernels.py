@@ -610,10 +610,13 @@ def _op_names(lowered) -> set:
     return set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
 
 
-def test_fused_program_carries_named_scopes_under_its_old_name():
+@pytest.mark.parametrize("mask_keys", [[b"salt"], []],
+                         ids=["mask_and_filter", "filter_alone"])
+def test_fused_program_carries_named_scopes_under_its_old_name(mask_keys):
     """The parts of the fused program are named in the compiled HLO's
     op metadata (what a profiler trace shows beside `%while.220`); the
-    module keeps its name, which the benchmark's roofline matches."""
+    module keeps its name, which the benchmark's rooflines match - with
+    masks, and for a run of filters alone (predicate inputs only)."""
     from transferia_tpu.columnar.batch import bucket_rows
     from transferia_tpu.ops.fused import FusedMaskFilterProgram
 
@@ -622,16 +625,19 @@ def test_fused_program_carries_named_scopes_under_its_old_name():
     data = np.frombuffer(b"".join(bufs), dtype=np.uint8).copy()
     offsets = _offsets_from_lengths([len(b) for b in bufs])
     region = np.arange(n, dtype=np.int32)
-    prog = FusedMaskFilterProgram([b"salt"], parse("region < 400"))
+    prog = FusedMaskFilterProgram(mask_keys, parse("region < 400"))
     blocks, nblocks, pred, states, spec, _rows, _h2d = prog._stage(
-        [(data, offsets)], {"region": (region, None)}, n, bucket_rows(n))
+        [(data, offsets)] if mask_keys else [],
+        {"region": (region, None)}, n, bucket_rows(n))
     lowered = prog._jit.lower(blocks, nblocks, states, pred, spec)
     names = _op_names(lowered)
     assert all(nm.startswith("jit(program)/") for nm in names
                if "/" in nm)
-    for scope in ("mask_hmac/hmac_inner/", "mask_hmac/hmac_outer/",
-                  "sha256_rounds/", "sha256_schedule/", "sha256_words/",
-                  "pred_decode/", "predicate/", "keep_pack/"):
+    scopes = ("pred_decode/", "predicate/", "keep_pack/")
+    if mask_keys:
+        scopes += ("mask_hmac/hmac_inner/", "mask_hmac/hmac_outer/",
+                   "sha256_rounds/", "sha256_schedule/", "sha256_words/")
+    for scope in scopes:
         assert any(scope in nm for nm in names), scope
 
 

@@ -303,3 +303,85 @@ def test_pipelined_chunk_exact_multiple():
     finally:
         set_chunk_rows(None)
     batches_equal(host, dev)
+
+
+FILTER_ONLY = {"transformers": [
+    {"filter_rows": {"filter": "region < 400"}},
+    {"filter_rows": {"filter": "width >= 390 AND region IS NOT NULL"}},
+]}
+
+
+def test_filter_only_chain_is_a_fused_step():
+    set_device_fusion(True)
+    try:
+        plan = build_chain(FILTER_ONLY).plan_for(TID, SCHEMA)
+        assert len(plan.steps) == 1
+        step = plan.steps[0]
+        assert isinstance(step, DeviceFusedStep)
+        assert step.mask_entries == [] and step.pred_cols == [
+            "region", "width"]
+        # a filter the device cannot take (string, 64-bit) stays a host
+        # step, and an always-true one alone is no run at all
+        for text in ("url = 'x'", "big > 5", ""):
+            plan = build_chain({"transformers": [
+                {"filter_rows": {"filter": text}}]}).plan_for(TID, SCHEMA)
+            assert not any(isinstance(s, DeviceFusedStep)
+                           for s in plan.steps)
+    finally:
+        set_device_fusion(None)
+
+
+@pytest.mark.parametrize("n", [300, 20000])   # one device; the mesh
+@pytest.mark.parametrize("placement", ["host", "device"])
+def test_filter_only_both_strategies_give_the_host_filters_rows(
+        n, placement):
+    batch = make_batch(n)
+    host = run_chain(FILTER_ONLY, batch, fused=False)
+    assert 0 < host.n_rows < n
+    batches_equal(host, run_chain(FILTER_ONLY, batch, fused=True,
+                                  placement=placement))
+
+
+def test_filter_only_run_is_placed_by_what_auto_measures():
+    """The run reaches `_pick_strategy` like any other: host first, then
+    the link model's word on the device, counted under placement_*; and
+    the model charges a run without a mask no mask compute."""
+    from transferia_tpu.stats.trace import TELEMETRY
+    from transferia_tpu.transform.fused import (
+        DEVICE_MASK_ROWS_PER_S,
+        set_placement,
+    )
+
+    set_device_fusion(True)
+    set_placement("auto")
+    try:
+        chain = build_chain(FILTER_ONLY)
+        step, = chain.plan_for(TID, SCHEMA).steps
+        before = TELEMETRY.snapshot()
+        batch = make_batch(4096)
+        outs = [chain.apply(batch) for _ in range(4)]
+        after = TELEMETRY.snapshot()
+        reasons = {k: after[k] - before[k] for k in after
+                   if k.startswith("placement_") and after[k] != before[k]}
+        assert sum(reasons.values()) == 4
+        assert reasons["placement_host_first"] == 1
+        assert set(reasons) <= {
+            "placement_host_first", "placement_link_gated",
+            "placement_device_explore", "placement_winner_host",
+            "placement_winner_device"}
+        rows = (after["filter_rows_host"] - before["filter_rows_host"]
+                + after["filter_rows_device"] - before["filter_rows_device"])
+        assert rows == 4 * 4096
+        host = run_chain(FILTER_ONLY, batch, fused=False)
+        for out in outs:
+            batches_equal(host, out)
+        masked, = build_chain(CONFIG).plan_for(TID, SCHEMA).steps
+        n = 131072
+        link_only = step._predict_device_ns_row(n, None)
+        with_mask = masked._predict_device_ns_row(n, None)
+        assert with_mask - link_only > 1e9 / DEVICE_MASK_ROWS_PER_S
+        h2d, d2h = step._estimate_link_bytes(n, None)
+        assert h2d < 2 * 9 * n and d2h <= n
+    finally:
+        set_device_fusion(None)
+        set_placement(None)

@@ -8,6 +8,7 @@ whole mesh, kept-count + shard-histogram psums crossing it.
 """
 
 import numpy as np
+import pytest
 
 import jax
 
@@ -258,10 +259,24 @@ def test_sharded_encoders_roundtrip_host():
             np.asarray(vv), validity.reshape(4, 512)[d])
 
 
-def test_sharded_program_names_its_body_and_its_psum():
+@pytest.mark.parametrize("mask_keys,scopes", [
+    ([b"k"], ("mask_hmac/hmac_inner/", "pred_decode/", "predicate/",
+              "shard_hist/", "mesh_psum/")),
+    # a run of filters alone: predicate inputs only, the same names
+    ([], ("pred_decode/", "predicate/", "shard_hist/", "mesh_psum/")),
+], ids=["mask_and_filter", "filter_alone"])
+def test_sharded_program_names_its_body_and_its_psum(mask_keys, scopes):
     """The sharded body's parts and the two psums carry named scopes in
-    the compiled HLO's op metadata (what a profiler trace shows)."""
-    prog = ShardedFusedProgram([b"k"], parse("region < 400"))
+    the compiled HLO's op metadata (what a profiler trace shows, and what
+    a four-chip cell's roofline and a later collectives metric find the
+    program by)."""
+    import jax
+
+    # an inner jit that an earlier test of this worker traced under
+    # another scope keeps that trace's names (the driver's -n 6 run puts
+    # other files' tests before this one): start from no trace
+    jax.clear_caches()
+    prog = ShardedFusedProgram(mask_keys, parse("region < 400"))
     lowered = {}
     get_compiled = prog._get_compiled
 
@@ -281,8 +296,99 @@ def test_sharded_program_names_its_body_and_its_psum():
     offsets = np.zeros(n + 1, dtype=np.int32)
     np.cumsum([len(v) for v in vals], out=offsets[1:])
     region = (np.arange(n) % 500).astype(np.int32)
-    prog.run([(data, offsets)], {"region": (region, None)}, n)
+    prog.run([(data, offsets)] if mask_keys else [],
+             {"region": (region, None)}, n)
+    assert prog.last_kept == int((region < 400).sum())
     names = lowered["names"]
-    for scope in ("mask_hmac/hmac_inner/", "pred_decode/", "predicate/",
-                  "shard_hist/", "mesh_psum/"):
+    for scope in scopes:
         assert any(scope in nm for nm in names), scope
+    if not mask_keys:
+        assert not any("mask_hmac/" in nm for nm in names)
+
+
+# -- what a four-chip snapshot met (PERF.md section 6, PR 27) -------------------
+#
+# Every part file brings dictionary pools of its own sizes, and eight part
+# threads reach a new signature together: neither may mean a compile each.
+
+def _dict_input(n_values, n_rows, seed):
+    from transferia_tpu.parallel.fusedmesh import DictMaskInput
+
+    rng = np.random.default_rng(seed)
+    digests = rng.integers(0, 2**32, (n_values, 8), dtype=np.uint64) \
+        .astype(np.uint32)
+    codes = rng.integers(0, n_values, n_rows).astype(np.int32)
+    return DictMaskInput(codes, digests, 68), digests, codes
+
+
+def test_sharded_program_pool_sizes_of_one_bucket_share_a_program():
+    from transferia_tpu.columnar.hexcol import digests_to_hex
+
+    prog = ShardedFusedProgram([b"k"], None)
+    n = 8 * 1024
+    sizes = []
+    for n_values in (5, 7, 164, 168):
+        entry, digests, codes = _dict_input(n_values, n, n_values)
+        hexes, keep = prog.run([entry], {}, n)
+        np.testing.assert_array_equal(
+            np.asarray(hexes[0]), digests_to_hex(digests[codes]))
+        fn = next(iter(prog._compiled.values()))
+        sizes.append(fn._cache_size())
+    assert len(prog._compiled) == 1
+    assert sizes == [sizes[0]] * 4, "a pool size compiled a program"
+    # another bucket is another shape, once
+    entry, _d, _c = _dict_input(300, n, 300)
+    prog.run([entry], {}, n)
+    assert fn._cache_size() == sizes[0] + 1
+
+
+def test_sharded_program_module_is_named_as_the_one_chip_program():
+    """The profiler's module name is what `mask_program_roofline` finds the
+    program by: jit_program, on one chip and on a mesh."""
+    prog = ShardedFusedProgram([b"k"], None)
+    fn = prog._get_compiled(("flat",), (), "bits")
+    assert fn.__name__ == "program"
+
+
+def test_sharded_program_threads_compile_a_signature_once(monkeypatch):
+    import threading
+
+    from transferia_tpu.parallel import fusedmesh
+
+    traced = []
+    core = fusedmesh.hmac_device_core
+
+    def counting_core(*args):
+        traced.append(1)        # runs while the program is traced
+        return core(*args)
+
+    monkeypatch.setattr(fusedmesh, "hmac_device_core", counting_core)
+    n = 8 * 1024
+    _, data, offsets = _varwidth(n, prefix="t")
+    region = (np.arange(n) % 500).astype(np.int32)
+    before = len(ShardedFusedProgram._compiled_sigs)
+    gate = threading.Barrier(4)
+    kept, errors = [], []
+
+    def work():
+        try:
+            own = ShardedFusedProgram([b"k"], parse("region < 417"))
+            gate.wait()
+            own.run([(data, offsets)], {"region": (region, None)}, n)
+            kept.append(own.last_kept)
+        except Exception as e:  # surfaced below
+            errors.append(e)
+
+    threads = [threading.Thread(target=work) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors
+    assert kept == [int((region < 417).sum())] * 4
+    # four instances, one shared jit, one signature, traced once
+    assert len(ShardedFusedProgram._compiled_sigs) == before + 1
+    fns = [fn for k, fn in ShardedFusedProgram._jit_cache.items()
+           if k[0] == repr(parse("region < 417"))]
+    assert len(fns) == 1 and fns[0]._cache_size() == 1
+    assert len(traced) == 1, "threads traced one signature more than once"

@@ -77,6 +77,26 @@ def test_auto_reprobes_loser():
     assert step._pick_strategy(4096) == "host"
 
 
+def test_auto_weighs_a_strategy_by_rows_not_batches():
+    """A flush tick's batch of a few rows costs what a batch costs: read
+    as ns a row it must not make the strategy that took it the loser."""
+    set_device_fusion(True)
+    set_placement("auto")
+    chain = build_chain(CONFIG)
+    step = chain.plan_for(TID, make_batch(4).schema).steps[0]
+    step._observe("host", 0.050, 100_000)        # 500 ns a row
+    step._observe("device", 0.0, 100_000)        # carries the compile
+    step._observe("device", 1.5, 100_000)        # 15,000 ns a row
+    assert step._pick_strategy(100_000) == "host"
+    step._observe("host", 0.005, 100)            # a tick: 50,000 ns a row
+    assert step._ns_row["host"] < 600
+    assert step._pick_strategy(100_000) == "host"
+    # equal batches weigh as before: 0.7 of the old, 0.3 of the new
+    step._observe("host", 0.100, 100_000)
+    step._observe("host", 0.100, 100_000)
+    assert 600 < step._ns_row["host"] < 1000
+
+
 def test_auto_gates_device_probe_on_slow_link(monkeypatch):
     from transferia_tpu.ops import linkprobe as lp
 

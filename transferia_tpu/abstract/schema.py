@@ -6,8 +6,12 @@ schema type set there; we keep the same names minus the `yt` prefix).
 
 TPU-first notes: every canonical type carries a fixed-width device dtype.
 Variable-length types (STRING/UTF8/ANY) are represented on device as a
-byte tensor + int32 offsets (Arrow-style); DECIMAL travels as scaled int64
-pairs or utf8 depending on provider rules.  The schema fingerprint
+byte tensor + int32 offsets (Arrow-style).  DECIMAL travels the same way,
+as the text the source sent, which is what a sink lands; where the source's
+catalog gives precision and scale they ride `ColSchema.properties`
+(`("precision", p), ("scale", s)`), and predicates compare an exact view of
+the text, unscaled int64 integers at that scale (predicate/exact.py) - no
+float on the way.  The schema fingerprint
 (`TableSchema.fingerprint`) keys the per-table transformer plan cache and the
 XLA compilation cache, mirroring the reference's schema-hash keyed plan cache
 (pkg/transformer/transformation.go:47-60).
@@ -47,7 +51,8 @@ class CanonicalType(str, enum.Enum):
     DATETIME = "datetime"    # seconds since epoch (int64)
     TIMESTAMP = "timestamp"  # microseconds since epoch (int64)
     INTERVAL = "interval"    # microseconds (int64)
-    DECIMAL = "decimal"      # exact numeric; utf8 on the wire by default
+    DECIMAL = "decimal"      # exact numeric: its text for the sink, an
+    #                          unscaled-integer view for predicates
     ANY = "any"          # JSON-ish variant
 
     @property

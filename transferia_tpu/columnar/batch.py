@@ -424,7 +424,7 @@ class Column:
     """
 
     __slots__ = ("name", "ctype", "_data", "_offsets", "validity",
-                 "dict_enc")
+                 "dict_enc", "memo")
 
     def __init__(self, name: str, ctype: CanonicalType,
                  data: Optional[np.ndarray] = None,
@@ -437,6 +437,9 @@ class Column:
         self._offsets = offsets
         self.validity = validity
         self.dict_enc = dict_enc
+        # a derived form of this column's values, kept by who derived it
+        # (predicate/exact.py: a DECIMAL column's integers at its scale)
+        self.memo = None
         if ctype.is_variable_width:
             if offsets is None and dict_enc is None:
                 raise ValueError(
@@ -1072,7 +1075,10 @@ def _adopt_string_buffers(arr) -> tuple[np.ndarray, np.ndarray]:
         else np.zeros(0, dtype=np.uint8)
     if arr.offset:
         off = off[arr.offset:]
-    if off[0] != 0:
+    # a slice of a longer array (pyarrow's to_batches) shares its
+    # buffers: the bytes are those its own offsets span, no more - a
+    # concat of two such columns reads data by length
+    if off[0] != 0 or off[-1] != len(data):
         data = data[off[0]:off[-1]]
         off = off - off[0]
     return np.ascontiguousarray(data), np.ascontiguousarray(off)
@@ -1193,18 +1199,8 @@ def _arrow_to_column(cs: ColSchema, arr) -> Column:
             or pt.is_large_binary(t):
         if pt.is_large_string(t) or pt.is_large_binary(t):
             arr = arr.cast(pa.string() if pt.is_large_string(t) else pa.binary())
-        bufs = arr.buffers()
-        off = np.frombuffer(bufs[1], dtype=np.int32,
-                            count=len(arr) + 1 + arr.offset)
-        data = np.frombuffer(bufs[2], dtype=np.uint8) if bufs[2] is not None \
-            else np.zeros(0, dtype=np.uint8)
-        if arr.offset:
-            off = off[arr.offset:]
-        if off[0] != 0:
-            data = data[off[0]:off[-1]]
-            off = off - off[0]
-        return Column(cs.name, cs.data_type, np.ascontiguousarray(data),
-                      np.ascontiguousarray(off), validity)
+        data, off = _adopt_string_buffers(arr)
+        return Column(cs.name, cs.data_type, data, off, validity)
     if cs.data_type.is_variable_width:
         # canonical var-width but arrow gave a non-string type: stringify
         vals = arr.to_pylist()

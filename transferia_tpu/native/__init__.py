@@ -148,6 +148,8 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
     cdll.pq_decode_rowgroup.restype = ctypes.c_int64
     cdll.pq_codec_supported.argtypes = [ctypes.c_int32]
     cdll.pq_codec_supported.restype = ctypes.c_int32
+    cdll.pg_copy_unframe.argtypes = [u8, ctypes.c_int64, u8, i64]
+    cdll.pg_copy_unframe.restype = ctypes.c_int64
     return cdll
 
 

@@ -941,4 +941,27 @@ int64_t avro_decode_flat(const uint8_t* data, const int64_t* offs,
     return n_msgs;
 }
 
+// PostgreSQL COPY OUT, unframed in bulk: walks whole CopyData messages
+// ('d' + int32 length, inclusive of itself, + payload) from the start
+// of `buf`, writes their payloads end to end into `out` (room for n
+// bytes) and stops at the first message of another type, the first
+// incomplete one or a length under 4.  Returns the bytes consumed;
+// counts[0] = payload bytes written, counts[1] = messages.
+int64_t pg_copy_unframe(const uint8_t* buf, int64_t n, uint8_t* out,
+                        int64_t* counts) {
+    int64_t pos = 0, w = 0, msgs = 0;
+    while (pos + 5 <= n && buf[pos] == 'd') {
+        int64_t len = ((int64_t)buf[pos + 1] << 24) | (buf[pos + 2] << 16)
+                      | (buf[pos + 3] << 8) | buf[pos + 4];
+        if (len < 4 || pos + 1 + len > n) break;
+        memcpy(out + w, buf + pos + 5, (size_t)(len - 4));
+        w += len - 4;
+        pos += 1 + len;
+        msgs++;
+    }
+    counts[0] = w;
+    counts[1] = msgs;
+    return pos;
+}
+
 }  // extern "C"

@@ -234,6 +234,20 @@ def encode_for(data: np.ndarray
     return mins[0], pack_bits_host(rel[0], bw), bw, frame
 
 
+def narrow_pred_i32(values: np.ndarray) -> Optional[np.ndarray]:
+    """A predicate column whose values arrive wider than the chip
+    compares in (a DECIMAL column's int64 integers at its scale), in
+    int32 - or None where the batch's range does not fit it: the same
+    guard `_delta_plan` and `_for_plan` apply before they carry a column,
+    made here for the whole batch so that nothing is truncated under x32
+    on any wire.  The int32 column then encodes (delta / FOR / raw) like
+    any other."""
+    if len(values) and (int(values.min()) < -2**31
+                        or int(values.max()) > 2**31 - 1):
+        return None
+    return values.astype(np.int32)
+
+
 # -- per-column dispatch encodings ------------------------------------------
 
 @dataclass(frozen=True)

@@ -109,6 +109,12 @@ class ShardedFusedProgram:
     # statics; the HMAC key states are traced ARGUMENTS.  Bounded FIFO.
     _jit_cache: dict = {}
     _JIT_CACHE_MAX = 64
+    # signatures (jit + statics + argument shapes) that have compiled.
+    # A snapshot's part threads reach a new signature together; left to
+    # themselves each compiles it (jax.jit does not share a compile in
+    # flight), so the first call of a signature is made under a lock
+    _compiled_sigs: set = set()
+    _compile_lock = threading.Lock()
 
     def __init__(self, mask_keys: Sequence[bytes], pred_node,
                  mesh: Optional[Mesh] = None, n_shards: int = 16):
@@ -197,8 +203,13 @@ class ShardedFusedProgram:
             # histogram over the first masked column's digest words
             # (digests[0] is already computed above — XLA CSEs the reuse)
             with jax.named_scope("shard_hist"):
-                shard = (digests[0][:, 0]
-                         % jnp.uint32(self.n_shards)).astype(jnp.int32)
+                if digests:
+                    shard = (digests[0][:, 0]
+                             % jnp.uint32(self.n_shards)).astype(jnp.int32)
+                else:
+                    # a run of filters alone has no digest to spread
+                    # rows by: the kept rows count under shard 0
+                    shard = jnp.zeros(keep.shape, dtype=jnp.int32)
                 hist = jnp.zeros((self.n_shards,), dtype=jnp.int32).at[
                     shard].add(keep.astype(jnp.int32))
             with jax.named_scope("mesh_psum"):
@@ -257,8 +268,10 @@ class ShardedFusedProgram:
                     P(),                             # kept count
                 )
                 # max_blocks + bucket must stay static: strip them from
-                # specs and close over them per call instead
-                def wrapper(blocks_t, nblocks_t, states_t, codes_t,
+                # specs and close over them per call instead.  Named as
+                # ops/fused.py names its own: the profiler shows one
+                # module name, jit_program, on one chip and on a mesh
+                def program(blocks_t, nblocks_t, states_t, codes_t,
                             digs_t, pred_arrays, valid_arr,
                             max_blocks_t, bucket):
                     body = shard_map(
@@ -274,7 +287,7 @@ class ShardedFusedProgram:
                     return body(blocks_t, nblocks_t, states_t, codes_t,
                                 digs_t, pred_arrays, valid_arr)
 
-                fn = jax.jit(wrapper, static_argnums=(7, 8))
+                fn = jax.jit(program, static_argnums=(7, 8))
                 self._compiled[key] = fn
                 while len(shared) >= ShardedFusedProgram._JIT_CACHE_MAX:
                     shared.pop(next(iter(shared)), None)
@@ -313,7 +326,15 @@ class ShardedFusedProgram:
                 if total != n_rows:
                     codes = np.pad(codes, (0, total - n_rows))
                 codes_t.append(codes)
-                digs_t.append(entry.digests)
+                # the matrix's row count is a shape of the traced
+                # program: padded to a bucket, or every pool size (one a
+                # column a part file) compiles a program of its own.  No
+                # code points at a padding row
+                digs = entry.digests
+                pad = bucket_rows(len(digs)) - len(digs)
+                if pad:
+                    digs = np.pad(digs, ((0, pad), (0, 0)))
+                digs_t.append(digs)
                 routes.append("dict")
                 # honesty: charge what the flat wire would have shipped
                 # (bucket-padded SHA block matrix + per-row counts)
@@ -371,12 +392,18 @@ class ShardedFusedProgram:
             stage_h2d(stage_tree, raw_equiv_bytes=raw_equiv,
                       what="mesh", put=False)
         TELEMETRY.record_launch()
+        sig = (fn, tuple(mb_t), per_dev, tuple(
+            leaf.shape for leaf in jax.tree_util.tree_leaves(stage_tree)))
         with trace.span("device_dispatch", bytes=h2d, rows=n_rows,
                         mesh=self.n_dev):
-            digests_dev, keep_dev, hist, kept = fn(
-                blocks_s, nblocks_s, tuple(flat_states), codes_s,
-                digs_s, pred_s, valid_s, tuple(mb_t), per_dev,
-            )
+            args = (blocks_s, nblocks_s, tuple(flat_states), codes_s,
+                    digs_s, pred_s, valid_s, tuple(mb_t), per_dev)
+            if sig in ShardedFusedProgram._compiled_sigs:
+                digests_dev, keep_dev, hist, kept = fn(*args)
+            else:
+                with ShardedFusedProgram._compile_lock:
+                    digests_dev, keep_dev, hist, kept = fn(*args)
+                    ShardedFusedProgram._compiled_sigs.add(sig)
         t_wait0 = _time.perf_counter()
         with trace.span("device_wait") as sp:
             hexes = [digests_to_hex(np.asarray(h)[:n_rows])

@@ -29,6 +29,22 @@ from transferia_tpu.predicate.ast import (
 )
 
 
+def _scalar(c, v, column: str):
+    """The literal as the arrow column's type compares it: a day number
+    against a date, the exact decimal of its text against a decimal
+    (predicate/exact.py); a literal that cannot be coerced raises
+    ValueError and the batch is decoded unfiltered."""
+    import pyarrow as pa
+
+    from transferia_tpu.predicate import exact
+
+    if pa.types.is_date32(c.type):
+        return pa.scalar(exact.date_days(v, column), pa.int32()).cast(c.type)
+    if pa.types.is_decimal(c.type):
+        return pa.scalar(exact.as_decimal(v, column))
+    return pa.scalar(v)
+
+
 def _eval(node: Node, rb):
     """Nullable BooleanArray: null entries are the 3VL 'unknown'.
 
@@ -69,7 +85,7 @@ def _eval(node: Node, rb):
                ">=": pc.greater_equal}
         if node.op not in ops:
             raise ValueError(node.op)
-        return ops[node.op](c, pa.scalar(v))
+        return ops[node.op](c, _scalar(c, v, node.column))
     if isinstance(node, InList):
         c = col(node.column)
         non_null = [v for v in node.values if v is not None]
@@ -97,8 +113,8 @@ def _eval(node: Node, rb):
     if isinstance(node, Between):
         c = col(node.column)
         return pc.and_kleene(
-            pc.greater_equal(c, pa.scalar(node.low)),
-            pc.less_equal(c, pa.scalar(node.high)))
+            pc.greater_equal(c, _scalar(c, node.low, node.column)),
+            pc.less_equal(c, _scalar(c, node.high, node.column)))
     if isinstance(node, And):
         out = None
         for p in node.parts:

@@ -5,6 +5,24 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Sequence, Union
 
+
+class NumberText(float):
+    """A numeric literal written with a point or an exponent: a float to
+    every comparison that is made in floats, and the text it was written
+    as to the exact ones (a DECIMAL column compares as SQL `numeric`
+    does, on scaled integers - predicate/exact.py)."""
+
+    __slots__ = ("text",)
+
+    def __new__(cls, text: str):
+        self = float.__new__(cls, text)
+        self.text = str(text)
+        return self
+
+    def __getnewargs__(self):
+        return (self.text,)
+
+
 Literal = Union[int, float, str, bool, None]
 
 

@@ -17,6 +17,7 @@ import numpy as np
 
 from transferia_tpu.abstract.schema import CanonicalType
 from transferia_tpu.columnar.batch import Column, ColumnBatch
+from transferia_tpu.predicate import exact
 from transferia_tpu.predicate.ast import (
     And, Between, Cmp, InList, IsNull, Node, Not, Or, TrueNode,
 )
@@ -115,9 +116,15 @@ def _eval_cmp(node: Cmp, batch: ColumnBatch) -> np.ndarray:
     if node.value is None:
         # col = NULL is never true in SQL; use IS NULL instead
         return np.zeros(batch.n_rows, dtype=np.bool_)
+    if col.ctype == CanonicalType.DECIMAL and col.offsets is not None \
+            and node.op != "~":
+        return _eval_cmp_decimal(node, col, valid,
+                                 batch.schema.find(node.column))
     if col.offsets is None:
         if col.ctype == CanonicalType.BOOLEAN:
             lit = bool(node.value)
+        elif col.ctype == CanonicalType.DATE:
+            lit = exact.date_days(node.value, node.column)
         else:
             lit = node.value
         arr = col.data
@@ -146,6 +153,24 @@ def _eval_cmp(node: Cmp, batch: ColumnBatch) -> np.ndarray:
             ) from e
         return np.asarray(m, dtype=np.bool_) & valid
     return _eval_cmp_str(node, col, valid)
+
+
+_NUMPY_OPS = {"=": np.equal, "!=": np.not_equal, "<": np.less,
+              "<=": np.less_equal, ">": np.greater, ">=": np.greater_equal}
+
+
+def _eval_cmp_decimal(node: Cmp, col: Column, valid: np.ndarray,
+                      cs) -> np.ndarray:
+    """A DECIMAL (text) column against a number, as SQL `numeric`
+    compares: on integers at the column's scale (predicate/exact.py)."""
+    lit = exact.as_decimal(node.value, node.column)
+    scaled, scale = exact.decimal_scaled(col, exact.column_scale(cs))
+    folded = exact.fold(node.op, lit, scale, 64)
+    if folded == exact.NEVER:
+        return np.zeros(len(valid), dtype=np.bool_)
+    if folded == exact.ALWAYS:
+        return valid.copy()
+    return _NUMPY_OPS[folded[0]](scaled, folded[1]) & valid
 
 
 def _gather_eq(col: Column, candidates: np.ndarray, lit: bytes,

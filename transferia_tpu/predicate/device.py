@@ -6,13 +6,20 @@ emits the same masks as jnp expressions so the row filter can ride the same
 XLA launch as the HMAC mask and numeric casts (the fused transform step,
 ops/fused.py) instead of a separate host pass per batch.
 
-Device eligibility is deliberately narrow: only fixed-width columns whose
+Device eligibility is deliberately narrow: fixed-width columns whose
 dtype survives the x32 device boundary bit-exactly (bool, int8/16/32,
-uint8/16, float32, date32).  64-bit integers would be silently truncated by
-the jax x32 default and float64 comparisons would change answers in
-float32 — those predicates stay on the host path.  String comparisons stay
-host-side too (predicate/compile.py's length-prefiltered gathers are
-already vectorized and the device gain would be eaten by transfers).
+uint8/16, float32, date32), and DECIMAL columns whose scale the schema
+gives: those compare as integers at that scale (predicate/exact.py), in
+int32, and the fused step checks per batch that the values fit it - a
+batch that does not goes to the host and is counted.  64-bit integers
+would be silently truncated by the jax x32 default and float64
+comparisons would change answers in float32 — those predicates stay on
+the host path.  String comparisons stay host-side too
+(predicate/compile.py's length-prefiltered gathers are already vectorized
+and the device gain would be eaten by transfers).
+
+The program is compiled from the predicate as `exact.bind_device` rewrote
+it for the schema: day numbers for dates, scaled integers for decimals.
 
 Reference being displaced: pkg/transformer/registry/filter_rows — a
 row-at-a-time Go predicate interpreter.
@@ -25,6 +32,7 @@ from typing import Callable
 import numpy as np
 
 from transferia_tpu.abstract.schema import CanonicalType, TableSchema
+from transferia_tpu.predicate import exact
 from transferia_tpu.predicate.ast import (
     And, Between, Cmp, InList, IsNull, Node, Not, Or, TrueNode,
 )
@@ -60,18 +68,37 @@ def _walk(node: Node, schema: TableSchema) -> tuple[bool, bool]:
         return _walk(node.inner, schema)
     if isinstance(node, (IsNull, Between, InList, Cmp)):
         cs = schema.find(node.column)
-        if cs is None or cs.data_type not in _DEVICE_SAFE:
+        if cs is None:
+            return False, False
+        if cs.data_type == CanonicalType.DECIMAL:
+            return _decimal_device_safe(node, cs), False
+        if cs.data_type not in _DEVICE_SAFE:
             return False, False
         if isinstance(node, IsNull):
             return True, False
-        values = (node.values if isinstance(node, InList)
-                  else [node.low, node.high] if isinstance(node, Between)
-                  else [node.value])
         if isinstance(node, Cmp) and node.op == "~":
             return False, False
         return all(v is None or _literal_device_safe(v, cs.data_type)
-                   for v in values), False
+                   for v in exact.literal_values(node)), False
     return False, False
+
+
+def _decimal_device_safe(node: Node, cs) -> bool:
+    """A DECIMAL column compares on the device as int32 values at the
+    scale its schema gives; every literal has to be a number."""
+    if exact.column_scale(cs) is None:
+        return False
+    if isinstance(node, IsNull):
+        return True
+    if isinstance(node, Cmp) and node.op == "~":
+        return False
+    try:
+        for v in exact.literal_values(node):
+            if v is not None:
+                exact.as_decimal(v, node.column)
+    except ValueError:
+        return False
+    return True
 
 
 def _literal_device_safe(v, ctype: CanonicalType) -> bool:
@@ -87,6 +114,14 @@ def _literal_device_safe(v, ctype: CanonicalType) -> bool:
         return ctype == CanonicalType.BOOLEAN
     if ctype == CanonicalType.BOOLEAN:
         return False
+    if isinstance(v, str):
+        if ctype != CanonicalType.DATE:
+            return False
+        try:
+            exact.date_days(v, "")
+        except ValueError:
+            return False
+        return True
     if isinstance(v, int):
         if ctype == CanonicalType.FLOAT:
             # int literal vs float32 column: exact iff it fits 2^24

@@ -21,7 +21,7 @@ import re
 from typing import Any, Optional
 
 from transferia_tpu.predicate.ast import (
-    And, Between, Cmp, InList, IsNull, Node, Not, Or, TrueNode,
+    And, Between, Cmp, InList, IsNull, Node, Not, NumberText, Or, TrueNode,
 )
 
 
@@ -64,7 +64,10 @@ class _Lexer:
             pos = m.end()
             if m.lastgroup == "num":
                 s = m.group("num")
-                self.tokens.append(("lit", float(s) if "." in s or "e" in s.lower() else int(s)))
+                # a number with a point or an exponent keeps its text:
+                # a DECIMAL column compares with it exactly (exact.py)
+                self.tokens.append(("lit", NumberText(s) if "." in s
+                                    or "e" in s.lower() else int(s)))
             elif m.lastgroup == "str":
                 raw = m.group("str")[1:-1]
                 self.tokens.append(("lit", re.sub(r"\\(.)", r"\1", raw)))

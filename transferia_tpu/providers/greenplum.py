@@ -32,7 +32,6 @@ address all segments must reach.
 
 from __future__ import annotations
 
-import io
 import logging
 import threading
 import uuid
@@ -146,16 +145,14 @@ class GPStorage(PGStorage):
                     if data:
                         if not data.endswith(b"\n"):
                             data += b"\n"
-                        self._flush_csv(io.BytesIO(data), table.id,
-                                        schema, pusher)
+                        self._flush_csv(data, table.id, schema, pusher)
                     return
                 nl = _safe_split(data)
                 if nl < 0:
                     tails[seg] = data
                     return
                 tails[seg] = data[nl + 1:]
-                self._flush_csv(io.BytesIO(data[:nl + 1]), table.id,
-                                schema, pusher)
+                self._flush_csv(data[:nl + 1], table.id, schema, pusher)
 
         try:
             server.register_sink(slot, on_chunk, n_segments)

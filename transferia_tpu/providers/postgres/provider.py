@@ -8,8 +8,9 @@ into pyarrow's block CSV reader — vectorized straight into ColumnBatch.
 
 from __future__ import annotations
 
-import io
 import logging
+import re
+import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -47,6 +48,7 @@ from transferia_tpu.providers.registry import (
     TestResult,
     register_provider,
 )
+from transferia_tpu.stats import trace
 from transferia_tpu.typesystem.rules import (
     register_source_rules,
     register_target_rules,
@@ -181,6 +183,10 @@ class PGStorage(Storage, ShardingStorage, PositionalStorage,
     def __init__(self, params: PGSourceParams):
         self.params = params
         self._c: Optional[PGConnection] = None
+        # part threads share this storage and its one catalog connection:
+        # each asks for its table's schema once, one at a time
+        self._load_lock = threading.Lock()
+        self._load_schemas: dict[TableID, TableSchema] = {}
 
     @property
     def conn(self) -> PGConnection:
@@ -237,12 +243,15 @@ class PGStorage(Storage, ShardingStorage, PositionalStorage,
         for r in rows:
             if is_meta_name(r["name"]):
                 continue  # hidden staged-commit part column
+            ctype = map_source_type("pg", r["typ"].lower())
             cols.append(ColSchema(
                 name=r["name"],
-                data_type=map_source_type("pg", r["typ"].lower()),
+                data_type=ctype,
                 primary_key=r["is_pk"] in ("t", True, "true"),
                 required=r["notnull"] in ("t", True, "true"),
                 original_type=f"pg:{r['typ']}",
+                properties=_numeric_properties(r["typ"])
+                if ctype == CanonicalType.DECIMAL else (),
             ))
         return TableSchema(cols)
 
@@ -326,7 +335,11 @@ class PGStorage(Storage, ShardingStorage, PositionalStorage,
 
     # -- snapshot load ------------------------------------------------------
     def load_table(self, table: TableDescription, pusher: Pusher) -> None:
-        schema = self.table_schema(table.id)
+        with self._load_lock:
+            schema = self._load_schemas.get(table.id)
+            if schema is None:
+                schema = self.table_schema(table.id)
+                self._load_schemas[table.id] = schema
         cols = ", ".join(f'"{c.name}"' for c in schema)
         where = f" WHERE {table.filter}" if table.filter else ""
         self._copy_select(
@@ -343,17 +356,31 @@ class PGStorage(Storage, ShardingStorage, PositionalStorage,
         # dedicated connection: parts stream in parallel threads
         conn = _conn(self.params)
         try:
-            buf = io.BytesIO()
-            nbytes = 0
-            for chunk in conn.copy_out(sql):
-                buf.write(chunk)
-                nbytes += len(chunk)
-                if nbytes >= 32 << 20:
-                    self._flush_csv(buf, tid, schema, pusher)
-                    buf = io.BytesIO()
-                    nbytes = 0
-            if buf.tell():
-                self._flush_csv(buf, tid, schema, pusher)
+            blocks = conn.copy_out(sql)
+            carry = None      # decoded rows short of a batch (arrow)
+            more = True
+            while more:
+                # one span a flush: the wait for and the read of CopyData
+                # from the socket, unframed in bulk (wire.py copy_out)
+                # (a PostgreSQL backend sends csv one message a row, so
+                # a flush is about a batch; a server that packs rows
+                # into fewer messages is flushed by bytes)
+                parts: list[bytes] = []
+                nbytes = messages = 0
+                with trace.span("pg_copy_read") as sp:
+                    for payload, n in blocks:
+                        parts.append(payload)
+                        nbytes += len(payload)
+                        messages += n
+                        if messages >= self.params.batch_rows \
+                                or nbytes >= 32 << 20:
+                            break
+                    else:
+                        more = False
+                    if sp:
+                        sp.add(messages=messages, bytes=nbytes)
+                carry = self._flush_csv(b"".join(parts), tid, schema,
+                                        pusher, carry, last=not more)
         finally:
             conn.close()
 
@@ -415,33 +442,66 @@ class PGStorage(Storage, ShardingStorage, PositionalStorage,
             table.id, schema, pusher,
         )
 
-    def _flush_csv(self, buf: io.BytesIO, tid: TableID,
-                   schema: TableSchema, pusher: Pusher) -> None:
-        """CSV chunk -> arrow (vectorized) -> ColumnBatch.
+    def _flush_csv(self, text: bytes, tid: TableID, schema: TableSchema,
+                   pusher: Pusher, carry=None, last: bool = True):
+        """COPY csv text -> arrow (vectorized) -> ColumnBatches of
+        `batch_rows`; returns the decoded rows short of a batch (an arrow
+        table) for the next flush to start with, None after the last.
 
-        Chunks split on CopyData boundaries which always align to row ends
-        (each CopyData message is one row for csv format).
+        `text` ends at a row's end (each CopyData message is one row for
+        csv format).  NULL is the unquoted empty field, `""` the empty
+        string, as COPY writes them.  DECIMAL columns stay the text the
+        source sent; DATE becomes int32 days.
         """
         import pyarrow as pa
         import pyarrow.csv as pacsv
 
-        from transferia_tpu.columnar.batch import arrow_to_table_schema
-
-        buf.seek(0)
-        convert = pacsv.ConvertOptions(
-            column_types={
-                c.name: _arrow_read_type(c.data_type) for c in schema
-            },
-            null_values=[""],
-            strings_can_be_null=True,
-        )
-        read = pacsv.ReadOptions(column_names=schema.names())
-        tbl = pacsv.read_csv(buf, read_options=read,
-                             convert_options=convert)
-        for rb in tbl.to_batches(max_chunksize=self.params.batch_rows):
-            batch = ColumnBatch.from_arrow(rb, tid, schema)
-            batch.read_bytes = rb.nbytes
+        # text to columns, and nothing of what the pusher does with them
+        batches = []
+        with trace.span("source_decode", format="pg_copy") as sp:
+            tbl = carry
+            if text:
+                convert = pacsv.ConvertOptions(
+                    column_types={
+                        c.name: _arrow_read_type(c.data_type)
+                        for c in schema
+                    },
+                    null_values=[""],
+                    strings_can_be_null=True,
+                    quoted_strings_can_be_null=False,
+                )
+                read = pacsv.ReadOptions(column_names=schema.names())
+                new = pacsv.read_csv(pa.BufferReader(text),
+                                     read_options=read,
+                                     convert_options=convert)
+                tbl = new if carry is None \
+                    else pa.concat_tables([carry, new])
+            n = tbl.num_rows if tbl is not None else 0
+            per = self.params.batch_rows
+            whole = n if last else n - n % per
+            for lo in range(0, whole, per):
+                # one record batch a slice: read_csv leaves a chunk a
+                # block of text, and a slice may straddle the carry
+                rb = tbl.slice(lo, min(per, whole - lo)) \
+                    .combine_chunks().to_batches()[0]
+                batch = ColumnBatch.from_arrow(rb, tid, schema)
+                batch.read_bytes = rb.nbytes
+                batches.append(batch)
+            if sp:
+                sp.add(rows=whole, bytes=len(text))
+        for batch in batches:
             pusher(batch)
+        return tbl.slice(whole) if whole < n else None
+
+
+def _numeric_properties(typ: str) -> tuple:
+    """(("precision", p), ("scale", s)) of `numeric(p,s)` as format_type
+    spells atttypmod; () for a numeric without them."""
+    m = re.search(r"\(\s*(\d+)\s*(?:,\s*(\d+)\s*)?\)", typ)
+    if not m:
+        return ()
+    return (("precision", int(m.group(1))),
+            ("scale", int(m.group(2) or 0)))
 
 
 def _arrow_read_type(ctype: CanonicalType):

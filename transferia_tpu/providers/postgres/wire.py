@@ -251,23 +251,45 @@ class PGConnection:
         return next(iter(rows[0].values()))
 
     # -- COPY ---------------------------------------------------------------
-    def copy_out(self, sql: str) -> Iterator[bytes]:
-        """COPY ... TO STDOUT: yields raw CopyData chunks."""
+    def copy_out(self, sql: str, block_bytes: int = 4 << 20
+                 ) -> Iterator[tuple[bytes, int]]:
+        """COPY ... TO STDOUT, unframed in bulk: yields (the payloads of
+        whole CopyData messages end to end, how many messages) a block of
+        the socket's bytes.  A PostgreSQL backend sends csv as one
+        CopyData a row, so the count is the block's rows; the framing is
+        walked natively (native/hostops.cpp pg_copy_unframe), never one
+        Python call a row."""
         self._send(b"Q", sql.encode() + b"\x00")
         error: Optional[PGError] = None
+        tail = b""
         while True:
-            try:
-                t, payload = self._recv_message()
-            except PGError as e:
-                error = e
-                continue
-            if t == b"d":
-                yield payload
-            elif t == b"Z":
-                if error is not None:
-                    raise error
-                return
-            # H (CopyOutResponse), c (CopyDone), C ignored
+            chunk = self.sock.recv(block_bytes)
+            if not chunk:
+                raise PGError("connection closed by peer")
+            data = tail + chunk if tail else chunk
+            used = 0
+            while True:
+                payload, rows, n = _unframe_copy_data(data, used)
+                used += n
+                if rows:
+                    yield payload, rows
+                if len(data) - used < 5 or data[used] == 0x64:   # b"d"
+                    break           # an incomplete message: read on
+                length = struct.unpack_from("!I", data, used + 1)[0]
+                end = used + 1 + max(length, 4)
+                if end > len(data):
+                    break
+                t, body = data[used:used + 1], data[used + 5:end]
+                used = end
+                if t == b"E":
+                    error = PGError(self._error_text(body),
+                                    self._error_fields(body))
+                elif t == b"Z":
+                    if error is not None:
+                        raise error
+                    return
+                # H (CopyOutResponse), c (CopyDone), C ignored
+            tail = data[used:]
 
     def _drain_until_ready(self, first_error: "PGError") -> None:
         """Consume messages through ReadyForQuery so the connection stays
@@ -307,3 +329,28 @@ class PGConnection:
                 if error is not None:
                     raise error
                 return
+
+
+def _unframe_copy_data(data: bytes, start: int) -> tuple[bytes, int, int]:
+    """(payloads end to end, messages, bytes consumed) of the whole
+    CopyData messages that `data` holds from `start` on."""
+    import numpy as np
+
+    from transferia_tpu import native
+
+    lib = native.lib()
+    if lib is not None:
+        src = np.frombuffer(data, dtype=np.uint8)[start:]
+        out = np.empty(len(src), dtype=np.uint8)
+        counts = np.zeros(2, dtype=np.int64)
+        used = lib.pg_copy_unframe(src, len(src), out, counts)
+        return out[:counts[0]].tobytes(), int(counts[1]), int(used)
+    parts = []
+    pos, n = start, len(data)
+    while pos + 5 <= n and data[pos] == 0x64:
+        length = int.from_bytes(data[pos + 1:pos + 5], "big")
+        if length < 4 or pos + 1 + length > n:
+            break
+        parts.append(data[pos + 5:pos + 1 + length])
+        pos += 1 + length
+    return b"".join(parts), len(parts), pos - start

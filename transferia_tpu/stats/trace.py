@@ -801,6 +801,12 @@ class DeviceTelemetry:
             # (transform/fused.py DeviceFusedStep._pick_strategy), one
             # count a batch
             self.placements = dict.fromkeys(PLACEMENT_REASONS, 0)
+            # rows whose filter_rows predicate ran on the chip / on the
+            # host, and batches the device strategy handed back to the
+            # host because a DECIMAL column's scaled values did not fit
+            # the int32 the chip compares in (transform/fused.py)
+            self.filter_rows = {"device": 0, "host": 0}
+            self.filter_batches_host_unsafe = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -831,6 +837,14 @@ class DeviceTelemetry:
         _ledger().add(launches=n)
         with self._lock:
             self.device_launches += n
+
+    def record_filter_rows(self, where: str, n_rows: int) -> None:
+        with self._lock:
+            self.filter_rows[where] += int(n_rows)
+
+    def record_filter_host_unsafe(self) -> None:
+        with self._lock:
+            self.filter_batches_host_unsafe += 1
 
     def record_dispatch(self, encoded_bytes: int,
                         raw_equiv_bytes: int) -> None:
@@ -930,6 +944,10 @@ class DeviceTelemetry:
                    for route, n in self.mask_route_rows.items()},
                 **{f"placement_{reason}": n
                    for reason, n in self.placements.items()},
+                "filter_rows_device": self.filter_rows["device"],
+                "filter_rows_host": self.filter_rows["host"],
+                "filter_batches_host_unsafe":
+                    self.filter_batches_host_unsafe,
             }
 
     def fold_into(self, metrics) -> None:

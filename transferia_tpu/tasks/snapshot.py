@@ -357,6 +357,7 @@ class SnapshotLoader:
         the init brackets, and re-sending could reset sink-side
         sharded-table state."""
         schemas = {td.id: storage.table_schema(td.id) for td in tables}
+        self._plan_transformation(schemas)
         sink = make_async_sink(self.transfer, self.metrics,
                                snapshot_stage=True)
         try:
@@ -659,6 +660,27 @@ class SnapshotLoader:
             return
         if node is not None and storage.set_scan_predicate(tid, node):
             logger.info("scan pushdown for %s: %s", tid, node)
+
+    def _plan_transformation(self, schemas: dict) -> None:
+        """Plan the transformer chain for every table before a row is
+        read: a step that cannot take its table's schema - a filter_rows
+        literal its column's type cannot be compared with
+        (predicate/exact.py) - aborts the activation here, and not a part
+        after its retries."""
+        from transferia_tpu.abstract.errors import AbortTransferError
+        from transferia_tpu.transform.chain import build_chain
+
+        chain = build_chain(self.transfer.transformation)
+        if chain is None:
+            return
+        for tid, schema in schemas.items():
+            if schema is None:
+                continue
+            try:
+                chain.plan_for(tid, schema)
+            except ValueError as e:
+                raise AbortTransferError(
+                    f"transformation cannot take table {tid}: {e}") from e
 
     # -- worker liveness: lease-renewal heartbeat ---------------------------
     def _heartbeat_loop(self, stop: threading.Event) -> None:

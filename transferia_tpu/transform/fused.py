@@ -6,6 +6,9 @@ maximal runs of device-able transformers — HMAC mask (mask_field) and
 row-filter predicates (filter_rows) — and replaces each run with a single
 DeviceFusedStep whose apply() does ONE device round-trip per batch
 (ops/fused.py), instead of one host pass (or one device launch) per step.
+A run of filters alone is such a run too: whether its predicate is
+evaluated on the chip or by numpy is `auto`'s to measure, as for every
+other run (`_pick_strategy`).
 
 Fusion preconditions (checked against the schema at that chain position):
 - mask_field targets only variable-width columns (fixed-width masking
@@ -113,15 +116,24 @@ class DeviceFusedStep(Transformer):
 
     def __init__(self, members: Sequence[Transformer],
                  mask_entries: Sequence[tuple[str, bytes]],
-                 pred_node):
+                 pred_node, device_pred=None,
+                 decimal_scales: Optional[dict[str, int]] = None):
+        """pred_node is the predicate as written (the host strategy
+        compiles it); device_pred is the same predicate bound to the
+        schema for the chip (predicate/exact.py bind_device: day numbers
+        and scaled integers), and decimal_scales the scale of every
+        DECIMAL column it reads."""
         from transferia_tpu.ops.fused import FusedMaskFilterProgram
 
         self.members = list(members)
         self.mask_entries = list(mask_entries)
         self.pred_node = pred_node
         self.pred_cols = sorted(pred_node.columns()) if pred_node else []
+        self.decimal_scales = dict(decimal_scales or {})
+        if device_pred is None:
+            device_pred = pred_node
         keys = [key for _, key in mask_entries]
-        self.program = FusedMaskFilterProgram(keys, pred_node)
+        self.program = FusedMaskFilterProgram(keys, device_pred)
         # >1 visible device: also build the mesh-sharded program and
         # route large batches through it (parallel/fusedmesh.py)
         self.sharded_program = None
@@ -131,7 +143,7 @@ class DeviceFusedStep(Transformer):
                 ShardedFusedProgram,
             )
 
-            self.sharded_program = ShardedFusedProgram(keys, pred_node)
+            self.sharded_program = ShardedFusedProgram(keys, device_pred)
             # below ~1k rows/device the launch+collective overhead wins
             self._sharded_min_rows = 1024 * _mesh_devices()
         # host strategy: vectorized predicate pushed down before the mask
@@ -142,6 +154,7 @@ class DeviceFusedStep(Transformer):
             self._host_pred_fn = compile_mask(pred_node)
         # auto-placement state (ns/row EMAs; -1 = not yet measured)
         self._ns_row = {"host": -1.0, "device": -1.0}
+        self._ema_rows = {"host": 0.0, "device": 0.0}
         self._batch_no = 0
         self._dev_samples = 0
         self._choice_logged = False
@@ -240,7 +253,9 @@ class DeviceFusedStep(Transformer):
         if self.pred_node is not None:
             for name in self.pred_cols:
                 itemsize = 8
-                if (batch is not None and name in batch.columns
+                if name in self.decimal_scales:
+                    itemsize = 4    # the scaled values cross as int32
+                elif (batch is not None and name in batch.columns
                         and not batch.column(name).is_lazy_dict):
                     itemsize = batch.column(name).data.dtype.itemsize
                 h2d += n_rows * itemsize
@@ -254,8 +269,10 @@ class DeviceFusedStep(Transformer):
         Two syncs (dispatch + collect) pay the launch overhead; the
         bytes-over-link terms come from _estimate_link_bytes, which
         folds the dispatch compression ratio in — so `auto` placement
-        judges the ENCODED wire, not the raw one.  Compute is charged
-        at DEVICE_MASK_ROWS_PER_S.
+        judges the ENCODED wire, not the raw one.  The mask's compute
+        is charged at DEVICE_MASK_ROWS_PER_S; a run without a mask has
+        no such term (the predicate is a few compares a row on data
+        already resident: the launch overhead covers it).
         """
         from transferia_tpu.ops.linkprobe import probe_link
 
@@ -263,8 +280,9 @@ class DeviceFusedStep(Transformer):
         h2d_bytes, d2h_bytes = self._estimate_link_bytes(n_rows, batch)
         s = (2 * link.launch_overhead_s
              + h2d_bytes / link.h2d_bytes_per_s
-             + d2h_bytes / link.d2h_bytes_per_s
-             + n_rows / DEVICE_MASK_ROWS_PER_S)
+             + d2h_bytes / link.d2h_bytes_per_s)
+        if self.mask_entries:
+            s += n_rows / DEVICE_MASK_ROWS_PER_S
         return s * 1e9 / max(n_rows, 1)
 
     # only probe the device strategy when the link model says it could
@@ -337,7 +355,19 @@ class DeviceFusedStep(Transformer):
                 return
         ns = seconds * 1e9 / max(n_rows, 1)
         prev = self._ns_row[strategy]
-        self._ns_row[strategy] = ns if prev < 0 else 0.7 * prev + 0.3 * ns
+        if prev < 0:
+            self._ns_row[strategy] = ns
+            self._ema_rows[strategy] = float(n_rows)
+            return
+        # a mean over rows, not over batches: a flush tick's batch of a
+        # few thousand rows is mostly what a batch costs whatever its
+        # size, and read as ns a row it made the strategy that happened
+        # to take it lose to the other (PERF.md section 6, PR 27)
+        w_prev = 0.7 * (self._ema_rows[strategy] or n_rows)
+        w_new = 0.3 * n_rows
+        self._ns_row[strategy] = (w_prev * prev + w_new * ns) \
+            / max(w_prev + w_new, 1e-9)
+        self._ema_rows[strategy] = w_prev + w_new
 
     def placement_summary(self) -> str:
         """Read-only diagnostics line (no probing side effects)."""
@@ -369,6 +399,12 @@ class DeviceFusedStep(Transformer):
         from transferia_tpu.ops.fused import hex_to_varwidth
 
         t0 = _time.perf_counter()
+        pred_inputs = self._device_pred_inputs(batch)
+        if pred_inputs is None:
+            # a DECIMAL column's scaled values do not fit the int32 the
+            # chip compares in: this batch is the host's
+            TELEMETRY.record_filter_host_unsafe()
+            return self._apply_host(batch)
         program = self.program
         if (self.sharded_program is not None
                 and batch.n_rows >= self._sharded_min_rows):
@@ -453,10 +489,8 @@ class DeviceFusedStep(Transformer):
             TELEMETRY.record_mask_route("device_flat", batch.n_rows)
             flat_entries.append((name, False))
             flat_states.append(states)
-        pred_inputs = {}
-        for name in self.pred_cols:
-            col = batch.column(name)
-            pred_inputs[name] = (col.data, col.validity)
+        if self.pred_node is not None:
+            TELEMETRY.record_filter_rows("device", batch.n_rows)
         if mask_inputs or self.pred_node is not None:
             if program is self.program:
                 hexes, keep = program.run(
@@ -486,6 +520,25 @@ class DeviceFusedStep(Transformer):
         self._observe("device", _time.perf_counter() - t0, batch.n_rows)
         return TransformResult(out)
 
+    def _device_pred_inputs(self, batch: ColumnBatch) -> Optional[dict]:
+        """name -> (fixed-width data, validity) of the predicate's
+        columns as the device program compares them; None where a
+        DECIMAL column of this batch has no exact int32 form."""
+        from transferia_tpu.ops.dispatch import narrow_pred_i32
+        from transferia_tpu.predicate.exact import decimal_scaled
+
+        inputs = {}
+        for name in self.pred_cols:
+            col = batch.column(name)
+            data = col.data
+            if name in self.decimal_scales:
+                scaled, _ = decimal_scaled(col, self.decimal_scales[name])
+                data = narrow_pred_i32(scaled)
+                if data is None:
+                    return None
+            inputs[name] = (data, col.validity)
+        return inputs
+
     def _apply_host(self, batch: ColumnBatch) -> TransformResult:
         """Host strategy with predicate pushdown.
 
@@ -507,6 +560,7 @@ class DeviceFusedStep(Transformer):
         cur = batch
         if self._host_pred_fn is not None:
             keep = self._host_pred_fn(batch)
+            TELEMETRY.record_filter_rows("host", batch.n_rows)
             if not keep.all():
                 cur = batch.filter(keep)
         with trace.span("host_mask"):
@@ -546,6 +600,7 @@ def maybe_fuse_steps(steps: Sequence[Transformer], in_table: TableID,
     if not device_fusion_enabled() or not steps:
         return list(steps)
     from transferia_tpu.predicate.device import device_compatible
+    from transferia_tpu.predicate.exact import bind_device, column_scale
 
     out: list[Transformer] = []
     schema = in_schema
@@ -556,6 +611,8 @@ def maybe_fuse_steps(steps: Sequence[Transformer], in_table: TableID,
         group: list[Transformer] = []
         mask_entries: list[tuple[str, bytes]] = []
         pred_parts = []
+        device_parts = []
+        decimal_scales: dict[str, int] = {}
         masked: set[str] = set()
         run_schema = schema
         j = i
@@ -579,26 +636,34 @@ def maybe_fuse_steps(steps: Sequence[Transformer], in_table: TableID,
                 if not isinstance(st.node, TrueNode):
                     # an always-true filter joins the run as a no-op
                     pred_parts.append(st.node)
+                    device_parts.append(bind_device(st.node, run_schema))
+                    for c in st.node.columns():
+                        cs = run_schema.find(c)
+                        if cs.data_type == CanonicalType.DECIMAL:
+                            decimal_scales[c] = column_scale(cs)
             else:
                 break
             group.append(st)
             run_schema = st.result_schema(run_schema)
             j += 1
-        if mask_entries and group:
-            # a run with at least one device mask pays for the launch;
-            # pure-filter runs stay on the (already vectorized) host path
-            pred_node = None
+        if mask_entries or pred_parts:
+            # whether the run pays for its launches is measured per
+            # batch (DeviceFusedStep._pick_strategy), masks or none
+            pred_node = device_pred = None
             if pred_parts:
                 from transferia_tpu.predicate.ast import And
 
                 pred_node = (pred_parts[0] if len(pred_parts) == 1
                              else And(tuple(pred_parts)))
+                device_pred = (device_parts[0] if len(device_parts) == 1
+                               else And(tuple(device_parts)))
             from transferia_tpu.runtime.backend import log_backend_once
 
             # a worker that came up on the CPU platform says so here,
             # before its first "device" step runs on XLA-CPU
             log_backend_once()
-            fused = DeviceFusedStep(group, mask_entries, pred_node)
+            fused = DeviceFusedStep(group, mask_entries, pred_node,
+                                    device_pred, decimal_scales)
             logger.info("fused %d transformer steps onto device: %s",
                         len(group), fused.describe())
             out.append(fused)

@@ -9,6 +9,8 @@ import numpy as np
 from transferia_tpu.abstract.schema import TableID, TableSchema
 from transferia_tpu.columnar.batch import ColumnBatch
 from transferia_tpu.predicate import compile_mask, parse
+from transferia_tpu.predicate.exact import coerce_literals
+from transferia_tpu.stats.trace import TELEMETRY
 from transferia_tpu.transform.base import TransformResult, Transformer
 from transferia_tpu.transform.registry import register_transformer
 
@@ -85,10 +87,16 @@ class FilterRows(Transformer):
         if not _tables_match(self.tables, table):
             return False
         names = set(schema.names())
-        return self.node.columns() <= names
+        if not self.node.columns() <= names:
+            return False
+        # a literal its column cannot be compared with (no date against
+        # a DATE column, no number against a DECIMAL one) fails the plan
+        coerce_literals(self.node, schema)
+        return True
 
     def apply(self, batch: ColumnBatch) -> TransformResult:
         mask = self.mask_fn(batch)
+        TELEMETRY.record_filter_rows("host", batch.n_rows)
         if mask.all():
             return TransformResult(batch)
         return TransformResult(batch.filter(mask))
