@@ -494,3 +494,141 @@ def test_16_partition_fanin_with_transform_chain_to_ch():
     finally:
         srv.stop()
         ch.stop()
+
+
+# -- the parsequeue pushes ahead of its acks ----------------------------------
+
+_BACKLOG = 5 * 1024 + 100    # six fetched batches: `fetch` keeps 1,024
+
+
+def _backlog_transfer(transfer_id, srv, ch):
+    from transferia_tpu.providers.clickhouse import CHTargetParams
+
+    seed = KafkaClient([f"127.0.0.1:{srv.port}"])
+    srv.create_topic("backlog")
+    for base in range(0, _BACKLOG, 256):
+        seed.produce("backlog", 0, [
+            Record(key=b"", value=json.dumps(
+                {"id": i, "v": f"x{i}"}).encode())
+            for i in range(base, min(base + 256, _BACKLOG))])
+    seed.close()
+    return Transfer(
+        id=transfer_id, type=TransferType.INCREMENT_ONLY,
+        src=KafkaSourceParams(
+            brokers=[f"127.0.0.1:{srv.port}"], topic="backlog",
+            parser={"json": {"schema": [
+                {"name": "id", "type": "int64", "key": True},
+                {"name": "v", "type": "utf8"},
+            ], "table": "backlog"}},
+        ),
+        # the default bufferer: 100,000 rows / 1.0 s
+        dst=CHTargetParams(host="127.0.0.1", port=ch.port),
+    )
+
+
+def _landed_ids(ch):
+    return [row["id"] for name, tb in ch.tables.items()
+            if not name.startswith("__trtpu") for row in tb["rows"]]
+
+
+def _committed(cp, transfer_id):
+    return cp.get_transfer_state(transfer_id).get(
+        "kafka_offsets", {}).get("backlog:0")
+
+
+def _until(cond, seconds):
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.05)
+    return cond()
+
+
+def test_a_backlog_lands_in_fewer_inserts_than_fetched_batches():
+    """Six fetched batches wait in the broker; the push stage hands them
+    all to the bufferer before its 1 s tick, so they land in fewer inserts
+    than batches, every id once, and the last offset is committed."""
+    from tests.recipes.fake_clickhouse import FakeCH
+    from transferia_tpu.stats import trace
+
+    srv = FakeKafka(n_partitions=1).start()
+    ch = FakeCH().start()
+    stop = threading.Event()
+    th = None
+    try:
+        cp = MemoryCoordinator()
+        t = _backlog_transfer("pushahead", srv, ch)
+        before = trace.TELEMETRY.snapshot()
+        trace.reset()
+        trace.enable(True)
+        th = threading.Thread(
+            target=run_replication, args=(t, cp),
+            kwargs={"stop_event": stop, "backoff": 0.2}, daemon=True)
+        th.start()
+        assert _until(lambda: _committed(cp, "pushahead") == _BACKLOG - 1,
+                      40)
+        stop.set()
+        th.join(timeout=10)
+        flushes = [s[7] for s in trace.spans() if s[0] == "bufferer_flush"]
+        after = trace.TELEMETRY.snapshot()
+    finally:
+        stop.set()
+        trace.enable(False)
+        trace.reset()
+        srv.stop()
+        ch.stop()
+    assert sorted(_landed_ids(ch)) == list(range(_BACKLOG))
+    pushes = after["parsequeue_pushes"] - before["parsequeue_pushes"]
+    assert pushes == 6
+    assert sum(f["units"] for f in flushes) == pushes
+    assert sum(f["rows"] for f in flushes) == _BACKLOG
+    assert len(flushes) < pushes and max(f["units"] for f in flushes) > 1
+    assert all(f["trigger"] in ("interval", "close") for f in flushes)
+    assert after["parsequeue_pushes_ahead"] \
+        > before["parsequeue_pushes_ahead"]
+
+
+def test_a_worker_stopped_between_a_push_and_its_ack_rereads(monkeypatch):
+    """The worker dies after its batches landed and before the second
+    one's offset is committed: the restart reads again from the committed
+    offset - duplicates above it, nothing missing, nothing below it twice."""
+    from tests.recipes.fake_clickhouse import FakeCH
+    from transferia_tpu.providers.kafka.provider import _KafkaQueueClient
+
+    commit = _KafkaQueueClient.commit
+    calls = []
+
+    def commit_then_die(self, topic, partition, offset):
+        calls.append(offset)
+        if len(calls) == 2:
+            raise ConnectionError("worker killed before the commit")
+        commit(self, topic, partition, offset)
+
+    monkeypatch.setattr(_KafkaQueueClient, "commit", commit_then_die)
+    srv = FakeKafka(n_partitions=1).start()
+    ch = FakeCH().start()
+    stop = threading.Event()
+    try:
+        cp = MemoryCoordinator()
+        t = _backlog_transfer("pushahead-kill", srv, ch)
+        th = threading.Thread(
+            target=run_replication, args=(t, cp),
+            kwargs={"stop_event": stop, "backoff": 0.2}, daemon=True)
+        th.start()
+        assert _until(
+            lambda: _committed(cp, "pushahead-kill") == _BACKLOG - 1, 40)
+        stop.set()
+        th.join(timeout=10)
+    finally:
+        stop.set()
+        srv.stop()
+        ch.stop()
+    # the first worker committed one fetched batch and died on the second
+    assert calls[:2] == [1023, 2047]
+    counts = {}
+    for i in _landed_ids(ch):
+        counts[i] = counts.get(i, 0) + 1
+    assert sorted(counts) == list(range(_BACKLOG))        # none missing
+    assert all(counts[i] == 1 for i in range(1024))       # committed: once
+    # what was pushed ahead of the failed ack had landed: read again
+    assert sum(1 for i in range(1024, _BACKLOG) if counts[i] == 2) >= 1024
+    assert max(counts.values()) == 2

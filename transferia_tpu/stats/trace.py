@@ -807,6 +807,12 @@ class DeviceTelemetry:
             # the int32 the chip compares in (transform/fused.py)
             self.filter_rows = {"device": 0, "host": 0}
             self.filter_batches_host_unsafe = 0
+            # units the parsequeue's push stage handed to the sink, and
+            # of those the ones handed over while an earlier unit was
+            # still unacked (parsequeue/queue.py): how often pushing
+            # ahead of the acks engages
+            self.parsequeue_pushes = 0
+            self.parsequeue_pushes_ahead = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -897,6 +903,11 @@ class DeviceTelemetry:
             # .get: a reason _decide adds must not fail the batch
             self.placements[reason] = self.placements.get(reason, 0) + 1
 
+    def record_parsequeue_push(self, ahead: bool) -> None:
+        with self._lock:
+            self.parsequeue_pushes += 1
+            self.parsequeue_pushes_ahead += ahead
+
     def record_device_wait(self, seconds: float) -> None:
         _ledger().add(device_wait_seconds=seconds)
         with self._lock:
@@ -948,6 +959,8 @@ class DeviceTelemetry:
                 "filter_rows_host": self.filter_rows["host"],
                 "filter_batches_host_unsafe":
                     self.filter_batches_host_unsafe,
+                "parsequeue_pushes": self.parsequeue_pushes,
+                "parsequeue_pushes_ahead": self.parsequeue_pushes_ahead,
             }
 
     def fold_into(self, metrics) -> None:
