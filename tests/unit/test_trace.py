@@ -3,7 +3,7 @@ export, device telemetry, and the /debug/trace endpoint.
 
 The contracts that matter:
 - disabled tracing is free: span() returns a shared no-op singleton
-  (no allocation, nothing recorded) — the bench path pays one bool
+  (no allocation, nothing recorded) — the hot path pays one bool
   check per site;
 - span nesting works across threads (per-thread stacks, self-time
   attribution);
@@ -620,6 +620,23 @@ def test_waits_stay_out_of_the_stage_shares_and_the_overlap_factor():
     assert after["waits"]["queue_wait"]["self_s"] == pytest.approx(9.0)
     # a span reader that filters on depth >= 0 still finds them
     assert sum(1 for s in trace.spans() if s[6] >= 0) == 3
+
+
+def test_summary_table_reads_recorded_spans_and_marks_waits():
+    trace.enable(True)
+    with trace.span("batch"):
+        with trace.span("transform"):
+            time.sleep(0.02)
+        # a passive wait far longer than the wall: no stage share
+        trace.complete("queue_wait", time.perf_counter() - 5.0, 5.0)
+    trace.enable(False)
+    stages = trace.stage_summary(0.04)["stages"]
+    assert list(stages) == ["transform", "batch"]
+    assert stages["transform"]["self_s"] >= 0.02
+    rows = trace.format_summary(0.04).splitlines()
+    names = [ln.split()[0] for ln in rows[2:5]]
+    assert names == ["transform", "batch", "~queue_wait"]
+    assert rows[0].startswith("wall=0.04s overlap_factor=")
 
 
 def test_queue_wait_costs_nothing_when_tracing_is_off():

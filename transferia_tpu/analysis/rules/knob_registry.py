@@ -1,7 +1,7 @@
 """KNB001 — env-knob drift between code and the README knob table.
 
-Every tuning knob in this tree is an environment variable prefixed
-``TRANSFERIA_TPU_`` or ``BENCH_``.  Three kinds of drift accumulate
+A knob is an environment variable named ``TRANSFERIA_TPU_*`` and read
+through :mod:`transferia_tpu.runtime.knobs`.  Three kinds of drift accumulate
 silently: a module growing its own ``os.environ.get`` (bypassing the
 :mod:`transferia_tpu.runtime.knobs` registry, so the knob is invisible
 to runtime enumeration), a knob added to code but never documented, and
@@ -18,8 +18,6 @@ three:
 
 Knob names are resolved statically: string literals, or module-level
 ``ENV_FOO = "TRANSFERIA_TPU_FOO"`` constants referenced by name.
-``bench.py`` sits outside the default scan path but is a first-class
-knob consumer, so the rule reads it from disk explicitly.
 """
 
 from __future__ import annotations
@@ -31,11 +29,10 @@ from typing import Optional
 
 from transferia_tpu.analysis.engine import Finding, ProjectRule
 
-_KNOB_RE = re.compile(r"\b(?:TRANSFERIA_TPU|BENCH)_[A-Z][A-Z0-9_]*\b")
+_KNOB_RE = re.compile(r"\bTRANSFERIA_TPU_[A-Z][A-Z0-9_]*\b")
 _HELPER_NAMES = frozenset(
     {"env_raw", "env_str", "env_int", "env_float", "env_bool"})
 _EXEMPT_FILES = frozenset({"transferia_tpu/runtime/knobs.py"})
-_EXTRA_FILES = ("bench.py",)
 _DOC_FILE = "README.md"
 
 
@@ -147,20 +144,6 @@ class KnobRegistryRule(ProjectRule):
             sc = _FileScan(rel)
             sc.scan(tree)
             scans[rel] = (sc, lines)
-        for rel in _EXTRA_FILES:
-            if rel in scans:
-                continue
-            abspath = os.path.join(root, rel)
-            try:
-                with open(abspath, encoding="utf-8") as fh:
-                    source = fh.read()
-                tree = ast.parse(source, filename=rel)
-            except (OSError, SyntaxError, UnicodeDecodeError):
-                continue
-            sc = _FileScan(rel)
-            sc.scan(tree)
-            scans[rel] = (sc, source.splitlines())
-
         documented = self._doc_names(root)
         findings: list[Finding] = []
         read_anywhere: set[str] = set()

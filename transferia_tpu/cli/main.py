@@ -1043,7 +1043,7 @@ def cmd_flight(args) -> int:
 
 
 def cmd_fleet(args) -> int:
-    """Fleet scheduler bench (fleet/bench.py).  Exit 0 only when every
+    """Fleet scheduler bench (transferia_tpu.fleet.bench).  Exit 0 only when every
     transfer delivered, nothing was lost or double-admitted, and the
     Jain fairness index held >= 0.9 under the skewed tenant mix."""
     from transferia_tpu.fleet.bench import format_report, run_fleet_bench

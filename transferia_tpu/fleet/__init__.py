@@ -17,9 +17,9 @@ bounded worker pool (ROADMAP item 3), in-process or distributed.
 - `backpressure.py` — hysteresis gate over the data-plane load gauges
   (readahead bytes/depth, sink in-flight rows, dispatch compression
   ratio, fleet queue depth).
-- `bench.py` — `trtpu fleet bench` / `bench.py --fleet`: 100+
-  concurrent sample->memory transfers; p50/p99 dispatch latency and
-  the Jain fairness index are tracked bench metrics.
+- `bench` — `trtpu fleet bench`: 100+ concurrent sample->memory
+  transfers; p50/p99 dispatch latency and the Jain fairness index
+  (no cell of the benchmark reads them: ROADMAP D2).
 
 Live schedulers (and autoscalers) register here so the health port can
 serve `/debug/fleet` without the CLI holding a reference.

@@ -1,4 +1,4 @@
-"""`trtpu fleet bench` / `bench.py --fleet`: the scheduler under load.
+"""`trtpu fleet bench`: the scheduler under load.
 
 Drives 100+ concurrent sample->memory snapshot transfers through
 FleetScheduler with a deliberately skewed tenant mix (one tenant

@@ -1,7 +1,6 @@
 """Interchange shard-handoff benchmark: pivot vs IPC vs shm vs Flight.
 
-Shared by `bench.py --interchange` (repo-root bench harness) and
-`trtpu flight bench` (CLI).  All paths move the SAME deterministic
+Behind `trtpu flight bench`.  All paths move the SAME deterministic
 sample batches from a producer to a consumer that materializes
 ColumnBatches; what varies is the wire:
 

@@ -99,10 +99,11 @@ def set_for_wire(on: Optional[bool]) -> None:
 
 def encoded_wire_enabled() -> bool:
     """TRANSFERIA_TPU_ENCODED_FLIGHT=0 forces dict columns FLAT on the
-    Arrow wire (the A side of `bench.py --encoded-wire`); default on —
-    dict columns cross as DictionaryArrays, and the IPC/Flight framing
-    ships each dictionary (pool) once per stream followed by codes-only
-    record batches."""
+    Arrow wire (the reference tests/unit/test_encoded_wire.py holds the
+    encoded wire's rows to; nothing else turns it off: ROADMAP D3);
+    default on — dict columns cross as DictionaryArrays, and the
+    IPC/Flight framing ships each dictionary (pool) once per stream
+    followed by codes-only record batches."""
     global _encoded_wire_cached
     if _encoded_wire_cached is None:
         _encoded_wire_cached = knobs.env_str(

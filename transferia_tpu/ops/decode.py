@@ -7,8 +7,8 @@ chip — unpack is pure vectorized shift/mask arithmetic (VPU), the gather
 rides HBM bandwidth.  The END-TO-END parquet decode stays on the host
 today (the native reader decodes dict pages, ops/dispatch.py re-encodes
 for the link); this module holds the kernels the fused program decodes
-its encoded inputs with, and bench.py times decode_dict_run alone as
-device_decode_rows_per_sec on resident buffers.
+its encoded inputs with.  decode_dict_run has no caller outside
+tests/unit/test_device_decode.py (ROADMAP D10).
 
 Scope mirrors the native decoder's hot path (native/parquetdec.cpp
 RleDecoder + dict gather):
@@ -125,24 +125,6 @@ def pack_mask_words(bits: jax.Array, n: int) -> jax.Array:
     b = bits.reshape(n // 32, 32).astype(jnp.uint32)
     weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
     return (b * weights).sum(axis=1).astype(jnp.uint32)
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3, 4))
-def decode_dict_loop(words: jax.Array, pool: jax.Array, bit_width: int,
-                     n: int, iters: int) -> jax.Array:
-    """`iters` back-to-back decodes in ONE launch (bench helper: one
-    launch's overhead would otherwise be a visible share of an op that
-    is pure HBM traffic).  The carry perturbs the input words each
-    iteration so XLA cannot hoist or CSE the loop body; returns a
-    checksum the caller discards after sync."""
-    def body(i, acc):
-        w = words ^ (acc & jnp.uint32(1))
-        codes = _unpack_core(w, bit_width, n)
-        with jax.named_scope("dict_gather"):
-            vals = jnp.take(pool, codes, axis=0, mode="clip")
-        return acc + vals.sum().astype(jnp.uint32)
-
-    return jax.lax.fori_loop(0, iters, body, jnp.uint32(0))
 
 
 @jax.named_scope("unpack_bits")

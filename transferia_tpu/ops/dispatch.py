@@ -23,8 +23,10 @@ Encodings (selection is per column, per batch, host-side):
 - **bool data** — bit-packed like validity.
 
 `TRANSFERIA_TPU_DISPATCH_ENCODING` picks the mode: `auto` (default —
-encode whenever it shrinks) or `raw` (the pre-compression wire, kept
-as the fallback and the A side of `bench.py --dispatch`).
+encode whenever it shrinks) or `raw` (every column as flat arrays: what
+`auto` itself ships for an array the encoders reject, and the reference
+the tests hold the encoded results to, byte for byte.  Nothing but
+tests sets the whole process to `raw`: ROADMAP D3).
 
 Grounding: Zerrow (PAPERS.md) keeps data in its compact columnar
 encoding across plane boundaries; Thallus shows transport cost, not

@@ -33,8 +33,7 @@ trace span on the worker thread; a consumer stall is recorded as a
 `decode_wait` span once it ends (`trace.complete`, so it takes self
 time from no parent) and billed to the ledger; queue depth and
 in-flight decoded bytes feed optional gauges (stats/registry.py
-DeviceStats) plus the module-level aggregate `snapshot_stats()` that
-`bench.py` appends to its stages line.
+DeviceStats) plus the module-level aggregate `snapshot_stats()`.
 """
 
 from __future__ import annotations
@@ -44,7 +43,7 @@ import time
 from collections import deque
 from typing import Callable, Iterable, Optional
 
-# process-wide aggregates (bench/diagnostic visibility, like
+# process-wide aggregates (diagnostic visibility, like
 # parquet_native.fallback_stats): how deep the queue ran and how much
 # decoded payload was in flight, across every prefetcher in the run
 _agg_lock = threading.Lock()

@@ -5,7 +5,7 @@ device its host shows, and a second process that asks for them fails at
 start-up ("The TPU is already in use by process …"; with JAX_PLATFORMS
 empty it would pick the CPU platform instead, without a word).  Nothing
 on the transfer path may therefore guess — `describe_backend()` resolves
-the backend once and every entry point (CLI, bench.py, chip_smoke.py)
+the backend once and every entry point (CLI, chip_smoke.py)
 logs or asserts on what it returned.
 """
 
@@ -99,8 +99,8 @@ def log_backend_once() -> dict:
 
 def require_tpu() -> dict:
     """The backend description, or SystemExit when it is not a TPU.
-    For measurement entry points (bench.py, chip_smoke.py): a run that
-    finds no chip prints nothing under a device metric's name."""
+    For chip_smoke.py: a run that finds no chip prints nothing under a
+    device metric's name."""
     info = describe_backend()
     if info["platform"] != "tpu":
         raise SystemExit(

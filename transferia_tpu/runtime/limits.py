@@ -26,10 +26,9 @@ logger = logging.getLogger(__name__)
 def effective_cpus() -> float:
     """Cores this process can actually use (affinity ∩ cgroup quota).
 
-    The sizing input for host-parallel work: bench.py's worker count and
-    the fs provider's column-parallel decode / readahead auto-knobs all
-    derive from it, so a 1-core CI box degrades to serial behavior
-    instead of thrashing."""
+    The sizing input for host-parallel work: the fs provider's
+    column-parallel decode / readahead auto-knobs derive from it, so a
+    1-core CI box degrades to serial behavior instead of thrashing."""
     try:
         n = float(len(os.sched_getaffinity(0)))
     except (AttributeError, OSError):

@@ -10,8 +10,8 @@ SUB sub-buckets per octave, so
 
     merge(h(A), h(B)) == h(A ++ B)     (exact, bucket-wise add)
 
-holds by construction — the property the fleet panes and `bench.py
---fleet` tail rely on to report cross-process p50/p99/p999.
+holds by construction — the property the fleet panes and `trtpu fleet
+bench` rely on to report cross-process p50/p99/p999.
 
 Bucketing: for v seconds, frexp(v) = (m, e) with m in [0.5, 1);
 the bucket index is (e + BIAS) * SUB + floor((m - 0.5) * 2 * SUB) —

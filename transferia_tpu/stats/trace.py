@@ -10,7 +10,7 @@ span-level attribution Thallus-style transport analysis needs
 (PAPERS.md) and the per-stage transfer accounting the Arrow Flight
 benchmarking work shows wire-speed columnar systems live or die on.
 It is the one recorder of stage time: `stage_summary()` is the per-stage
-wall breakdown the bench prints.
+wall breakdown `trtpu trace` prints.
 
 Design constraints:
 
@@ -561,22 +561,10 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
     }
 
 
-def format_summary(wall_seconds: Optional[float] = None,
-                   one_line: bool = False) -> str:
-    """Human table for `trtpu trace` / bench output; `one_line` gives
-    `stage=1.23s(45%) ... overlap_factor=x` (self seconds as a share
-    of the wall, largest first, waits left out) for bench's result."""
+def format_summary(wall_seconds: Optional[float] = None) -> str:
+    """Human table for `trtpu trace`: stages by self time, largest
+    first, then the waits (`~name`), then the device counters."""
     s = stage_summary(wall_seconds)
-    if one_line:
-        wall = s["wall_s"]
-        parts = [
-            f"{name}={d['self_s']:.2f}s"
-            f"({100.0 * d['self_s'] / wall if wall else 0.0:.0f}%)"
-            for name, d in s["stages"].items()]
-        if not parts:
-            return ""
-        parts.append(f"overlap_factor={s['overlap_factor']:.2f}")
-        return " ".join(parts)
     lines = [
         f"wall={s['wall_s']:.2f}s overlap_factor={s['overlap_factor']}",
         f"{'stage':<18} {'calls':>7} {'p50_ms':>9} {'p99_ms':>9} "
