@@ -587,6 +587,58 @@ def test_a_backlog_lands_in_fewer_inserts_than_fetched_batches():
         > before["parsequeue_pushes_ahead"]
 
 
+def test_a_large_response_is_fetched_once_and_acked_in_units():
+    """One response carries the whole backlog (a producer batch of several
+    `max_messages`): the source decodes it once and hands it out 1,024 at
+    a time, so the broker sees one Fetch with rows where six units are
+    pushed and acked; every id lands and the last offset is committed."""
+    from transferia_tpu.stats import trace
+
+    srv = FakeKafka(n_partitions=1).start()
+    seed = KafkaClient([f"127.0.0.1:{srv.port}"])
+    seed.produce("once", 0, [
+        Record(key=b"", value=json.dumps({"id": i, "v": f"x{i}"}).encode())
+        for i in range(_BACKLOG)])
+    seed.close()
+    store = get_store("fetch-once")
+    store.clear()
+    cp = MemoryCoordinator()
+    t = Transfer(
+        id="fetch-once", type=TransferType.INCREMENT_ONLY,
+        src=KafkaSourceParams(
+            brokers=[f"127.0.0.1:{srv.port}"], topic="once",
+            parser={"json": {"schema": [
+                {"name": "id", "type": "int64", "key": True},
+                {"name": "v", "type": "utf8"},
+            ], "table": "once"}},
+        ),
+        dst=MemoryTargetParams(sink_id="fetch-once"),
+    )
+    stop = threading.Event()
+    before = trace.TELEMETRY.snapshot()
+    th = threading.Thread(
+        target=run_replication, args=(t, cp),
+        kwargs={"stop_event": stop, "backoff": 0.2}, daemon=True)
+    th.start()
+    try:
+        assert _until(lambda: cp.get_transfer_state("fetch-once").get(
+            "kafka_offsets", {}).get("once:0") == _BACKLOG - 1, 40)
+    finally:
+        stop.set()
+        th.join(timeout=10)
+        srv.stop()
+    assert not th.is_alive()
+    after = trace.TELEMETRY.snapshot()
+    ids = sorted(r.value("id") for r in store.rows(TableID("", "once")))
+    assert sorted(set(ids)) == list(range(_BACKLOG))
+    units = after["parsequeue_pushes"] - before["parsequeue_pushes"]
+    assert units == 6
+    assert after["kafka_handouts"] - before["kafka_handouts"] == units
+    assert after["kafka_handouts_buffered"] \
+        - before["kafka_handouts_buffered"] == units - 1
+    assert srv.fetches_with_rows == 1
+
+
 def test_a_worker_stopped_between_a_push_and_its_ack_rereads(monkeypatch):
     """The worker dies after its batches landed and before the second
     one's offset is committed: the restart reads again from the committed

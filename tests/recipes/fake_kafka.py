@@ -166,6 +166,7 @@ class FakeKafka:
         self.txns: dict[str, dict] = {}
         self._next_pid = 1000
         self.auth_attempts = 0
+        self.fetches_with_rows = 0
         self._ssl_ctx = None
         if tls_cert is not None:
             import ssl
@@ -460,6 +461,7 @@ class FakeKafka:
         n_topics = r.i32()
         out = struct.pack("!i", 0)  # throttle
         out += struct.pack("!i", n_topics)
+        any_rows = False
         for _ in range(n_topics):
             topic = r.string()
             n_parts = r.i32()
@@ -479,7 +481,10 @@ class FakeKafka:
                     else:
                         blob = b""
                         high = 0
+                any_rows = any_rows or bool(blob)
                 out += struct.pack("!ihqq", partition, 0, high, high)
                 out += struct.pack("!i", 0)   # aborted txns
                 out += struct.pack("!i", len(blob)) + blob
+        with self.lock:
+            self.fetches_with_rows += any_rows
         return out

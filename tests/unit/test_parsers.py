@@ -226,3 +226,50 @@ def test_confluent_sr_avro_native_matches_python():
         got = {n: fb.column(n).to_pylist()[i] for n in want}
         assert got == want, (i, got, want)
     assert fb.n_rows == 500
+
+
+def _epoch_messages(values, n=300):
+    import json as _json
+
+    return [Message(
+        value=_json.dumps({"id": i, "name": f"n{i}",
+                           "at": values[i % len(values)]}).encode(),
+        topic="t", partition=0, offset=i, write_time_ns=1_000 + i)
+        for i in range(n)]
+
+
+@pytest.mark.parametrize("values,columnar", [
+    ([1_790_000_000_000_000, 0, -5], True),      # JSON integers
+    ([1_790_000_000_000_000, None], True),       # nulls stay nulls
+    ([1.5e9, 7], False),                         # a float: general path
+    (["1790000000", 7], False),                  # a digit string
+    (["2026-10-03T00:00:00Z", 7], False),        # not a number: null
+], ids=["ints", "nulls", "float", "digits", "iso"])
+@pytest.mark.parametrize("kind", ["timestamp", "datetime"])
+def test_epoch_columns_parse_columnar_as_the_general_path_does(
+        kind, values, columnar, monkeypatch):
+    """A batch whose DATETIME / TIMESTAMP values are JSON integers takes
+    the whole-batch arrow path and reads what the per-row path reads;
+    any other value leaves it to the per-row path."""
+    from transferia_tpu.parsers.generic import GenericJsonParser, _Lines
+
+    cfg = {"json": {"schema": [
+        {"name": "id", "type": "int64", "key": True},
+        {"name": "name", "type": "utf8"},
+        {"name": "at", "type": kind},
+    ], "table": "t"}}
+    msgs = _epoch_messages(values)
+    parser = make_parser(cfg)
+    fast = parser._fast_columnar(msgs, _Lines(msgs))
+    assert (fast is not None) == columnar
+    got = parser.do_batch(msgs)
+    monkeypatch.setattr(GenericJsonParser, "_fast_columnar",
+                        lambda self, messages, lines: None)
+    want = make_parser(cfg).do_batch(msgs)
+    assert got.unparsed is None and want.unparsed is None
+    a, b = got.batches[0], want.batches[0]
+    assert a.schema == b.schema and list(a.columns) == list(b.columns)
+    for name in b.columns:
+        assert a.column(name).to_pylist() == b.column(name).to_pylist(), \
+            name
+        assert a.column(name).data.dtype == b.column(name).data.dtype, name

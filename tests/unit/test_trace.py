@@ -643,3 +643,49 @@ def test_queue_wait_costs_nothing_when_tracing_is_off():
     sink = _run_parsequeue(3, seconds=0.0)
     assert len(sink.pushed) == 3
     assert trace.spans() == []
+
+
+# -- the kafka client's decode of a fetch response ---------------------------
+
+def test_kafka_decode_is_recorded_with_what_it_decoded():
+    """`kafka_decode` covers the decode and offset filter of one
+    partition's blob in `fetch_multi`, beside `kafka_roundtrip` and not
+    under it; an empty long poll records none."""
+    from tests.recipes.fake_kafka import FakeKafka
+    from transferia_tpu.providers.kafka.client import KafkaClient
+    from transferia_tpu.providers.kafka.protocol import Record
+    from transferia_tpu.stats import critpath
+
+    srv = FakeKafka(n_partitions=2).start()
+    client = KafkaClient([f"127.0.0.1:{srv.port}"])
+    try:
+        client.metadata(["td"])
+        client.produce("td", 1, [Record(key=b"", value=b"x" * 100)
+                                 for _ in range(7)])
+        trace.enable(True)
+        got = client.fetch_multi("td", {0: 0, 1: 2})
+        recorded = trace.spans()
+    finally:
+        client.close()
+        srv.stop()
+    assert [r.offset for r in got[1][0]] == [2, 3, 4, 5, 6]
+    decode = [s for s in recorded if s[0] == "kafka_decode"]
+    assert [s[7] for s in decode] == [
+        {"partition": 1, "bytes": decode[0][7]["bytes"], "records": 5}]
+    assert decode[0][7]["bytes"] > 7 * 100     # the blob's, untrimmed
+    trip = [s for s in recorded if s[0] == "kafka_roundtrip"][-1]
+    assert decode[0][10] != trip[9]            # a sibling, not a child
+    assert critpath.stage_of("kafka_decode") == "decode"
+
+
+def test_kafka_handout_counters_are_in_the_snapshot_and_reset():
+    trace.TELEMETRY.reset()
+    assert trace.TELEMETRY.snapshot()["kafka_handouts"] == 0
+    assert trace.TELEMETRY.snapshot()["kafka_handouts_buffered"] == 0
+    for buffered in (False, True, True):
+        trace.TELEMETRY.record_kafka_handout(buffered)
+    tel = trace.TELEMETRY.snapshot()
+    assert (tel["kafka_handouts"], tel["kafka_handouts_buffered"]) == (3, 2)
+    trace.TELEMETRY.reset()
+    tel = trace.TELEMETRY.snapshot()
+    assert (tel["kafka_handouts"], tel["kafka_handouts_buffered"]) == (0, 0)

@@ -199,6 +199,11 @@ class GenericJsonParser(Parser):
             CanonicalType.BOOLEAN: pa.bool_(),
             CanonicalType.UTF8: pa.string(),
             CanonicalType.STRING: pa.string(),
+            # epoch counts held as int64, as `_coerce` takes them: JSON
+            # integers; a float or a string fails the read and the
+            # general path decides
+            CanonicalType.DATETIME: pa.int64(),
+            CanonicalType.TIMESTAMP: pa.int64(),
         }
         out = []
         for cs in self.fields:

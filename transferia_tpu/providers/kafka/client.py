@@ -15,11 +15,14 @@ single-broker case.
 
 from __future__ import annotations
 
+import bisect
 import logging
+import operator
 import socket
 import struct
 import threading
 import time
+from collections.abc import Sequence
 from typing import Optional
 
 from transferia_tpu.abstract.errors import CategorizedError
@@ -30,6 +33,7 @@ from transferia_tpu.providers.kafka.protocol import (
     enc_bytes,
     enc_str,
     encode_record_batch,
+    scan_record_batches,
 )
 from transferia_tpu.utils.net import recv_exact
 
@@ -50,6 +54,7 @@ ERR_INVALID_PRODUCER_EPOCH = 47
 ERR_PRODUCER_FENCED = 90
 
 _RETRIABLE = {ERR_LEADER_NOT_AVAILABLE, ERR_NOT_LEADER}
+_record_offset = operator.attrgetter("offset")
 _FENCED = {ERR_INVALID_PRODUCER_EPOCH, ERR_PRODUCER_FENCED}
 
 
@@ -481,18 +486,22 @@ class KafkaClient:
 
     def fetch_multi(self, topic: str, offsets: dict[int, int],
                     max_bytes: int = 8 << 20, max_wait_ms: int = 250,
-                    ) -> dict[int, tuple[list[Record], int]]:
+                    ) -> dict[int, tuple[Sequence[Record], int]]:
         """Fetch many partitions in few round-trips: partitions group by
         leader and each leader gets ONE Fetch request carrying all of its
         partitions (the wire format is multi-partition; issuing one
         request per partition costs n_partitions round-trips per poll
         cycle — the 64-partition fan-in killer).  Returns
-        {partition: (records, high_watermark)}; per-partition retriable
-        errors retry once through the single-partition path."""
+        {partition: (records, high_watermark)}, the records a sequence
+        that builds them when taken (`protocol.scan_record_batches`: a
+        consumer takes part of a response at a time); per-partition
+        retriable errors retry once through the single-partition path."""
+        from transferia_tpu.stats import trace
+
         by_node: dict[object, list[int]] = {}
         for p in offsets:
             by_node.setdefault(self._leader_node(topic, p), []).append(p)
-        out: dict[int, tuple[list[Record], int]] = {}
+        out: dict[int, tuple[Sequence[Record], int]] = {}
         retry: list[int] = []
         self._fetch_rotation = getattr(self, "_fetch_rotation", 0) + 1
         for node, parts in by_node.items():
@@ -532,8 +541,17 @@ class KafkaClient:
                         retry.append(p)
                         continue
                     off = offsets.get(p, 0)
-                    recs = [rec for rec in decode_record_batches(blob)
-                            if rec.offset >= off]
+                    recs = []
+                    if blob:    # an empty long poll decodes nothing
+                        with trace.span("kafka_decode", partition=p,
+                                        bytes=len(blob)) as sp:
+                            recs = scan_record_batches(blob)
+                            # whole batches come back, in offset order:
+                            # drop what lies below the offset asked for
+                            recs = recs[bisect.bisect_left(
+                                recs, off, key=_record_offset):]
+                            if sp:
+                                sp.add(records=len(recs))
                     out[p] = (recs, high)
         for p in retry:
             if p in offsets:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import struct
 import time
+from collections.abc import Sequence
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -299,7 +300,43 @@ def _finish_record_batch(records: list[Record], recs: bytes,
         + header + after_crc
 
 
-def _scan_records_native(data: bytes) -> Optional[list[Record]]:
+class RecordView(Sequence):
+    """The Records of one natively scanned blob, built when asked for: a
+    fetch response holds some 73,000 of them and its consumer takes 1,024
+    at a time, so the scan's index (one row a record: key start and end,
+    value start and end, -1 for null, offset, timestamp) and the blob are
+    what is kept.  A slice is a view (it pins the whole blob until it is
+    dropped); an item or an iteration builds Records."""
+
+    __slots__ = ("_data", "_rows")
+
+    def __init__(self, data: bytes, rows):
+        self._data = data
+        self._rows = rows           # (n, 6) int64
+
+    def __len__(self) -> int:
+        return len(self._rows)
+
+    def __getitem__(self, i):
+        rows = self._rows[i]
+        if isinstance(i, slice):
+            return RecordView(self._data, rows)
+        return next(iter(RecordView(self._data, rows[None])))
+
+    def payload_bytes(self) -> int:
+        ks, ke, vs, ve = self._rows[:, :4].T
+        return int((ke - ks)[ks >= 0].sum() + (ve - vs)[vs >= 0].sum())
+
+    def __iter__(self):
+        data = self._data
+        for ks, ke, vs, ve, off, ts in self._rows.tolist():
+            yield Record(
+                key=data[ks:ke] if ks >= 0 else None,
+                value=data[vs:ve] if vs >= 0 else None,
+                offset=off, timestamp_ms=ts)
+
+
+def _scan_records_native(data: bytes) -> Optional[RecordView]:
     """C fast path (hostops.cpp kafka_scan_records): zero-copy scan of
     uncompressed, header-less frames; None defers to the Python walk."""
     from transferia_tpu.native import lib as native_lib
@@ -320,29 +357,39 @@ def _scan_records_native(data: bytes) -> Optional[list[Record]]:
             return None  # corrupt/foreign framing: python path decides
         max_n += count
         pos += 12 + batch_len
-    if max_n == 0:
-        return [] if pos else None
     arr = np.empty(max_n * 6, dtype=np.int64)
+    if max_n == 0:
+        return RecordView(data, arr.reshape(0, 6)) if pos else None
     blob = np.frombuffer(data, dtype=np.uint8)
     rc = cdll.kafka_scan_records(blob, len(data), arr, max_n)
     if rc < 0:
         if rc == -1:
             raise ValueError("record batch CRC mismatch or corrupt frame")
         return None  # -2: compression/headers — python path handles
-    out = []
-    for ks, ke, vs, ve, off, ts in arr[:rc * 6].reshape(-1, 6).tolist():
-        out.append(Record(
-            key=data[ks:ke] if ks >= 0 else None,
-            value=data[vs:ve] if vs >= 0 else None,
-            offset=off, timestamp_ms=ts))
-    return out
+    return RecordView(data, arr[:rc * 6].reshape(-1, 6))
+
+
+def scan_record_batches(data: bytes) -> Sequence[Record]:
+    """`decode_record_batches` for a consumer that takes a part at a
+    time: a RecordView where the native scan applies, the list else."""
+    native = _scan_records_native(data)
+    return native if native is not None else _walk_record_batches(data)
 
 
 def decode_record_batches(data: bytes) -> list[Record]:
     """RecordBatch v2 blob(s) -> Records with absolute offsets."""
-    native = _scan_records_native(data)
-    if native is not None:
-        return native
+    return list(scan_record_batches(data))
+
+
+def payload_bytes(records: Sequence[Record]) -> int:
+    """Key and value bytes of the records, what a consumer holds of them."""
+    if isinstance(records, RecordView):
+        return records.payload_bytes()
+    return sum(len(r.key or b"") + len(r.value or b"") for r in records)
+
+
+def _walk_record_batches(data: bytes) -> list[Record]:
+    """The Python walk: every codec and header the scan leaves out."""
     out: list[Record] = []
     pos = 0
     n = len(data)

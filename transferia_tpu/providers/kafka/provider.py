@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import logging
 import threading
+from collections.abc import Sequence
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -20,7 +21,7 @@ from transferia_tpu.coordinator.interface import Coordinator
 from transferia_tpu.models.endpoint import EndpointParams, register_endpoint
 from transferia_tpu.parsers import Message
 from transferia_tpu.providers.kafka.client import KafkaClient, KafkaError
-from transferia_tpu.providers.kafka.protocol import Record
+from transferia_tpu.providers.kafka.protocol import Record, payload_bytes
 from transferia_tpu.providers.queue_common import FetchedBatch, QueueSource
 from transferia_tpu.providers.registry import (
     Provider,
@@ -28,6 +29,7 @@ from transferia_tpu.providers.registry import (
     register_provider,
 )
 from transferia_tpu.serializers import make_queue_serializer
+from transferia_tpu.stats import trace
 from transferia_tpu.transform.plugins.sharder import hash_column_to_shards
 
 logger = logging.getLogger(__name__)
@@ -103,7 +105,18 @@ def _make_client(params) -> KafkaClient:
 
 class _KafkaQueueClient:
     """QueueSource client contract over KafkaClient with coordinator-backed
-    offset checkpoints (state key kafka_offsets)."""
+    offset checkpoints (state key kafka_offsets).
+
+    A response holds what `max_bytes_per_fetch` buys (some 73,000 messages
+    of 100 bytes), `fetch` hands out `max_messages` a partition: the rest
+    stays in a per-partition remainder, scanned once (a
+    `protocol.RecordView`: the blob and its index), and the broker is
+    asked only for partitions that have none.  `positions[p]` is the offset
+    behind the last record HANDED OUT; a remainder holds only records at
+    or above it, contiguous with it, so dropping one (close, a fetch
+    error, anything that moves `positions[p]`) loses nothing: the records
+    are fetched again from `positions[p]`, and a restarted client starts
+    at committed + 1."""
 
     STATE_KEY = "kafka_offsets"
 
@@ -136,6 +149,7 @@ class _KafkaQueueClient:
                 self.STATE_KEY, {}
             )
         self.positions: dict[int, int] = {}
+        self._remainders: dict[int, Sequence[Record]] = {}
         for p in partitions:
             key = f"{params.topic}:{p}"
             if key in saved:
@@ -146,20 +160,50 @@ class _KafkaQueueClient:
                     params.topic, p, ts
                 )
 
+    def held_bytes(self) -> int:
+        """Payload bytes decoded and not handed out yet, all partitions."""
+        return sum(payload_bytes(r) for r in self._remainders.values())
+
+    def _refill(self) -> set[int]:
+        """Ask the broker for the partitions with nothing left to hand
+        out; returns the partitions asked.  One multi-partition Fetch per
+        leader (not one round-trip per partition: a 64-partition fan-in
+        would pay 64 RTTs per cycle)."""
+        dry = {p: pos for p, pos in self.positions.items()
+               if p not in self._remainders}
+        if not dry:
+            return set()
+        # what is held and what is asked for stay within
+        # max_bytes_per_fetch together, so memory is that plus the one
+        # response in hand (a broker sends a first batch of any size)
+        room = self.params.max_bytes_per_fetch - self.held_bytes()
+        if room <= 0:
+            return set()
+        try:
+            fetched = self.client.fetch_multi(
+                self.params.topic, dry, max_bytes=room,
+                # the long poll is for a client with nothing to hand
+                # out: it would stall the others' remainders
+                max_wait_ms=0 if self._remainders else 250,
+            )
+        except KafkaError:
+            self._remainders.clear()
+            raise
+        for p, (records, _high) in fetched.items():
+            if len(records):
+                self._remainders[p] = records
+        return set(dry)
+
     def fetch(self, max_messages: int = 1024) -> list[FetchedBatch]:
-        # one multi-partition Fetch per leader (not one round-trip per
-        # partition: a 64-partition fan-in would pay 64 RTTs per cycle)
-        fetched = self.client.fetch_multi(
-            self.params.topic, dict(self.positions),
-            max_bytes=self.params.max_bytes_per_fetch,
-        )
+        asked = self._refill()
         out = []
-        for p in sorted(fetched):
-            records, high = fetched[p]
-            if not records:
-                continue
-            records = records[:max_messages]
+        for p in sorted(self._remainders):
+            rem = self._remainders.pop(p)
+            records = rem[:max_messages]
+            if len(rem) > max_messages:
+                self._remainders[p] = rem[max_messages:]
             self.positions[p] = records[-1].offset + 1
+            trace.TELEMETRY.record_kafka_handout(buffered=p not in asked)
             out.append(FetchedBatch(
                 self.params.topic, p,
                 [
@@ -188,6 +232,7 @@ class _KafkaQueueClient:
             )
 
     def close(self) -> None:
+        self._remainders.clear()
         self.client.close()
 
 
@@ -340,7 +385,6 @@ class KafkaSinker(Sinker, StagedSinker):
         )
         from transferia_tpu.providers.staging import part_slug, \
             publish_guard
-        from transferia_tpu.stats import trace
 
         stage = self._stage
         if stage is None or self._stage_key != key:

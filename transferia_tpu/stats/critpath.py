@@ -49,6 +49,7 @@ _EXACT = {
     "source_decode": "decode",
     "decode_readahead": "decode",
     "native_rowgroup_decode": "decode",
+    "kafka_decode": "decode",
     "pivot": "decode",
     "batch": "decode",
     "transform": "transform",

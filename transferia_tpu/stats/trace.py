@@ -801,6 +801,12 @@ class DeviceTelemetry:
             # ahead of the acks engages
             self.parsequeue_pushes = 0
             self.parsequeue_pushes_ahead = 0
+            # fetched batches the kafka queue client handed out, and of
+            # those the ones served from the decoded remainder of an
+            # earlier response with no broker request for the partition
+            # in that call (providers/kafka/provider.py)
+            self.kafka_handouts = 0
+            self.kafka_handouts_buffered = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -896,6 +902,11 @@ class DeviceTelemetry:
             self.parsequeue_pushes += 1
             self.parsequeue_pushes_ahead += ahead
 
+    def record_kafka_handout(self, buffered: bool) -> None:
+        with self._lock:
+            self.kafka_handouts += 1
+            self.kafka_handouts_buffered += buffered
+
     def record_device_wait(self, seconds: float) -> None:
         _ledger().add(device_wait_seconds=seconds)
         with self._lock:
@@ -949,6 +960,8 @@ class DeviceTelemetry:
                     self.filter_batches_host_unsafe,
                 "parsequeue_pushes": self.parsequeue_pushes,
                 "parsequeue_pushes_ahead": self.parsequeue_pushes_ahead,
+                "kafka_handouts": self.kafka_handouts,
+                "kafka_handouts_buffered": self.kafka_handouts_buffered,
             }
 
     def fold_into(self, metrics) -> None:
