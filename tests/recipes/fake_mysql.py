@@ -25,6 +25,9 @@ class FakeMyTable:
         self.name = name
         self.columns = columns
         self.rows = rows or []
+        # the primary key's columns in index order, where that is not
+        # the table's column order (information_schema.STATISTICS)
+        self.pk_order: list[str] | None = None
 
 
 class FakeMySQL:
@@ -33,6 +36,10 @@ class FakeMySQL:
         self.password = password
         self.tables: dict[tuple[str, str], FakeMyTable] = {}
         self.queries: list[str] = []
+        # SELECTs that paged by OFFSET (the source must send none), and
+        # whether such a statement is refused outright
+        self.offset_statements = 0
+        self.refuse_offset = False
         self.lock = threading.RLock()
         self.port = 0
         self._srv = None
@@ -316,7 +323,8 @@ class _MySession:
     def dispatch(self, sql: str):
         fake = self.fake
         low = " ".join(sql.lower().split())
-        if "from information_schema.tables" in low:
+        if "from information_schema.tables" in low \
+                and "data_length" not in low:
             m = re.search(r"table_schema = '(\w+)'", low)
             db = m.group(1)
             with fake.lock:
@@ -357,31 +365,46 @@ class _MySession:
             except (TypeError, ValueError):
                 best = max(vals) if vals else None
             return self.send_rows(["m"], [[best]])
+        if fake.refuse_offset and " offset " in f" {low} ":
+            raise ValueError("fake mysql: OFFSET refused")
+        if "from information_schema.statistics" in low:
+            m = re.search(r"table_schema = '(\w+)' and table_name = "
+                          r"'(\w+)'", low)
+            t = fake.tables.get((m.group(1), m.group(2))) if m else None
+            order = (t.pk_order or [c[0] for c in t.columns if c[3]]) \
+                if t else []
+            return self.send_rows(["name"], [[n] for n in order])
+        if "data_length + index_length" in low:
+            m = re.search(r"table_schema = '(\w+)' and table_name = "
+                          r"'(\w+)'", low)
+            t = fake.tables.get((m.group(1), m.group(2))) if m else None
+            size = sum(len(str(v)) for r in (t.rows if t else [])
+                       for v in r.values())
+            return self.send_rows(["size"], [[size]])
+        m = re.match(r"select min\(`(\w+)`\) as lo, max\(`\1`\) as hi "
+                     r"from `(\w+)`\.`(\w+)`(?: where (.*))?$", low)
+        if m:
+            t = fake.tables.get((m.group(2), m.group(3)))
+            rows = self._where(list(t.rows) if t else [], m.group(4))
+            vals = [float(r[m.group(1)]) for r in rows
+                    if r.get(m.group(1)) is not None]
+            lo, hi = (min(vals), max(vals)) if vals else (None, None)
+            return self.send_rows(
+                ["lo", "hi"],
+                [[None if lo is None else int(lo),
+                  None if hi is None else int(hi)]])
         m = re.match(r"select (.*) from `(\w+)`\.`(\w+)`"
                      r"(?: where (.*?))?(?: order by (.*?))?"
-                     r" limit (\d+)(?: offset (\d+))?$", low, re.S)
+                     r"(?: limit (\d+)(?: offset (\d+))?)?$", low, re.S)
         if m:
+            if m.group(7) is not None:
+                fake.offset_statements += 1
             t = fake.tables.get((m.group(2), m.group(3)))
             if t is None:
                 raise ValueError(f"Table {m.group(3)} doesn't exist")
             cols = [c.strip().strip("`")
                     for c in m.group(1).split(",")]
-            rows = list(t.rows)
-            if m.group(4):
-                cm = re.search(r"`(\w+)` > '?([^')]*)'?", m.group(4))
-                if cm:
-                    field, lit = cm.group(1), cm.group(2)
-
-                    def gt(r):
-                        v = r.get(field)
-                        if v is None:
-                            return False
-                        try:
-                            return float(v) > float(lit)
-                        except (TypeError, ValueError):
-                            return str(v) > lit
-
-                    rows = [r for r in rows if gt(r)]
+            rows = self._where(list(t.rows), m.group(4))
             if m.group(5):
                 order_col = m.group(5).split(",")[0].strip().strip("`")
 
@@ -393,9 +416,9 @@ class _MySession:
                         return (1, str(v))
 
                 rows.sort(key=key_fn)
-            lim = int(m.group(6))
             off = int(m.group(7) or 0)
-            window = rows[off:off + lim]
+            window = rows[off:off + int(m.group(6))] if m.group(6) \
+                else rows
             return self.send_rows(
                 cols, [[r.get(c) for c in cols] for r in window]
             )
@@ -404,6 +427,33 @@ class _MySession:
             self.apply_write(sql)
             return self.send_ok()
         raise ValueError(f"fake mysql: unhandled query: {sql[:120]}")
+
+    @staticmethod
+    def _where(rows: list, where) -> list:
+        """`col` OP literal [AND ...], OP one of > >= < <= = (numbers
+        compared as numbers); parentheses around the whole are dropped."""
+        if not where:
+            return rows
+        for cond in where.strip("() ").split(" and "):
+            cm = re.match(r"\(?`(\w+)` (>=|<=|>|<|=) '?([^')]*)'?\)?$",
+                          cond.strip())
+            if cm is None:
+                raise ValueError(f"fake mysql: unhandled condition: {cond}")
+            field, op, lit = cm.groups()
+
+            def keep(r, field=field, op=op, lit=lit):
+                v = r.get(field)
+                if v is None:
+                    return False
+                try:
+                    a, b = float(v), float(lit)
+                except (TypeError, ValueError):
+                    a, b = str(v), lit
+                return {">": a > b, ">=": a >= b, "<": a < b,
+                        "<=": a <= b, "=": a == b}[op]
+
+            rows = [r for r in rows if keep(r)]
+        return rows
 
     def apply_write(self, sql: str):
         fake = self.fake

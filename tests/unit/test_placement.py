@@ -309,3 +309,141 @@ def test_placement_instant_lands_on_the_transform_span(fast_link):
         "strategy": "host", "reason": "host_first", "rows": 257,
         "host_ns_row": -1.0, "device_ns_row": -1.0,
         "predicted_device_ns_row": -1.0}
+
+
+# -- an activation's chains share the first host reading (PlacementBook) --------
+
+def _plain():
+    return run_chain(CONFIG, make_batch(), fused=False)
+
+
+def test_a_second_chain_of_the_activation_explores_with_its_first_batch(
+        fast_link):
+    from transferia_tpu.transform.fused import PlacementBook
+
+    book, plain = PlacementBook(), _plain()
+    first = build_chain(CONFIG, placement_book=book)
+    batches_equal(plain, first.apply(make_batch()))
+    assert _placements() == {"host_first": 1}
+    # a part of one batch: its chain takes the first chain's reading and
+    # asks the device at once
+    for n in (1, 2):
+        later = build_chain(CONFIG, placement_book=book)
+        batches_equal(plain, later.apply(make_batch()))
+        assert _placements() == {"host_first": 1, "device_explore": n}
+        step = later.plan_for(TID, make_batch(4).schema).steps[0]
+        read = first.plan_for(TID, make_batch(4).schema).steps[0]
+        assert step._ns_row["host"] == read._ns_row["host"] > 0
+
+
+def test_a_chain_that_starts_with_a_whole_batch_keeps_off_the_book(
+        fast_link, monkeypatch):
+    from transferia_tpu.transform.fused import PlacementBook
+
+    book = PlacementBook()
+    n = make_batch().n_rows
+    monkeypatch.setattr(DeviceFusedStep, "SHARED_READING_MAX_ROWS", n - 1)
+    for _ in range(2):
+        build_chain(CONFIG, placement_book=book).apply(make_batch())
+    assert _placements() == {"host_first": 2} and not book._readings
+    # the small parts that follow share a reading of their own
+    monkeypatch.setattr(DeviceFusedStep, "SHARED_READING_MAX_ROWS", n)
+    for _ in range(2):
+        build_chain(CONFIG, placement_book=book).apply(make_batch())
+    assert _placements() == {"host_first": 3, "device_explore": 1}
+
+
+def test_chains_without_a_book_measure_the_host_each(fast_link):
+    for _ in range(2):
+        build_chain(CONFIG).apply(make_batch())
+    assert _placements() == {"host_first": 2}
+
+
+def test_a_planned_chain_that_never_runs_claims_no_reading(fast_link):
+    from transferia_tpu.transform.fused import PlacementBook
+
+    book = PlacementBook()
+    build_chain(CONFIG, placement_book=book).plan_for(
+        TID, make_batch(4).schema)
+    assert not book._readings
+    build_chain(CONFIG, placement_book=book).apply(make_batch())
+    assert _placements() == {"host_first": 1}
+
+
+def test_a_failed_measuring_batch_leaves_the_others_to_measure(
+        fast_link, monkeypatch):
+    from transferia_tpu.transform.fused import PlacementBook
+
+    book = PlacementBook()
+    first = build_chain(CONFIG, placement_book=book)
+    step = first.plan_for(TID, make_batch(4).schema).steps[0]
+
+    def boom(batch):
+        raise RuntimeError("host strategy failed")
+
+    monkeypatch.setattr(step, "_apply_host", boom)
+    with pytest.raises(RuntimeError):
+        first.apply(make_batch())
+    (reading,) = book._readings.values()
+    assert reading.done.is_set() and reading.ns_row < 0
+    build_chain(CONFIG, placement_book=book).apply(make_batch())
+    assert _placements() == {"host_first": 2}
+
+
+def test_a_chain_waits_out_a_measuring_batch_under_way(fast_link,
+                                                       monkeypatch):
+    import threading
+
+    from transferia_tpu.transform.fused import PlacementBook
+
+    book = PlacementBook()
+    first = build_chain(CONFIG, placement_book=book)
+    step = first.plan_for(TID, make_batch(4).schema).steps[0]
+    entered, release = threading.Event(), threading.Event()
+    host = step._apply_host
+
+    def slow_host(batch):
+        entered.set()
+        assert release.wait(30)
+        return host(batch)
+
+    monkeypatch.setattr(step, "_apply_host", slow_host)
+    t1 = threading.Thread(target=first.apply, args=(make_batch(),))
+    t1.start()
+    assert entered.wait(30)
+    later = build_chain(CONFIG, placement_book=book)
+    t2 = threading.Thread(target=later.apply, args=(make_batch(),))
+    t2.start()
+    t2.join(0.3)
+    assert t2.is_alive() and _placements() == {"host_first": 1}
+    release.set()
+    t1.join(30)
+    t2.join(30)
+    assert not t1.is_alive() and not t2.is_alive()
+    assert _placements() == {"host_first": 1, "device_explore": 1}
+
+
+def test_a_snapshots_parts_get_the_activations_book(monkeypatch):
+    import inspect
+
+    from tests.unit.test_factories import make_transfer
+    from transferia_tpu.factories import sink as sink_factory
+    from transferia_tpu.tasks import snapshot
+    from transferia_tpu.transform.fused import PlacementBook
+
+    seen = []
+    real = sink_factory.build_chain
+
+    def spy(config, stats=None, placement_book=None):
+        seen.append(placement_book)
+        return real(config, stats, placement_book)
+
+    monkeypatch.setattr(sink_factory, "build_chain", spy)
+    transfer, _ = make_transfer("book", transformation=CONFIG)
+    book = PlacementBook()
+    sink_factory.make_async_sink(transfer, snapshot_stage=True,
+                                 placement_book=book).close()
+    sink_factory.make_async_sink(transfer, snapshot_stage=True).close()
+    assert seen == [book, None]
+    assert "placement_book=self._placement_book" in inspect.getsource(
+        snapshot.SnapshotLoader._upload_part)

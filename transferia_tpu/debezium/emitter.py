@@ -33,8 +33,10 @@ from transferia_tpu.columnar.batch import ColumnBatch
 from transferia_tpu.debezium.types import (
     _split_original,
     encode_value,
+    mysql_datetime_millis,
     to_connect,
 )
+from transferia_tpu.stats import trace
 
 
 def _field_schema(cs) -> dict:
@@ -74,7 +76,11 @@ class DebeziumEmitter:
         (pkg/debezium/packer/ parity).  topic: the sink's FIXED topic when
         it writes into one topic — SR subjects derive from the topic the
         messages actually land on (TopicNameStrategy); default is the
-        kafka sink's per-table naming '<namespace>.<table>'."""
+        kafka sink's per-table naming '<namespace>.<table>'.
+        connector / source_db_type: what `source.connector` and
+        `source.db` say; the sink's factory fills them in from the
+        transfer's source endpoint where it knows what Debezium's own
+        connector for that source says (providers/kafka)."""
         self.sink_topic = topic
         self.topic_prefix = topic_prefix
         self.connector = connector
@@ -198,9 +204,10 @@ class DebeziumEmitter:
             "txId": item.txn_id or None,
         }
 
-    def emit_item(self, item: ChangeItem,
-                  snapshot: bool = False) -> list[tuple[bytes, Optional[bytes]]]:
-        """One row -> [(key, value)] (+ tombstone for deletes)."""
+    def emit_item(self, item: ChangeItem, snapshot: bool = False
+                  ) -> list[tuple[Optional[bytes], Optional[bytes]]]:
+        """One row -> [(key, value)] (+ tombstone for deletes); the key
+        is None for a table without a primary key."""
         schema = item.table_schema
         if schema is None:
             raise ValueError("debezium emitter requires table_schema")
@@ -239,10 +246,11 @@ class DebeziumEmitter:
             "op": op,
             "ts_ms": int(time.time() * 1000),
         }
+        keyless = not schema.key_columns()
         if self.value_packer is not None:
             # Confluent wire format: schemas live in the registry
             topic = self.topic_for(item)
-            key_b = self.key_packer.pack(
+            key_b = None if keyless else self.key_packer.pack(
                 topic, self._key_schema(item, schema), key_vals)
             value_b = self.value_packer.pack(
                 topic, self._value_schema(item, schema), value_payload)
@@ -257,31 +265,46 @@ class DebeziumEmitter:
                          "payload": value_payload}
         else:
             key_obj, value_obj = key_vals, value_payload
-        key_b = json.dumps(key_obj, separators=(",", ":"),
-                           default=str).encode()
+        # a table without a primary key: a null message key, as Debezium
+        # gives it (the sink spreads null keys over the partitions)
+        key_b = None if keyless else json.dumps(
+            key_obj, separators=(",", ":"), default=str).encode()
         value_b = json.dumps(value_obj, separators=(",", ":"),
                              default=str).encode()
-        out: list[tuple[bytes, Optional[bytes]]] = [(key_b, value_b)]
+        out: list[tuple[Optional[bytes], Optional[bytes]]] = [
+            (key_b, value_b)]
         if item.kind == Kind.DELETE and self.emit_tombstones:
             out.append((key_b, None))
         return out
 
     def emit_batch(self, batch, snapshot: bool = False
-                   ) -> list[tuple[bytes, Optional[bytes]]]:
+                   ) -> list[tuple[Optional[bytes], Optional[bytes]]]:
         """ColumnBatch or row list -> envelope pairs, order-preserving."""
+        with trace.span("serialize", format="debezium") as sp:
+            out, rows, fast = self._emit_batch(batch, snapshot)
+            if sp:
+                sp.add(path="fast" if fast else "row", rows=rows)
+        if rows:
+            trace.TELEMETRY.record_debezium_rows(rows, fast)
+        return out
+
+    def _emit_batch(self, batch, snapshot: bool) -> tuple[list, int, bool]:
+        """(pairs, rows rendered, whether the columnar path took them)."""
         items: Iterable[ChangeItem]
         if isinstance(batch, ColumnBatch):
             fast = self._emit_columnar_fast(batch, snapshot)
             if fast is not None:
-                return fast
+                return fast, batch.n_rows, True
             items = batch.to_rows()
         else:
             items = batch
         out = []
+        rows = 0
         for it in items:
             if it.is_row_event():
+                rows += 1
                 out.extend(self.emit_item(it, snapshot))
-        return out
+        return out, rows, False
 
     # -- vectorized insert-only columnar path --------------------------------
 
@@ -332,6 +355,8 @@ class DebeziumEmitter:
                     return None
                 if data.dtype.kind == "M":
                     data = data.astype("datetime64[us]").astype(np.int64)
+                if mysql_datetime_millis(orig):
+                    data = data.astype(np.int64) // 1000
                 frags = data.astype("U").tolist()
             elif ct in (CanonicalType.FLOAT, CanonicalType.DOUBLE):
                 data = col.data
@@ -392,8 +417,6 @@ class DebeziumEmitter:
             if not (batch.kinds == KIND_CODES[Kind.INSERT]).all():
                 return None
         key_cols = schema.key_columns()
-        if not key_cols:
-            return None
         names = [cs.name for cs in schema]
         if set(n for n in names) - set(batch.columns.keys()):
             return None
@@ -503,9 +526,12 @@ class DebeziumEmitter:
 
         col_frags = [frag_by_name[nm] for nm in names]
         after_strs = list(map(after_fmt.__mod__, zip(*col_frags)))
-        key_frags = [frag_by_name[c.name] for c in key_cols]
-        key_strs = list(map(key_fmt.__mod__, zip(*key_frags)))
         value_strs = list(map(value_fmt.__mod__,
                               zip(after_strs, src_strs)))
+        if not key_cols:
+            # no primary key: a null message key (emit_item's rule)
+            return [(None, v.encode()) for v in value_strs]
+        key_frags = [frag_by_name[c.name] for c in key_cols]
+        key_strs = list(map(key_fmt.__mod__, zip(*key_frags)))
         return [(k.encode(), v.encode())
                 for k, v in zip(key_strs, value_strs)]

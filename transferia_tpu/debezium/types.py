@@ -9,7 +9,8 @@ pg: uuid/xml/hstore (semantic names), money (currency-normalized string),
 range families (text), inet/cidr/macaddr, bit/varbit (Bits), arrays
 (Connect array of the element mapping, element-wise encode);
 mysql: bigint unsigned (precise Connect Decimal — int64 overflows),
-enum/set (Enum/EnumSet), year (Year), time (MicroTime), bit(n) (Bits).
+enum/set (Enum/EnumSet), year (Year), time (MicroTime), bit(n) (Bits),
+datetime(0..3) (Timestamp, epoch milliseconds).
 """
 
 from __future__ import annotations
@@ -131,6 +132,8 @@ def to_connect(cs) -> tuple[Any, Optional[str], dict]:
             return "bytes", "io.debezium.data.Bits", \
                 ({"length": args} if args else {})
     if provider == "mysql":
+        if mysql_datetime_millis(original):
+            return "int64", "io.debezium.time.Timestamp", {}
         if base == "bigint unsigned":
             # int64 overflows above 2^63-1: precise Connect Decimal
             # (mysql/emitter.go precise handling of unsigned bigint)
@@ -155,6 +158,17 @@ def to_connect(cs) -> tuple[Any, Optional[str], dict]:
 
     ctype, semantic = TO_CONNECT[cs.data_type]
     return ctype, semantic, {}
+
+
+@functools.lru_cache(maxsize=4096)
+def mysql_datetime_millis(original_type: str) -> bool:
+    """MySQL `datetime` / `datetime(0..3)`: Debezium's default
+    time.precision.mode (adaptive_time_microseconds) gives it
+    io.debezium.time.Timestamp, epoch MILLIseconds; `datetime(4..6)`
+    keeps MicroTimestamp.  The column holds microseconds either way."""
+    provider, base, args = _split_original(original_type or "")
+    return provider == "mysql" and base == "datetime" \
+        and (not args.isdigit() or int(args) <= 3)
 
 
 class _Elem:
@@ -299,6 +313,9 @@ def encode_value(ctype: CanonicalType, v: Any,
             if base in ("bit", "bit varying", "varbit"):
                 return _encode_bits(v, _args)
         if provider == "mysql":
+            if ctype == CanonicalType.TIMESTAMP and isinstance(v, int) \
+                    and mysql_datetime_millis(original_type):
+                return v // 1000
             if base == "bigint unsigned":
                 return _encode_unscaled_decimal(v)
             if base == "time":

@@ -54,7 +54,8 @@ def _system_table_filter(tid: TableID) -> bool:
 def make_sinker(transfer, metrics: Optional[Metrics] = None,
                 snapshot_stage: bool = False,
                 stats: Optional[SinkerStats] = None,
-                post_transform_wrap=None) -> Sinker:
+                post_transform_wrap=None,
+                placement_book=None) -> Sinker:
     """Build the synchronous middleware stack over the provider's raw sink."""
     metrics = metrics or Metrics()
     provider = get_provider(transfer.dst_provider(), transfer, metrics)
@@ -91,7 +92,8 @@ def make_sinker(transfer, metrics: Optional[Metrics] = None,
         # injection point for observers of post-transform data (the
         # snapshot loader's inline fingerprint tap)
         s = post_transform_wrap(s)
-    chain = build_chain(transfer.transformation)
+    chain = build_chain(transfer.transformation,
+                        placement_book=placement_book)
     if chain is not None:
         s = TransformationMW(s, chain)
     s = InputMetering(s, agent)
@@ -104,7 +106,8 @@ def make_sinker(transfer, metrics: Optional[Metrics] = None,
 def make_async_sink(transfer, metrics: Optional[Metrics] = None,
                     snapshot_stage: bool = False,
                     stats: Optional[SinkerStats] = None,
-                    post_transform_wrap=None) -> AsyncSink:
+                    post_transform_wrap=None,
+                    placement_book=None) -> AsyncSink:
     """MakeAsyncSink (sink_factory.go:31): full async pipeline.
 
     Providers may supply a native AsyncSink (constructBaseAsyncSink:173);
@@ -119,7 +122,8 @@ def make_async_sink(transfer, metrics: Optional[Metrics] = None,
     if native is not None:
         return ErrorTracker(native)
     sync_stack = make_sinker(transfer, metrics, snapshot_stage, stats,
-                             post_transform_wrap=post_transform_wrap)
+                             post_transform_wrap=post_transform_wrap,
+                             placement_book=placement_book)
     buf_cfg = capability(transfer.dst, "bufferer_config", None)
     if buf_cfg is not None and not isinstance(buf_cfg, BuffererConfig):
         buf_cfg = BuffererConfig(**buf_cfg) if isinstance(buf_cfg, dict) \

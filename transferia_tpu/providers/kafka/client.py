@@ -207,12 +207,17 @@ class KafkaClient:
                 self._drop_conn(node)
 
     def _roundtrip(self, api_key: int, api_version: int, body: bytes,
-                   node="boot") -> Reader:
+                   node="boot", span: str = "kafka_roundtrip",
+                   **span_args) -> Reader:
+        """One request and its response.  `span` names the span it is
+        recorded as: a Produce is the sink's write (`sink_push` with
+        `direction="kafka_produce"`, its `bytes` the request's), every
+        other call `kafka_roundtrip` (its `bytes` the response's)."""
         from transferia_tpu.chaos.failpoints import failpoint
         from transferia_tpu.stats import trace
 
         failpoint("client.kafka.roundtrip")  # before the lock: may sleep
-        with trace.span("kafka_roundtrip", api=api_key) as sp, self._lock:
+        with trace.span(span, api=api_key, **span_args) as sp, self._lock:
             sock = self._conn_for(node)
             self._corr += 1
             corr = self._corr
@@ -231,7 +236,8 @@ class KafkaClient:
                     # to the first response byte: the broker's long
                     # poll, apart from reading the response
                     sp.add(wait_s=round(time.perf_counter() - t_sent, 6),
-                           bytes=size)
+                           **({"bytes": size} if "bytes" not in span_args
+                              else {"response_bytes": size}))
                 payload = recv_exact(sock, size)  # trtpu: ignore[LCK001]
             except (OSError, ConnectionError) as e:
                 self._drop_conn(node)
@@ -297,23 +303,28 @@ class KafkaClient:
             else "boot"
 
     def _routed(self, topic: str, partition: int, api: int, version: int,
-                body: bytes) -> Reader:
+                body: bytes, **span_args) -> Reader:
         """Round-trip to the partition leader; one metadata-refresh retry
         on routing errors."""
         node = self._leader_node(topic, partition)
         try:
-            return self._roundtrip(api, version, body, node)
+            return self._roundtrip(api, version, body, node, **span_args)
         except KafkaError:
             self.metadata([topic])
             node = self._leader_node(topic, partition)
-            return self._roundtrip(api, version, body, node)
+            return self._roundtrip(api, version, body, node, **span_args)
 
     # -- produce ------------------------------------------------------------
     def produce(self, topic: str, partition: int,
                 records: list[Record], acks: int = -1,
                 timeout_ms: int = 30_000, compression: str = "") -> int:
         """Append records; returns the base offset assigned (Produce v3)."""
-        batch = encode_record_batch(records, compression=compression)
+        from transferia_tpu.stats import trace
+
+        with trace.span("kafka_encode") as sp:
+            batch = encode_record_batch(records, compression=compression)
+            if sp:
+                sp.add(records=len(records), bytes=len(batch))
         body = enc_str(None)                      # transactional id
         body += struct.pack("!hi", acks, timeout_ms)
         body += struct.pack("!i", 1) + enc_str(topic)
@@ -321,7 +332,10 @@ class KafkaClient:
         body += enc_bytes(batch)
 
         def attempt() -> int:
-            r = self._routed(topic, partition, API_PRODUCE, 3, body)
+            r = self._routed(topic, partition, API_PRODUCE, 3, body,
+                             span="sink_push", direction="kafka_produce",
+                             bytes=len(body), records=len(records),
+                             partitions=1)
             base_offset = -1
             for _ in range(r.i32()):
                 r.string()
@@ -386,21 +400,33 @@ class KafkaClient:
         by_topic: dict[str, list[tuple[int, list[Record]]]] = {}
         for (topic, partition), records in sorted(messages.items()):
             by_topic.setdefault(topic, []).append((partition, records))
-        body = enc_str(transactional_id)
-        body += struct.pack("!hi", acks, timeout_ms)
-        body += struct.pack("!i", len(by_topic))
+        from transferia_tpu.stats import trace
+
+        # the request in pieces, joined once: a part's records are
+        # hundreds of megabytes, and `+=` would copy them a partition
+        pieces = [enc_str(transactional_id),
+                  struct.pack("!hi", acks, timeout_ms),
+                  struct.pack("!i", len(by_topic))]
         total = 0
-        for topic, parts in sorted(by_topic.items()):
-            body += enc_str(topic)
-            body += struct.pack("!i", len(parts))
-            for partition, records in parts:
-                batch = encode_record_batch(
-                    records, producer_id=producer_id,
-                    producer_epoch=producer_epoch)
-                body += struct.pack("!i", partition)
-                body += enc_bytes(batch)
-                total += len(records)
-        r = self._roundtrip(API_PRODUCE, 3, body)
+        with trace.span("kafka_encode") as sp:
+            for topic, parts in sorted(by_topic.items()):
+                pieces.append(enc_str(topic))
+                pieces.append(struct.pack("!i", len(parts)))
+                for partition, records in parts:
+                    batch = encode_record_batch(
+                        records, producer_id=producer_id,
+                        producer_epoch=producer_epoch)
+                    pieces.append(struct.pack("!ii", partition,
+                                              len(batch)))
+                    pieces.append(batch)
+                    total += len(records)
+            body = b"".join(pieces)
+            if sp:
+                sp.add(records=total, bytes=len(body))
+        del pieces
+        r = self._roundtrip(API_PRODUCE, 3, body, span="sink_push",
+                            direction="kafka_produce", bytes=len(body),
+                            records=total, partitions=len(messages))
         for _ in range(r.i32()):
             r.string()
             for _ in range(r.i32()):

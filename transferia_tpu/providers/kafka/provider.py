@@ -267,14 +267,28 @@ class KafkaSinker(Sinker, StagedSinker):
     the exactly-once claim holds for the fake-backed wire, and real
     brokers should keep the at-least-once path."""
 
-    def __init__(self, params: KafkaTargetParams):
+    def __init__(self, params: KafkaTargetParams, snapshot: bool = False,
+                 source=None):
+        """snapshot: this sink takes the rows of an initial load (the
+        provider's `snapshot_sinker`): the Debezium serializer marks
+        them `op: "r"`, `source.snapshot: "true"`, as Debezium's
+        snapshot.mode=initial does.  source: the transfer's source
+        endpoint, for the envelope's `source` block."""
         self.params = params
         self.client = _make_client(params)
         cfg = dict(params.serializer_config or {})
-        if params.serializer == "debezium" and params.topic:
-            # single-topic sinks: SR subjects must derive from the real
-            # topic (TopicNameStrategy)
-            cfg.setdefault("topic", params.topic)
+        if params.serializer == "debezium":
+            if source is not None and source.provider() == "mysql":
+                # what Debezium's MySQL connector says of itself
+                cfg.setdefault("connector", "mysql")
+                cfg.setdefault("source_db_type", source.database)
+            if params.topic:
+                # single-topic sinks: SR subjects must derive from the
+                # real topic (TopicNameStrategy)
+                cfg.setdefault("topic", params.topic)
+            if snapshot:
+                cfg.setdefault("snapshot", True)
+        self._null_key_turn = 0
         self.serializer = make_queue_serializer(params.serializer, **cfg)
         self._partitions: dict[str, list[int]] = {}
         self._stage = None  # staging.PartStage when open
@@ -287,14 +301,21 @@ class KafkaSinker(Sinker, StagedSinker):
             self._partitions[topic] = meta.get(topic) or [0]
         return self._partitions[topic]
 
-    @staticmethod
-    def _key_partitions(pairs, n_parts: int):
+    def _key_partitions(self, pairs, n_parts: int):
         """crc32c(key) % n_parts per pair, batched through the native lib
-        when present."""
+        when present (Kafka's own default partitioner hashes the key with
+        murmur2: a key's partition here is stable, not the one a Java
+        producer would pick).  A batch of null keys (a table without a
+        primary key) is dealt round the partitions in turn, as Kafka's
+        partitioner spreads records without a key."""
         import numpy as np
 
         from transferia_tpu.native import lib as native_lib
 
+        if all(k is None for k, _ in pairs):
+            turn = self._null_key_turn
+            self._null_key_turn = (turn + len(pairs)) % n_parts
+            return (np.arange(len(pairs), dtype=np.int64) + turn) % n_parts
         cdll = native_lib()
         keys = [bytes(k or b"") for k, _ in pairs]
         if cdll is not None:
@@ -446,7 +467,13 @@ class KafkaProvider(Provider):
 
     def sinker(self):
         if isinstance(self.transfer.dst, KafkaTargetParams):
-            return KafkaSinker(self.transfer.dst)
+            return KafkaSinker(self.transfer.dst, source=self.transfer.src)
+        return None
+
+    def snapshot_sinker(self):
+        if isinstance(self.transfer.dst, KafkaTargetParams):
+            return KafkaSinker(self.transfer.dst, snapshot=True,
+                               source=self.transfer.src)
         return None
 
     def test(self) -> TestResult:

@@ -3,7 +3,9 @@ typesystem.go rules; sharded reads via key-range splitting)."""
 
 from __future__ import annotations
 
+import datetime
 import logging
+import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -13,6 +15,7 @@ from transferia_tpu.abstract.interfaces import (
     IncrementalStorage,
     PositionalStorage,
     Pusher,
+    ShardingStorage,
     Sinker,
     Storage,
     TableInfo,
@@ -32,12 +35,14 @@ from transferia_tpu.models.endpoint import (
     EndpointParams,
     register_endpoint,
 )
+from transferia_tpu.providers.mysql import textrows
 from transferia_tpu.providers.mysql.wire import MySQLConnection, MySQLError
 from transferia_tpu.providers.registry import (
     Provider,
     TestResult,
     register_provider,
 )
+from transferia_tpu.stats import trace
 from transferia_tpu.typesystem.rules import (
     register_source_rules,
     register_target_rules,
@@ -151,14 +156,39 @@ def _coerce(cs: ColSchema, v: Optional[str]):
         return v not in ("0", "", "false")
     if t == CanonicalType.STRING:
         return v.encode("utf-8", "surrogateescape")
+    if t in (CanonicalType.TIMESTAMP, CanonicalType.DATE):
+        # DATETIME/TIMESTAMP text -> microseconds, DATE -> days since
+        # 1970-01-01; a zero date (0000-00-00) is no instant: NULL
+        try:
+            at = datetime.datetime.fromisoformat(v)
+        except ValueError:
+            return None
+        since = at - datetime.datetime(1970, 1, 1)
+        return since.days if t == CanonicalType.DATE \
+            else since // datetime.timedelta(microseconds=1)
     return v
 
 
+# The least a key range has to carry to be a part of its own
+# (MySQLStorage.shard_table).  A part's fixed cost - its connection and
+# handshake, its staged commit - is 0.09-0.17 s, and a part thread moves
+# 0.35-0.5 MB of result-set text a second through decode, serializer and
+# produce (PERF.md section 6, PR 33): from here on the fixed cost is
+# under a tenth of the part.
+_MIN_PART_BYTES = 1 << 20
+
+
 class MySQLStorage(Storage, PositionalStorage, IncrementalStorage,
-                   SampleableStorage):
-    def __init__(self, params: MySQLSourceParams):
+                   SampleableStorage, ShardingStorage):
+    def __init__(self, params: MySQLSourceParams, parts: int = 1):
+        """parts: how many part threads the transfer runs (a large table
+        is cut into that many key ranges, `shard_table`)."""
         self.params = params
+        self.parts = max(1, int(parts))
         self._c: Optional[MySQLConnection] = None
+        # part threads share one storage: a table's schema is read once
+        self._load_lock = threading.Lock()
+        self._load_schemas: dict[TableID, TableSchema] = {}
 
     @property
     def conn(self) -> MySQLConnection:
@@ -245,58 +275,174 @@ class MySQLStorage(Storage, PositionalStorage, IncrementalStorage,
         )
         return {}
 
-    def load_table(self, table: TableDescription, pusher: Pusher) -> None:
-        schema = self.table_schema(table.id)
-        cols = ", ".join(f"`{c.name}`" for c in schema)
-        conn = _conn(self.params)
-        keys = schema.key_columns()
+    # -- intra-table sharding: ranges of the primary key -------------------
+    def shard_table(self, table: TableDescription) -> list[TableDescription]:
+        """A table in as many ranges of its primary key as there are
+        part threads, or as many as leave each `_MIN_PART_BYTES` of the
+        table's size if that is fewer.  The ranges
+        are cut on the first key column that takes more than one value
+        (MIN/MAX under equality on the columns before it: one warehouse
+        of a (warehouse, district, id) key is cut by district), the first
+        open below and the last open above, so rows written since are in
+        one of them.  No integer key column to cut on, a filter already
+        in place or a small table: one part."""
+        if self.parts <= 1 or table.filter:
+            return [table]
+        want = min(self.parts,
+                   self.table_size_in_bytes(table.id) // _MIN_PART_BYTES)
+        if want <= 1:
+            return [table]
         ref = f"`{table.id.namespace}`.`{table.id.name}`"
-        bs = self.params.batch_rows
+        fixed: list[str] = []
+        for key in self._primary_key(table.id):
+            if not key.data_type.is_integer:
+                break
+            where = f" WHERE {' AND '.join(fixed)}" if fixed else ""
+            row = self.conn.query(
+                f"SELECT MIN(`{key.name}`) AS lo, MAX(`{key.name}`) AS hi "
+                f"FROM {ref}{where}")
+            if not row or row[0]["lo"] is None:
+                break
+            lo, hi = int(row[0]["lo"]), int(row[0]["hi"])
+            if lo == hi:
+                fixed.append(f"`{key.name}` = {lo}")
+                continue
+            n = min(want, hi - lo + 1)
+            cuts = [lo + (hi - lo + 1) * i // n for i in range(1, n)]
+            out = []
+            for i in range(n):
+                conds = list(fixed)
+                if i > 0:
+                    conds.append(f"`{key.name}` >= {cuts[i - 1]}")
+                if i < n - 1:
+                    conds.append(f"`{key.name}` < {cuts[i]}")
+                out.append(TableDescription(
+                    id=table.id, filter=" AND ".join(conds),
+                    eta_rows=table.eta_rows // n))
+            return out
+        return [table]
+
+    def _primary_key(self, table: TableID) -> list[ColSchema]:
+        """The primary key's columns in the index's order (the order a
+        range on a leading column can use), which need not be the
+        table's column order; that order where the server does not say."""
+        schema = self.table_schema(table)
         try:
-            if len(keys) == 1:
-                # keyset pagination: stable under concurrent writes and
-                # O(N) server-side, unlike OFFSET scans
-                key = keys[0].name
-                last = None
-                while True:
-                    conds = []
-                    if table.filter:
-                        conds.append(f"({table.filter})")
-                    if last is not None:
-                        conds.append(f"`{key}` > {_sql_literal(last)}")
-                    where = f" WHERE {' AND '.join(conds)}" if conds else ""
-                    rows = conn.query(
-                        f"SELECT {cols} FROM {ref}{where} "
-                        f"ORDER BY `{key}` LIMIT {bs}"
-                    )
-                    if not rows:
-                        return
-                    self._push_rows(rows, schema, table.id, pusher)
-                    last_raw = rows[-1].get(key)
-                    last = _coerce(schema.find(key), last_raw)
-                    if len(rows) < bs:
-                        return
-            else:
-                # multi/no-PK fallback: OFFSET paging over a fixed ORDER BY
-                # (full pk list) so the scan order is at least deterministic
-                order = ", ".join(f"`{k.name}`" for k in keys) if keys \
-                    else ""
-                order_sql = f" ORDER BY {order}" if order else ""
-                where = f" WHERE {table.filter}" if table.filter else ""
-                offset = 0
-                while True:
-                    rows = conn.query(
-                        f"SELECT {cols} FROM {ref}{where}{order_sql} "
-                        f"LIMIT {bs} OFFSET {offset}"
-                    )
-                    if not rows:
-                        return
-                    self._push_rows(rows, schema, table.id, pusher)
-                    if len(rows) < bs:
-                        return
-                    offset += bs
+            rows = self.conn.query(
+                "SELECT COLUMN_NAME AS name "
+                "FROM information_schema.STATISTICS "
+                f"WHERE TABLE_SCHEMA = '{table.namespace}' "
+                f"AND TABLE_NAME = '{table.name}' "
+                "AND INDEX_NAME = 'PRIMARY' ORDER BY SEQ_IN_INDEX")
+        except MySQLError:
+            rows = []
+        named = [schema.find(r["name"]) for r in rows]
+        if named and all(c is not None and c.primary_key for c in named):
+            return named
+        return schema.key_columns()
+
+    # -- snapshot load -------------------------------------------------------
+    def load_table(self, table: TableDescription, pusher: Pusher) -> None:
+        """One part as ONE streamed result set, with neither ORDER BY nor
+        OFFSET: a table with a composite key or none reads as a table
+        with one key column does, and the server scans what it serves
+        once.  Rows leave as ColumnBatches of `batch_rows`; what is held
+        is one flush of the socket's bytes and the rows short of a
+        batch."""
+        with self._load_lock:
+            schema = self._load_schemas.get(table.id)
+            if schema is None:
+                schema = self.table_schema(table.id)
+                self._load_schemas[table.id] = schema
+        cols = ", ".join(f"`{c.name}`" for c in schema)
+        where = f" WHERE {table.filter}" if table.filter else ""
+        columnar = all(c.data_type in textrows.COLUMNAR_TYPES
+                       for c in schema)
+        per = self.params.batch_rows
+        trace.TELEMETRY.record_mysql_part()
+        # dedicated connection: parts stream in parallel threads
+        conn = _conn(self.params)
+        try:
+            blocks = conn.query_stream(
+                f"SELECT {cols} FROM `{table.id.namespace}`."
+                f"`{table.id.name}`{where}")
+            carry = None      # decoded rows short of a batch (arrow)
+            more = True
+            while more:
+                # one span a flush: the wait for and the read of row
+                # packets from the socket, their framing walked
+                flush: list[tuple[bytes, list[int]]] = []
+                rows = nbytes = 0
+                with trace.span("mysql_read") as sp:
+                    for data, starts in blocks:
+                        flush.append((data, starts))
+                        rows += len(starts)
+                        nbytes += len(data)
+                        if rows >= per or nbytes >= 32 << 20:
+                            break
+                    else:
+                        more = False
+                    if sp:
+                        sp.add(table=table.id.name, rows=rows,
+                               bytes=nbytes)
+                if not columnar:
+                    self._push_cells(flush, table.id, schema, pusher)
+                    continue
+                carry = self._flush_rows(flush, table.id, schema, pusher,
+                                         carry, last=not more)
         finally:
             conn.close()
+
+    def _push_cells(self, flush, tid: TableID, schema: TableSchema,
+                    pusher: Pusher) -> None:
+        """A flush cell by cell (`_coerce`), a batch a block: for what
+        `textrows.decode` does not take."""
+        for data, starts in flush:
+            self._push_rows(
+                textrows.rows_as_dicts(data, starts, schema.names()),
+                schema, tid, pusher)
+
+    def _flush_rows(self, flush, tid: TableID, schema: TableSchema,
+                    pusher: Pusher, carry, last: bool):
+        """Row packets -> arrow, a column at a time -> ColumnBatches of
+        `batch_rows`; returns the rows short of a batch (an arrow table)
+        for the next flush to start with, None after the last."""
+        import pyarrow as pa
+
+        batches = []
+        with trace.span("source_decode", format="mysql_text") as sp:
+            try:
+                new = [textrows.decode(data, starts, schema)
+                       for data, starts in flush]
+            except pa.ArrowInvalid:
+                # text arrow's parsers do not read as the column's type
+                # (a zero date): this flush goes cell by cell
+                if carry is not None and carry.num_rows:
+                    pusher(ColumnBatch.from_arrow(
+                        carry.combine_chunks().to_batches()[0], tid,
+                        schema))
+                self._push_cells(flush, tid, schema, pusher)
+                return None
+            tbl = carry
+            if new:
+                fresh = pa.Table.from_batches(new)
+                tbl = fresh if carry is None \
+                    else pa.concat_tables([carry, fresh])
+            n = tbl.num_rows if tbl is not None else 0
+            per = self.params.batch_rows
+            whole = n if last else n - n % per
+            for lo in range(0, whole, per):
+                rb = tbl.slice(lo, min(per, whole - lo)) \
+                    .combine_chunks().to_batches()[0]
+                batch = ColumnBatch.from_arrow(rb, tid, schema)
+                batch.read_bytes = rb.nbytes
+                batches.append(batch)
+            if sp:
+                sp.add(rows=whole,
+                       bytes=sum(len(data) for data, _ in flush))
+        for batch in batches:
+            pusher(batch)
+        return tbl.slice(whole) if whole < n else None
 
     @staticmethod
     def _push_rows(rows, schema, tid, pusher: Pusher) -> None:
@@ -518,7 +664,9 @@ class MySQLProvider(Provider):
 
     def storage(self):
         if isinstance(self.transfer.src, MySQLSourceParams):
-            return MySQLStorage(self.transfer.src)
+            return MySQLStorage(
+                self.transfer.src,
+                parts=self.transfer.runtime.sharding.process_count)
         return None
 
     def destination_storage(self):

@@ -11,7 +11,7 @@ from __future__ import annotations
 import hashlib
 import socket
 import struct
-from typing import Optional
+from typing import Iterator, Optional
 
 from transferia_tpu.abstract.errors import CategorizedError
 from transferia_tpu.utils.net import BufferedSock, recv_exact
@@ -257,6 +257,58 @@ class MySQLConnection:
                     )
                     pos += ln
             rows.append(dict(zip(columns, vals)))
+
+    def query_stream(self, sql: str, block_bytes: int = 4 << 20
+                     ) -> Iterator[tuple[bytes, list[int]]]:
+        """COM_QUERY whose text result set is never held whole: yields
+        (a block of the socket's bytes, the offset in it of every row
+        packet's payload) as the server sends them.  A block ends at a
+        packet's end; the rows are not parsed here (`textrows.decode`
+        reads a block's fields column by column).  A statement without a
+        result set yields nothing; an ERR packet, before or among the
+        rows, raises once it is read.  A row of 16 MiB or more (a packet
+        split in two) is not spoken here."""
+        self._seq = 0
+        self._send_packet(bytes([COM_QUERY]) + sql.encode())
+        first = self._read_packet()
+        if first[:1] == b"\xff":
+            raise self._err(first)
+        if first[:1] == b"\x00":
+            return
+        n_cols, _ = self._lenenc(first, 0)
+        for _ in range(n_cols + 1):     # the definitions and their EOF
+            self._read_packet()
+        tail = b""
+        while True:
+            chunk = self.sock.recv(block_bytes)
+            if not chunk:
+                raise MySQLError("connection closed by peer")
+            data = tail + chunk if tail else chunk
+            starts: list[int] = []
+            pos, n, last = 0, len(data), None
+            while pos + 4 <= n:
+                length = data[pos] | (data[pos + 1] << 8) \
+                    | (data[pos + 2] << 16)
+                end = pos + 4 + length
+                if end > n:
+                    break
+                head = data[pos + 4] if length else 0
+                if head >= 0xFE and (head == 0xFF or length < 9):
+                    last = data[pos + 4:end]
+                    pos = end
+                    break
+                if length == self._MAX_PACKET:
+                    raise MySQLError(
+                        "a row of 16 MiB or more in a streamed result set")
+                starts.append(pos + 4)
+                pos = end
+            if starts:
+                yield data[:pos], starts
+            if last is not None:
+                if last[:1] == b"\xff":
+                    raise self._err(last)
+                return
+            tail = data[pos:]
 
     @staticmethod
     def _parse_column_name(defn: bytes) -> str:

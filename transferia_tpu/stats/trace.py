@@ -807,6 +807,14 @@ class DeviceTelemetry:
             # in that call (providers/kafka/provider.py)
             self.kafka_handouts = 0
             self.kafka_handouts_buffered = 0
+            # parts the MySQL source streamed (one result set each), rows
+            # the Debezium emitter rendered and of those the ones its
+            # columnar path took, and batches the transformer chain
+            # passed on because no step of it applies to their table
+            self.mysql_parts = 0
+            self.debezium_rows = 0
+            self.debezium_rows_fast = 0
+            self.chain_batches_untouched = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -907,6 +915,20 @@ class DeviceTelemetry:
             self.kafka_handouts += 1
             self.kafka_handouts_buffered += buffered
 
+    def record_mysql_part(self) -> None:
+        with self._lock:
+            self.mysql_parts += 1
+
+    def record_debezium_rows(self, n_rows: int, fast: bool) -> None:
+        with self._lock:
+            self.debezium_rows += int(n_rows)
+            if fast:
+                self.debezium_rows_fast += int(n_rows)
+
+    def record_chain_untouched(self) -> None:
+        with self._lock:
+            self.chain_batches_untouched += 1
+
     def record_device_wait(self, seconds: float) -> None:
         _ledger().add(device_wait_seconds=seconds)
         with self._lock:
@@ -962,6 +984,10 @@ class DeviceTelemetry:
                 "parsequeue_pushes_ahead": self.parsequeue_pushes_ahead,
                 "kafka_handouts": self.kafka_handouts,
                 "kafka_handouts_buffered": self.kafka_handouts_buffered,
+                "mysql_parts": self.mysql_parts,
+                "debezium_rows": self.debezium_rows,
+                "debezium_rows_fast": self.debezium_rows_fast,
+                "chain_batches_untouched": self.chain_batches_untouched,
             }
 
     def fold_into(self, metrics) -> None:

@@ -171,6 +171,11 @@ class SnapshotLoader:
         # tables whose scan predicate has been computed (set-once; reads
         # and adds race benignly — worst case one repeat computation)
         self._pushdown_done: set = set()
+        # the parts' chains share what the first of them measured of the
+        # host strategy (transform/fused.py PlacementBook)
+        from transferia_tpu.transform.fused import PlacementBook
+
+        self._placement_book = PlacementBook()
         # fleet observability export stream (stats/fleetobs.py): under
         # a fleet worker this joins the worker's ambient stream; a bare
         # sharded loader exports under its own worker label.  Disabled
@@ -973,7 +978,8 @@ class SnapshotLoader:
 
         sink = make_async_sink(self.transfer, self.metrics,
                                snapshot_stage=True,
-                               post_transform_wrap=wrap)
+                               post_transform_wrap=wrap,
+                               placement_book=self._placement_book)
         # staged two-phase commit (abstract/commit.py): when both ends
         # are capable, this part's batches land invisibly in the sink's
         # staging area and publish only after the coordinator grants a

@@ -17,6 +17,7 @@ from transferia_tpu.abstract.interfaces import Batch, is_columnar
 from transferia_tpu.abstract.schema import TableID, TableSchema
 from transferia_tpu.columnar.batch import ColumnBatch
 from transferia_tpu.stats.registry import TransformStats
+from transferia_tpu.stats.trace import TELEMETRY
 from transferia_tpu.transform.base import TransformResult, Transformer
 from transferia_tpu.transform.registry import parse_transformers_config
 
@@ -27,10 +28,11 @@ class _Plan:
     __slots__ = ("steps", "out_schema", "out_table")
 
     def __init__(self, steps: list[Transformer], in_table: TableID,
-                 in_schema: TableSchema):
+                 in_schema: TableSchema, placement_book=None):
         from transferia_tpu.transform.fused import maybe_fuse_steps
 
-        self.steps = maybe_fuse_steps(steps, in_table, in_schema)
+        self.steps = maybe_fuse_steps(steps, in_table, in_schema,
+                                      placement_book)
         steps = self.steps
         table, schema = in_table, in_schema
         for t in steps:
@@ -51,10 +53,14 @@ class Transformation:
 
     def __init__(self, transformers: Sequence[Transformer],
                  error_behavior: str = "emit",
-                 stats: Optional[TransformStats] = None):
+                 stats: Optional[TransformStats] = None,
+                 placement_book=None):
         self.transformers = list(transformers)
         self.error_behavior = error_behavior
         self.stats = stats or TransformStats()
+        # transform/fused.py PlacementBook: what the activation's other
+        # chains measured (a snapshot hands every part's chain one)
+        self.placement_book = placement_book
         self._plans: dict[tuple[TableID, str], _Plan] = {}
         self._lock = threading.Lock()
 
@@ -69,7 +75,8 @@ class Transformation:
                         t for t in self.transformers
                         if t.suitable(table, schema)
                     ]
-                    plan = _Plan(steps, table, schema)
+                    plan = _Plan(steps, table, schema,
+                                 self.placement_book)
                     self._plans[key] = plan
                     self.stats.compiles.inc()
                     logger.info(
@@ -198,6 +205,9 @@ class Transformation:
 
         plan = self.plan_for(batch.table_id, batch.schema)
         if not plan.steps:
+            # no step of the chain is for this table: the batch goes on
+            # as it came, with no pack, device batch or placement decision
+            TELEMETRY.record_chain_untouched()
             return batch
         self.stats.rows_in.inc(batch.n_rows)
         _t0 = _time.monotonic()
@@ -224,7 +234,8 @@ class Transformation:
 
 
 def build_chain(config: Optional[dict],
-                stats: Optional[TransformStats] = None) -> Optional[Transformation]:
+                stats: Optional[TransformStats] = None,
+                placement_book=None) -> Optional[Transformation]:
     """Build a Transformation from transfer.transformation config dict."""
     if not config:
         return None
@@ -235,4 +246,5 @@ def build_chain(config: Optional[dict],
         transformers,
         error_behavior=config.get("error_behavior", "emit"),
         stats=stats,
+        placement_book=placement_book,
     )

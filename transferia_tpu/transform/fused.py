@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import logging
 import os
+import threading
 from typing import Optional, Sequence
 
 from transferia_tpu.abstract.schema import (
@@ -106,6 +107,44 @@ def set_placement(mode: Optional[str]) -> None:
     _placement = mode
 
 
+class _HostReading:
+    """One fused step's first host measurement of an activation."""
+
+    __slots__ = ("done", "ns_row", "rows")
+
+    def __init__(self):
+        self.done = threading.Event()
+        self.ns_row = -1.0      # stays -1 where the measuring batch failed
+        self.rows = 0.0
+
+
+class PlacementBook:
+    """What the small parts of one activation have measured of the host
+    strategy, by fused step.  A snapshot builds a chain a part and a
+    chain's first batch goes to the host to be measured, so a part of
+    one batch never asked the device.  A chain whose first batch is small
+    (DeviceFusedStep.SHARED_READING_MAX_ROWS: a source hands out whole
+    batches first, so it is most likely the part's only one) goes by the
+    book: the first such chain of the activation measures the host, every
+    other takes that reading for its own and spends its batch on the
+    device (link-gated as ever), waiting out the measuring batch where it
+    is still under way.  A chain that starts with a whole batch measures
+    for itself as it always did."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._readings: dict = {}
+
+    def reading(self, key) -> tuple[_HostReading, bool]:
+        """(the step's reading, whether the caller is the one to take it)"""
+        with self._lock:
+            r = self._readings.get(key)
+            if r is not None:
+                return r, False
+            r = self._readings[key] = _HostReading()
+            return r, True
+
+
 class DeviceFusedStep(Transformer):
     """A fused run of mask_field/filter_rows steps, one device launch."""
 
@@ -113,16 +152,22 @@ class DeviceFusedStep(Transformer):
 
     # auto placement: re-probe the losing strategy every this many batches
     REPROBE_EVERY = 256
+    # a first batch of up to this many rows (one chunk of the device
+    # pipeline on an accelerator, ops/fused.py) goes by the activation's
+    # PlacementBook
+    SHARED_READING_MAX_ROWS = 32768
 
     def __init__(self, members: Sequence[Transformer],
                  mask_entries: Sequence[tuple[str, bytes]],
                  pred_node, device_pred=None,
-                 decimal_scales: Optional[dict[str, int]] = None):
+                 decimal_scales: Optional[dict[str, int]] = None,
+                 shared: Optional[tuple[PlacementBook, tuple]] = None):
         """pred_node is the predicate as written (the host strategy
         compiles it); device_pred is the same predicate bound to the
         schema for the chip (predicate/exact.py bind_device: day numbers
         and scaled integers), and decimal_scales the scale of every
-        DECIMAL column it reads."""
+        DECIMAL column it reads; shared is the activation's
+        PlacementBook and this step's key in it."""
         from transferia_tpu.ops.fused import FusedMaskFilterProgram
 
         self.members = list(members)
@@ -159,6 +204,10 @@ class DeviceFusedStep(Transformer):
         self._dev_samples = 0
         self._choice_logged = False
         self._device_gated = False
+        # the activation's book: opened with the first batch (a chain
+        # that is planned and never applied claims nothing)
+        self._shared = shared
+        self._owed: Optional[_HostReading] = None
 
     def suitable(self, table: TableID, schema: TableSchema) -> bool:
         # constructed at plan time from already-suitable members
@@ -185,10 +234,35 @@ class DeviceFusedStep(Transformer):
             for m in self.members:
                 out = m.apply(out).transformed
             return TransformResult(out)
-        strategy = self._pick_strategy(batch.n_rows, batch)
-        if strategy == "host":
-            return self._apply_host(batch)
-        return self._apply_device(batch)
+        try:
+            strategy = self._pick_strategy(batch.n_rows, batch)
+            if strategy == "host":
+                return self._apply_host(batch)
+            return self._apply_device(batch)
+        finally:
+            # a measuring batch that failed leaves the reading empty:
+            # the chains waiting for it measure for themselves
+            self._publish_host_reading()
+
+    def _take_host_reading(self, book: PlacementBook, key) -> None:
+        """The activation's host reading for this step, as this chain's
+        own first; or the duty to take it."""
+        reading, mine = book.reading(key)
+        if mine:
+            self._owed = reading
+            return
+        reading.done.wait()
+        if reading.ns_row >= 0:
+            self._ns_row["host"] = reading.ns_row
+            self._ema_rows["host"] = reading.rows
+
+    def _publish_host_reading(self) -> None:
+        reading, self._owed = self._owed, None
+        if reading is not None:
+            if self._ns_row["host"] >= 0:
+                reading.ns_row = self._ns_row["host"]
+                reading.rows = self._ema_rows["host"]
+            reading.done.set()
 
     def _estimate_link_bytes(self, n_rows: int, batch=None
                              ) -> tuple[float, float]:
@@ -311,6 +385,11 @@ class DeviceFusedStep(Transformer):
             return mode, "pinned", -1.0
         # auto: measure each strategy once, keep the winner, re-probe the
         # loser every REPROBE_EVERY batches (links drift — see linkprobe)
+        if self._shared is not None:
+            # the chain's first batch: by the book if it is a small one
+            shared, self._shared = self._shared, None
+            if n_rows <= self.SHARED_READING_MAX_ROWS:
+                self._take_host_reading(*shared)
         host_ns, dev_ns = self._ns_row["host"], self._ns_row["device"]
         if host_ns < 0:
             return "host", "host_first", -1.0
@@ -595,7 +674,9 @@ def _mask_target_cols(step: MaskField, schema: TableSchema) -> list[str]:
 
 
 def maybe_fuse_steps(steps: Sequence[Transformer], in_table: TableID,
-                     in_schema: TableSchema) -> list[Transformer]:
+                     in_schema: TableSchema,
+                     placement_book: Optional[PlacementBook] = None
+                     ) -> list[Transformer]:
     """Replace device-able runs with DeviceFusedSteps (plan-time)."""
     if not device_fusion_enabled() or not steps:
         return list(steps)
@@ -662,8 +743,12 @@ def maybe_fuse_steps(steps: Sequence[Transformer], in_table: TableID,
             # a worker that came up on the CPU platform says so here,
             # before its first "device" step runs on XLA-CPU
             log_backend_once()
+            shared = None
+            if placement_book is not None:
+                shared = (placement_book,
+                          (in_table, in_schema.fingerprint(), i))
             fused = DeviceFusedStep(group, mask_entries, pred_node,
-                                    device_pred, decimal_scales)
+                                    device_pred, decimal_scales, shared)
             logger.info("fused %d transformer steps onto device: %s",
                         len(group), fused.describe())
             out.append(fused)
