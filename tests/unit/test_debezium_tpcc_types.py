@@ -126,10 +126,13 @@ def test_an_emitter_told_nothing_keeps_the_old_identity():
                                              "postgresql")
 
 
-@pytest.mark.parametrize("path", ["fast", "row"])
+@pytest.mark.parametrize("path", ["native", "row"])
 def test_serialize_span_and_counters_say_which_path(path):
+    # (a batch of columns is the native renderer's since PR 34, and counts
+    # as a columnar - "fast" - row too; the Python renderer's own span
+    # and counters: test_debezium_native_render.py)
     batch = batch_of("int", TYPES["int"][0])
-    items = batch if path == "fast" else batch.to_rows()
+    items = batch if path == "native" else batch.to_rows()
     before = trace.TELEMETRY.snapshot()
     trace.enable(True)
     trace.reset()
@@ -144,4 +147,4 @@ def test_serialize_span_and_counters_say_which_path(path):
         {"format": "debezium", "path": path, "rows": 3}]
     assert after["debezium_rows"] - before["debezium_rows"] == 3
     assert after["debezium_rows_fast"] - before["debezium_rows_fast"] \
-        == (3 if path == "fast" else 0)
+        == (3 if path == "native" else 0)

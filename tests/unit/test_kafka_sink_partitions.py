@@ -155,4 +155,4 @@ def test_produce_is_recorded_as_the_sinks_write(broker):
     assert push["direction"] == "kafka_produce" and push["records"] == 64
     assert push["partitions"] == 16
     assert push["bytes"] == spans["kafka_encode"]["bytes"]
-    assert spans["serialize"]["path"] == "fast"
+    assert spans["serialize"]["path"] == "native"

@@ -4,21 +4,45 @@ Produces (key_bytes, value_bytes) JSON pairs per row.  Deletes also emit the
 tombstone (key, None) message when configured, matching Debezium's default
 topic compaction contract.
 
-Insert-only columnar batches take a VECTORIZED path (the reference
-multithreads exactly this serialization —
-pkg/serializer/queue/debezium_multithreading.go; on a single core the
-speedup must be algorithmic instead): the schema block and every static
-byte of the envelope render once per (table, schema) into %s-templates,
-values render per COLUMN (numpy string casts for ints, C-speed maps for
-the rest), and rows assemble by template substitution.  Output bytes are
-identical to the per-row path (pinned by differential tests); anything
-outside the envelope — CDC kinds, packers, exotic source types — falls
-back to the per-row emitter below.
+A batch takes one of three paths, which the `serialize` span's `path` arg
+and the `debezium_rows*` counters name; all three give the same bytes
+(pinned by differential tests):
+
+"native"  An insert-only ColumnBatch in JSON mode (no schema-registry
+    packer) that carries no `lsns`, `commit_times` or `txn_ids` of its
+    own, so that one `source` block serves every row: a snapshot's every
+    batch.  The reference multithreads exactly this serialization
+    (pkg/serializer/queue/debezium_multithreading.go); under one GIL the
+    equivalent is to leave Python.  The schema block and every static byte
+    of the envelope render once per (table, schema, mode) into
+    %s-templates, which are cut at their slots into constant pieces;
+    native/hostops.cpp then walks the batch twice with the GIL released,
+    so part threads render side by side: once for every message's size,
+    then writing the values, and the keys, a slab of rows at a time
+    through one buffer of _SLAB_BYTES.  It reads the
+    columns' own buffers: integers (DATE, DATETIME and TIMESTAMP scaled
+    with numpy first) as digits, UTF8 and DECIMAL text quoted exactly as
+    json.dumps quotes under ensure_ascii.  A column it cannot read that
+    way - FLOAT/DOUBLE, BOOLEAN, STRING, a `pg` or _SLOW_MYSQL original
+    type, a lazy dictionary - comes to it as the fragments the Python
+    renderer makes, to be copied.  The pairs are `bytes` cut from the
+    buffer slab by slab.
+"fast"  The same batch rendered in Python, a str per cell and two `%` per
+    row: what runs under TRANSFERIA_TPU_NO_NATIVE=1, for a batch of
+    inserts with source metadata row by row (replication's, a few rows a
+    batch: under some 5 rows the native call's fixed cost is more than it
+    saves, and no benchmark cell sends them), and where the native walk
+    declines (a text cell that is not UTF-8, whose replacement characters
+    are Python's decoder's to choose; fragments past 2 GiB).
+"row"  Everything outside the envelope - CDC kinds other than insert,
+    packers, NaN or infinity in a float column, a row list - goes through
+    emit_item, a ChangeItem and a json.dumps per row.
 """
 
 from __future__ import annotations
 
 import base64
+import itertools
 import json
 import re
 import time
@@ -36,7 +60,61 @@ from transferia_tpu.debezium.types import (
     mysql_datetime_millis,
     to_connect,
 )
+from transferia_tpu import native
 from transferia_tpu.stats import trace
+
+# column kinds of native/hostops.cpp's debezium_render_*
+_I64, _U64, _TEXT, _RAW = 0, 1, 2, 3
+# The native renderer writes a batch through one buffer of this size, a
+# slab of rows at a time, and the pairs are cut from it slab by slab.  A
+# batch's 100-225 MB as one buffer are written to memory, read back for
+# the cut and handed back to the kernel; 1 MiB stays in a core's cache and
+# is taken once.  Four threads of 30,000 CUSTOMER rows each on the chip's
+# host: 14.0 s with one buffer a batch, 8.1 s with 4 MiB, 6.3 s with 1 MiB
+# or 256 KiB (PERF.md section 6, PR 34).
+_SLAB_BYTES = 1 << 20
+
+
+def _digit_column(data: np.ndarray, validity) -> Optional[tuple]:
+    """Integers as the native renderer's column; None for a dtype whose
+    digits numpy's string cast must spell."""
+    if data.dtype.kind not in "iu":
+        return None
+    if data.dtype == np.uint64:
+        return _U64, data, None, validity
+    return _I64, data.astype(np.int64, copy=False), None, validity
+
+
+def _packed_fragments(frags: list) -> Optional[tuple]:
+    """Rendered cells (ASCII, as json.dumps, repr and base64 give them)
+    end to end with int32 offsets, as the native renderer's column; None
+    where they do not fit."""
+    data = "".join(frags).encode()
+    lens = np.fromiter(map(len, frags), dtype=np.int64, count=len(frags))
+    if len(data) != lens.sum() or len(data) >= 2 ** 31:
+        return None
+    offsets = np.zeros(len(frags) + 1, dtype=np.int32)
+    np.cumsum(lens, out=offsets[1:])
+    return _RAW, np.frombuffer(data, dtype=np.uint8), offsets, None
+
+
+def _cut(fmt: str) -> list[str]:
+    """A %-template's constant pieces, cut at its %s slots ("%%" is a
+    literal "%"): len(slots) + 1 of them."""
+    tokens = re.split("%(.)", fmt, flags=re.DOTALL)
+    pieces = [tokens[0]]
+    for directive, text in zip(tokens[1::2], tokens[2::2]):
+        if directive == "s":
+            pieces.append(text)
+        else:
+            pieces[-1] += "%" + text
+    return pieces
+
+
+def _splice(head: list, tail: list) -> list:
+    """Two runs of pieces as one: the last of one joins the first of the
+    other, the slots between the rest stay."""
+    return head[:-1] + [head[-1] + tail[0]] + tail[1:]
 
 
 def _field_schema(cs) -> dict:
@@ -97,7 +175,8 @@ class DebeziumEmitter:
         # equivalent here; it is computed once and cached on the schema)
         self._value_schema_cache: dict = {}
         self._key_schema_cache: dict = {}
-        # rendered %s-templates for the vectorized columnar path
+        # rendered %s-templates for the columnar paths, and the same cut
+        # into pieces for the native renderer
         self._fast_tmpl_cache: dict = {}
         if packer == "schema_registry":
             from transferia_tpu.debezium.packer import SchemaRegistryPacker
@@ -281,20 +360,21 @@ class DebeziumEmitter:
                    ) -> list[tuple[Optional[bytes], Optional[bytes]]]:
         """ColumnBatch or row list -> envelope pairs, order-preserving."""
         with trace.span("serialize", format="debezium") as sp:
-            out, rows, fast = self._emit_batch(batch, snapshot)
+            out, rows, path = self._emit_batch(batch, snapshot)
             if sp:
-                sp.add(path="fast" if fast else "row", rows=rows)
+                sp.add(path=path, rows=rows)
         if rows:
-            trace.TELEMETRY.record_debezium_rows(rows, fast)
+            trace.TELEMETRY.record_debezium_rows(rows, path)
         return out
 
-    def _emit_batch(self, batch, snapshot: bool) -> tuple[list, int, bool]:
-        """(pairs, rows rendered, whether the columnar path took them)."""
+    def _emit_batch(self, batch, snapshot: bool) -> tuple[list, int, str]:
+        """(pairs, rows rendered, the path that took them: "native",
+        "fast" or "row")."""
         items: Iterable[ChangeItem]
         if isinstance(batch, ColumnBatch):
-            fast = self._emit_columnar_fast(batch, snapshot)
-            if fast is not None:
-                return fast, batch.n_rows, True
+            taken = self._emit_columnar(batch, snapshot)
+            if taken is not None:
+                return taken[0], batch.n_rows, taken[1]
             items = batch.to_rows()
         else:
             items = batch
@@ -304,9 +384,9 @@ class DebeziumEmitter:
             if it.is_row_event():
                 rows += 1
                 out.extend(self.emit_item(it, snapshot))
-        return out, rows, False
+        return out, rows, "row"
 
-    # -- vectorized insert-only columnar path --------------------------------
+    # -- insert-only columnar batches: the native and the Python renderer ----
 
     # original_type (provider, base) combinations encode_value special-
     # cases; columns carrying them take the per-value path
@@ -314,49 +394,57 @@ class DebeziumEmitter:
     # chars safe to embed in a JSON string unescaped under ensure_ascii:
     # printable ASCII minus '"' and '\'
     _JSON_SAFE = re.compile(r'[^ !#-\[\]-~]')
+    # canonical types whose cell is the digits of an integer
+    _DIGITS = (CanonicalType.INT8, CanonicalType.INT16,
+               CanonicalType.INT32, CanonicalType.INT64,
+               CanonicalType.UINT8, CanonicalType.UINT16,
+               CanonicalType.UINT32, CanonicalType.UINT64,
+               CanonicalType.DATE, CanonicalType.DATETIME,
+               CanonicalType.TIMESTAMP)
+
+    @classmethod
+    def _slow_original(cls, orig: str) -> bool:
+        if not orig:
+            return False
+        provider, base, _args = _split_original(orig)
+        # pg arrays/money/ranges/bits: keep exact
+        return provider == "pg" or (
+            provider == "mysql" and base in cls._SLOW_MYSQL)
+
+    @staticmethod
+    def _digit_values(col, ct, orig: str) -> Optional[np.ndarray]:
+        """The integers whose digits are the cells of a _DIGITS column,
+        scaled to what Debezium's semantic type counts."""
+        data = col.data
+        if data is None:
+            return None
+        if ct == CanonicalType.DATETIME:
+            if data.dtype.kind == "M":
+                data = data.astype("datetime64[s]").astype(np.int64)
+            # seconds -> ms (io.debezium.time.Timestamp)
+            return data.astype(np.int64) * 1000
+        if ct == CanonicalType.TIMESTAMP:
+            if data.dtype.kind == "M":
+                data = data.astype("datetime64[us]").astype(np.int64)
+            if mysql_datetime_millis(orig):
+                data = data.astype(np.int64) // 1000
+            return data
+        if ct == CanonicalType.DATE and data.dtype.kind == "M":
+            data = data.astype("datetime64[D]").astype(np.int64)
+        return data
 
     def _col_fragments(self, col, cs) -> Optional[list]:
         """Per-row JSON value fragments for one column, byte-identical to
         json.dumps(encode_value(...)); None = out of the fast envelope."""
         orig = cs.original_type or ""
-        slow_orig = False
-        if orig:
-            provider, base, _args = _split_original(orig)
-            if provider == "pg":
-                slow_orig = True  # arrays/money/ranges/bits: keep exact
-            elif provider == "mysql" and base in self._SLOW_MYSQL:
-                slow_orig = True
+        slow_orig = self._slow_original(orig)
         ct = cs.data_type
         frags: Optional[list] = None
         if not slow_orig:
-            if ct in (CanonicalType.INT8, CanonicalType.INT16,
-                      CanonicalType.INT32, CanonicalType.INT64,
-                      CanonicalType.UINT8, CanonicalType.UINT16,
-                      CanonicalType.UINT32, CanonicalType.UINT64,
-                      CanonicalType.DATE):
-                data = col.data
+            if ct in self._DIGITS:
+                data = self._digit_values(col, ct, orig)
                 if data is None:
                     return None
-                if ct == CanonicalType.DATE and \
-                        data.dtype.kind == "M":
-                    data = data.astype("datetime64[D]").astype(np.int64)
-                frags = data.astype("U").tolist()
-            elif ct == CanonicalType.DATETIME:
-                data = col.data
-                if data is None:
-                    return None
-                if data.dtype.kind == "M":
-                    data = data.astype("datetime64[s]").astype(np.int64)
-                # seconds -> ms (io.debezium.time.Timestamp)
-                frags = (data.astype(np.int64) * 1000).astype("U").tolist()
-            elif ct == CanonicalType.TIMESTAMP:
-                data = col.data
-                if data is None:
-                    return None
-                if data.dtype.kind == "M":
-                    data = data.astype("datetime64[us]").astype(np.int64)
-                if mysql_datetime_millis(orig):
-                    data = data.astype(np.int64) // 1000
                 frags = data.astype("U").tolist()
             elif ct in (CanonicalType.FLOAT, CanonicalType.DOUBLE):
                 data = col.data
@@ -404,8 +492,15 @@ class DebeziumEmitter:
 
     def _emit_columnar_fast(self, batch: ColumnBatch, snapshot: bool
                             ) -> Optional[list]:
-        """Insert-only JSON-mode batches render by template; None defers
-        to the per-row path."""
+        """The pairs of an insert-only JSON-mode batch from its columns;
+        None defers to the per-row path."""
+        taken = self._emit_columnar(batch, snapshot)
+        return None if taken is None else taken[0]
+
+    def _emit_columnar(self, batch: ColumnBatch, snapshot: bool
+                       ) -> Optional[tuple[list, str]]:
+        """(pairs, "native" or "fast") for a batch inside the envelope
+        (the module docstring says which batch takes which path)."""
         if self.value_packer is not None:
             return None
         schema = batch.schema
@@ -421,14 +516,172 @@ class DebeziumEmitter:
         if set(n for n in names) - set(batch.columns.keys()):
             return None
 
+        cdll = native.lib()
+        if batch.commit_times is not None or batch.lsns is not None \
+                or getattr(batch, "txn_ids", None) is not None:
+            # a source block row by row is replication's: batches of a
+            # few inserts, which the Python renderer takes quicker
+            cdll = None
+        # a column the native renderer cannot read from the batch's own
+        # buffers comes to it as the Python renderer's fragments, which
+        # are made once for both
+        cols = []
         frag_by_name = {}
         for cs in schema:
-            frags = self._col_fragments(batch.columns[cs.name], cs)
-            if frags is None:
-                return None
-            frag_by_name[cs.name] = frags
+            col = batch.columns[cs.name]
+            spec = None if cdll is None else self._native_column(col, cs)
+            if spec is None:
+                frags = self._col_fragments(col, cs)
+                if frags is None:
+                    return None
+                frag_by_name[cs.name] = frags
+                spec = None if cdll is None else _packed_fragments(frags)
+            cols.append(spec)
+        if cdll is not None and all(spec is not None for spec in cols):
+            pairs = self._render_native(cdll, batch, schema, names,
+                                        key_cols, cols, snapshot)
+            if pairs is not None:
+                return pairs, "native"
+        for cs in schema:
+            if cs.name not in frag_by_name:
+                frags = self._col_fragments(batch.columns[cs.name], cs)
+                if frags is None:
+                    return None
+                frag_by_name[cs.name] = frags
         return self._render_fast(batch, schema, names, key_cols,
-                                 frag_by_name, snapshot)
+                                 frag_by_name, snapshot), "fast"
+
+    def _native_column(self, col, cs) -> Optional[tuple]:
+        """(kind, values, offsets, validity) of a column the native
+        renderer reads from the batch's own buffers - integers to write
+        as digits, UTF8 or DECIMAL text to quote - by the column's type,
+        original type and dtype alone; None: _col_fragments renders it."""
+        orig = cs.original_type or ""
+        if col.is_lazy_dict or self._slow_original(orig):
+            return None
+        ct = cs.data_type
+        if ct in self._DIGITS:
+            data = self._digit_values(col, ct, orig)
+            return None if data is None else _digit_column(
+                data, col.validity)
+        if ct in (CanonicalType.UTF8, CanonicalType.DECIMAL) \
+                and col.offsets is not None:
+            return _TEXT, col.data, col.offsets, col.validity
+        return None
+
+    def _render_native(self, cdll, batch: ColumnBatch, schema, names,
+                       key_cols, cols: list, snapshot: bool
+                       ) -> Optional[list]:
+        """The pairs of a batch with no source metadata of its own, cut
+        from the buffer that native/hostops.cpp fills a slab at a time
+        with the GIL released; None where it takes no part (a text cell
+        that is not UTF-8): the Python renderer decides."""
+        n = batch.n_rows
+        now_ms = int(time.time() * 1000)
+        after_p, key_p, (head, mid, tail), src_p = self._templates(
+            batch, schema, names, key_cols, snapshot)[-1]
+        value_slots = list(range(len(names)))
+        # one source block for every row: ts_ms now, no lsn, no txId
+        src_p = [src_p[0] + str(now_ms) + src_p[1] + "null"
+                 + src_p[2] + "null" + src_p[3]]
+        value_p = _splice(_splice([head], after_p), _splice([mid], src_p))
+        value_p[-1] += tail.replace("\x00TS\x00", str(now_ms))
+
+        kinds = np.zeros(len(cols), dtype=np.int32)
+        data = np.zeros(len(cols), dtype=np.uint64)
+        offsets = np.zeros(len(cols), dtype=np.uint64)
+        validity = np.zeros(len(cols), dtype=np.uint64)
+        held = []  # the buffers whose addresses the renderer reads
+        for c, (kind, values, off, valid) in enumerate(cols):
+            kinds[c] = kind
+            if off is None:
+                values = np.ascontiguousarray(values)
+                if values.shape != (n,):
+                    return None
+            else:
+                values = np.ascontiguousarray(values, dtype=np.uint8)
+                off = np.ascontiguousarray(off, dtype=np.int32)
+                if off.shape != (n + 1,) or off[0] < 0 \
+                        or off[-1] > len(values):
+                    raise ValueError(
+                        f"column {c} of {batch.table_id}: offsets do not "
+                        f"fit {n} rows over {len(values)} bytes")
+                offsets[c] = off.ctypes.data
+                held.append(off)
+            data[c] = values.ctypes.data
+            held.append(values)
+            if valid is not None:
+                valid = np.ascontiguousarray(valid, dtype=np.bool_)
+                if valid.shape != (n,):
+                    return None
+                validity[c] = valid.ctypes.data
+                held.append(valid)
+
+        def render(pieces: list, slots: list) -> Optional[list]:
+            raw = [p.encode() for p in pieces]
+            piece_off = np.zeros(len(raw) + 1, dtype=np.int64)
+            np.cumsum([len(p) for p in raw], out=piece_off[1:])
+            joined = b"".join(raw)
+            slot_cols = np.asarray(slots, dtype=np.int32)
+            row_off = np.empty(n + 1, dtype=np.int64)
+            total = cdll.debezium_render_size(
+                n, kinds, data, offsets, validity, len(slots), slot_cols,
+                piece_off, row_off)
+            if total == -2:
+                return None
+            if total < 0:
+                raise ValueError(
+                    f"{batch.table_id}: a column's offsets decrease")
+            # a slab of whole rows at a time through one buffer (the
+            # longest row fits; one byte over, so that no cut is the
+            # whole buffer, which a slice would hand out uncopied)
+            room = max(_SLAB_BYTES, int(np.diff(row_off).max()))
+            slab = native.new_bytes(None, min(total, room) + 1)
+            out: list = []
+            lo = 0
+            while lo < n:
+                hi = int(np.searchsorted(row_off, row_off[lo] + room,
+                                         side="right")) - 1
+                written = cdll.debezium_render_write(
+                    lo, hi, kinds, data, offsets, validity, len(slots),
+                    slot_cols, joined, piece_off, slab)
+                cuts = (row_off[lo:hi + 1] - row_off[lo]).tolist()
+                if written != cuts[-1]:
+                    raise RuntimeError(
+                        f"debezium renderer wrote {written} of "
+                        f"{cuts[-1]} bytes")
+                out.extend(map(slab.__getitem__,
+                               map(slice, cuts, cuts[1:])))
+                lo = hi
+            return out
+
+        values = render(value_p, value_slots)
+        if values is None:
+            return None
+        if not key_cols:
+            # no primary key: a null message key (emit_item's rule)
+            return list(zip(itertools.repeat(None), values))
+        keys = render(key_p, [names.index(c.name) for c in key_cols])
+        return None if keys is None else list(zip(keys, values))
+
+    def _templates(self, batch: ColumnBatch, schema, names, key_cols,
+                   snapshot: bool) -> tuple:
+        """ALL static bytes (incl. the full schema blocks) render once
+        per (table, schema, mode) and cache - re-dumping a multi-KB
+        schema json per small CDC batch would dwarf the row rendering
+        the columnar paths accelerate.  _build_templates' four
+        %s-templates, then the same four cut at their slots into the
+        constant pieces the native renderer copies."""
+        tid = batch.table_id
+        cache_key = (tid.namespace, tid.name, schema.fingerprint(),
+                     snapshot)
+        tmpl = self._fast_tmpl_cache.get(cache_key)
+        if tmpl is None:
+            fmts = self._build_templates(schema, names, key_cols,
+                                         tid.namespace, tid.name, snapshot)
+            tmpl = fmts + (tuple(_cut(f) for f in fmts),)
+            self._fast_tmpl_cache[cache_key] = tmpl
+        return tmpl
 
     def _build_templates(self, schema, names, key_cols, item_schema,
                          item_table, snapshot) -> tuple:
@@ -478,24 +731,11 @@ class DebeziumEmitter:
     def _render_fast(self, batch: ColumnBatch, schema, names, key_cols,
                      frag_by_name: dict, snapshot: bool) -> list:
 
-        tid = batch.table_id
-        item_schema, item_table = tid.namespace, tid.name
         now_ms = int(time.time() * 1000)
-
-        # -- templates: ALL static bytes (incl. the full schema blocks)
-        # render once per (table, schema, mode) and cache — re-dumping a
-        # multi-KB schema json per small CDC batch would dwarf the row
-        # rendering this path accelerates.  \x00TS\x00 marks the
-        # envelope timestamp slot (a NUL can never appear in json text)
-        cache_key = (item_schema, item_table, schema.fingerprint(),
-                     snapshot)
-        tmpl = self._fast_tmpl_cache.get(cache_key)
-        if tmpl is None:
-            tmpl = self._build_templates(schema, names, key_cols,
-                                         item_schema, item_table,
-                                         snapshot)
-            self._fast_tmpl_cache[cache_key] = tmpl
-        after_fmt, key_fmt_t, value_fmt_t, src_fmt = tmpl
+        # \x00TS\x00 marks the envelope timestamp slot (a NUL can never
+        # appear in json text)
+        after_fmt, key_fmt_t, value_fmt_t, src_fmt = self._templates(
+            batch, schema, names, key_cols, snapshot)[:4]
         key_fmt = key_fmt_t
         value_fmt = value_fmt_t.replace("\x00TS\x00", str(now_ms))
         n = batch.n_rows

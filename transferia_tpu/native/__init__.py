@@ -36,6 +36,15 @@ class NativeBuildError(RuntimeError):
     """The host-ops library could not be built or loaded."""
 
 
+# An uninitialised bytes object for a native writer to fill: the result
+# itself (a numpy buffer and tobytes() would copy it once more, holding the
+# GIL).  A prototype of our own: ctypes.pythonapi's attribute is the
+# process's.
+new_bytes = ctypes.PYFUNCTYPE(
+    ctypes.py_object, ctypes.c_void_p, ctypes.c_ssize_t,
+)(("PyBytes_FromStringAndSize", ctypes.pythonapi))
+
+
 def so_path() -> pathlib.Path:
     """Where the library for the sources on disk lives (may not exist
     yet): the name carries a hash of every source file's bytes."""
@@ -65,6 +74,18 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_char_p,  # the bytes object the rows are written into
     ]
     cdll.rowbinary_write.restype = ctypes.c_int64
+    # Debezium envelope renderer: per-column kinds and buffer addresses,
+    # then a message's slots and the offsets of its constant pieces
+    cdll.debezium_render_size.argtypes = [
+        ctypes.c_int64, i32, u64, u64, u64, ctypes.c_int32, i32, i64, i64,
+    ]
+    cdll.debezium_render_size.restype = ctypes.c_int64
+    cdll.debezium_render_write.argtypes = [
+        ctypes.c_int64, ctypes.c_int64, i32, u64, u64, u64, ctypes.c_int32,
+        i32, ctypes.c_char_p, i64,
+        ctypes.c_char_p,  # the bytes object the messages are written into
+    ]
+    cdll.debezium_render_write.restype = ctypes.c_int64
     cdll.gather_varwidth.argtypes = [u8, i32, i64, ctypes.c_int64, u8, i32]
     cdll.gather_varwidth.restype = ctypes.c_int64
     cdll.gather_var_offsets.argtypes = [i32, i64, ctypes.c_int64, i32]

@@ -5,6 +5,7 @@
 // library covers the residual host loops that numpy can't fully vectorize
 // without large temporaries:
 //   - the RowBinary writer (columnar -> row-major, the ClickHouse sink)
+//   - the Debezium envelope renderer (columnar -> JSON keys and values)
 //   - var-width gather (Column.take without index temporaries)
 //
 // Build: transferia_tpu/native/build.py (g++ -O3 -shared -fPIC).  All
@@ -962,6 +963,300 @@ int64_t pg_copy_unframe(const uint8_t* buf, int64_t n, uint8_t* out,
     counts[0] = w;
     counts[1] = msgs;
     return pos;
+}
+
+// ---------------------------------------------------------------------------
+// Debezium envelope renderer (debezium/emitter.py): the JSON keys or values
+// of an insert-only batch, sized in one call and written a run of rows a
+// call from the columns' own buffers.  A message is constant pieces with
+// one cell between each two:
+//   piece[0] cell(slot 0) piece[1] ... cell(slot n_slots-1) piece[n_slots]
+// pieces are laid end to end, piece s being bytes piece_off[s] to
+// piece_off[s+1] (the schema block, `"name":` between columns, the source
+// block, the tail), and slot_cols[s] names the column whose cell fills slot
+// s.  Column c is the c-th entry of four parallel arrays:
+//   kinds[c]     0 int64 values, written as decimal digits
+//                1 uint64 values, likewise
+//                2 UTF-8 text (bytes + int32 offsets), quoted as json.dumps
+//                  quotes a str under ensure_ascii
+//                3 JSON fragments rendered by the caller (bytes + int32
+//                  offsets), copied as they are
+//   data[c]      address of the values or of the byte buffer
+//   offsets[c]   address of the (n_rows + 1) int32 offsets; 0 for kinds 0, 1
+//   validity[c]  address of n_rows bytes, nonzero = present; 0 = all present
+// A null cell is `null`.  No state outlives a call: part threads render
+// their batches side by side.
+
+enum { DBZ_I64 = 0, DBZ_U64 = 1, DBZ_TEXT = 2, DBZ_RAW = 3 };
+
+// What a byte under 0x80 takes inside a JSON string under ensure_ascii: 1
+// as it is, 2 for \" \\ \b \t \n \f \r, 6 for \u00xx (the other bytes under
+// 0x20, and 0x7f).  0: part of a multi-byte sequence.
+static struct DbzEsc {
+    uint8_t len[256];
+    DbzEsc() {  // filled when the library is loaded
+        for (int b = 0; b < 256; b++)
+            len[b] = b >= 0x80 ? 0 : (b < 0x20 || b == 0x7f) ? 6 : 1;
+        len['"'] = len['\\'] = 2;
+        len['\b'] = len['\t'] = len['\n'] = len['\f'] = len['\r'] = 2;
+    }
+} const dbz_esc_table;
+static const uint8_t* const dbz_esc = dbz_esc_table.len;
+
+// The first byte from p on that cannot be copied as it is; eight at a time
+// while none of them is under 0x20, over 0x7e, '"' or '\'.
+static inline const uint8_t* dbz_skip_plain(const uint8_t* p,
+                                            const uint8_t* end) {
+    const uint64_t ones = 0x0101010101010101ULL;
+    const uint64_t high = 0x8080808080808080ULL;
+    while (end - p >= 8) {
+        uint64_t w;
+        memcpy(&w, p, 8);
+        uint64_t q = w ^ (ones * '"'), s = w ^ (ones * '\\');
+        uint64_t d = w ^ (ones * 0x7f);
+        uint64_t odd = w | ((w - ones * 0x20) & ~w) | ((q - ones) & ~q)
+                     | ((s - ones) & ~s) | ((d - ones) & ~d);
+        if (odd & high) break;
+        p += 8;
+    }
+    while (p < end && dbz_esc[*p] == 1) p++;
+    return p;
+}
+
+// Width of the well-formed UTF-8 sequence at p (2 to 4) and its code point;
+// 0 for what Python's strict decoder refuses: a stray continuation byte, an
+// overlong form, a surrogate, a value over U+10FFFF, a sequence cut short.
+static inline int dbz_utf8(const uint8_t* p, const uint8_t* end,
+                           uint32_t* cp) {
+    const uint8_t b0 = p[0];
+    if (b0 < 0xc2 || b0 > 0xf4) return 0;
+    const int w = b0 < 0xe0 ? 2 : b0 < 0xf0 ? 3 : 4;
+    if (end - p < w) return 0;
+    const uint8_t b1 = p[1];
+    uint8_t lo = 0x80, hi = 0xbf;
+    if (b0 == 0xe0) lo = 0xa0;
+    else if (b0 == 0xed) hi = 0x9f;
+    else if (b0 == 0xf0) lo = 0x90;
+    else if (b0 == 0xf4) hi = 0x8f;
+    if (b1 < lo || b1 > hi) return 0;
+    if (w == 2) {
+        *cp = ((uint32_t)(b0 & 0x1f) << 6) | (b1 & 0x3f);
+        return 2;
+    }
+    if ((p[2] & 0xc0) != 0x80) return 0;
+    if (w == 3) {
+        *cp = ((uint32_t)(b0 & 0x0f) << 12) | ((uint32_t)(b1 & 0x3f) << 6)
+            | (p[2] & 0x3f);
+        return 3;
+    }
+    if ((p[3] & 0xc0) != 0x80) return 0;
+    *cp = ((uint32_t)(b0 & 0x07) << 18) | ((uint32_t)(b1 & 0x3f) << 12)
+        | ((uint32_t)(p[2] & 0x3f) << 6) | (p[3] & 0x3f);
+    return 4;
+}
+
+// Bytes of the quoted text, the quotes included; -1: not UTF-8.
+static inline int64_t dbz_text_len(const uint8_t* p, const uint8_t* end) {
+    int64_t len = 2;
+    while (p < end) {
+        const uint8_t* q = dbz_skip_plain(p, end);
+        len += q - p;
+        if (q == end) break;
+        p = q;
+        if (*p < 0x80) {
+            len += dbz_esc[*p++];
+            continue;
+        }
+        uint32_t cp;
+        const int w = dbz_utf8(p, end, &cp);
+        if (!w) return -1;
+        len += w == 4 ? 12 : 6;  // over U+FFFF: a surrogate pair
+        p += w;
+    }
+    return len;
+}
+
+static inline uint8_t* dbz_put_u(uint8_t* out, uint32_t unit) {
+    out[0] = '\\';
+    out[1] = 'u';
+    out[2] = HEXD[(unit >> 12) & 15];
+    out[3] = HEXD[(unit >> 8) & 15];
+    out[4] = HEXD[(unit >> 4) & 15];
+    out[5] = HEXD[unit & 15];
+    return out + 6;
+}
+
+// Quote text that dbz_text_len has taken; returns the end of what it wrote.
+static inline uint8_t* dbz_put_text(uint8_t* out, const uint8_t* p,
+                                    const uint8_t* end) {
+    *out++ = '"';
+    while (p < end) {
+        const uint8_t* q = dbz_skip_plain(p, end);
+        memcpy(out, p, (size_t)(q - p));
+        out += q - p;
+        if (q == end) break;
+        p = q;
+        const uint8_t b = *p;
+        if (b < 0x80) {
+            p++;
+            if (dbz_esc[b] == 6) {
+                out = dbz_put_u(out, b);
+                continue;
+            }
+            *out++ = '\\';
+            switch (b) {
+                case '\b': *out++ = 'b'; break;
+                case '\t': *out++ = 't'; break;
+                case '\n': *out++ = 'n'; break;
+                case '\f': *out++ = 'f'; break;
+                case '\r': *out++ = 'r'; break;
+                default: *out++ = b;  // '"' and '\'
+            }
+            continue;
+        }
+        uint32_t cp = 0;
+        p += dbz_utf8(p, end, &cp);
+        if (cp >= 0x10000) {
+            cp -= 0x10000;
+            out = dbz_put_u(out, 0xd800 | (cp >> 10));
+            cp = 0xdc00 | (cp & 0x3ff);
+        }
+        out = dbz_put_u(out, cp);
+    }
+    *out++ = '"';
+    return out;
+}
+
+static inline int dbz_digits(uint64_t v) {
+    int n = 1;
+    for (;;) {
+        if (v < 10) return n;
+        if (v < 100) return n + 1;
+        if (v < 1000) return n + 2;
+        if (v < 10000) return n + 3;
+        v /= 10000;
+        n += 4;
+    }
+}
+
+static inline uint8_t* dbz_put_uint(uint8_t* out, uint64_t v) {
+    uint8_t tmp[20];
+    int i = 20;
+    do {
+        tmp[--i] = (uint8_t)('0' + v % 10);
+        v /= 10;
+    } while (v);
+    memcpy(out, tmp + i, (size_t)(20 - i));
+    return out + (20 - i);
+}
+
+// Sizes of the batch's messages: row_off[r] .. row_off[r+1] is where
+// message r goes (n_rows + 1 entries, row_off[0] = 0).  Returns the total,
+// -1 when a column's offsets decrease, -2 when a text cell is not UTF-8
+// (the caller's own renderer decides what such bytes read as).  Walks a
+// column at a time: each buffer is read once, front to back.
+int64_t debezium_render_size(int64_t n_rows, const int32_t* kinds,
+                             const uint64_t* data, const uint64_t* offsets,
+                             const uint64_t* validity, int32_t n_slots,
+                             const int32_t* slot_cols,
+                             const int64_t* piece_off, int64_t* row_off) {
+    const int64_t fixed = piece_off[n_slots + 1] - piece_off[0];
+    int64_t* lens = row_off + 1;
+    for (int64_t r = 0; r < n_rows; r++) lens[r] = fixed;
+    for (int32_t s = 0; s < n_slots; s++) {
+        const int32_t c = slot_cols[s];
+        const uint8_t* valid = (const uint8_t*)validity[c];
+        if (kinds[c] == DBZ_I64 || kinds[c] == DBZ_U64) {
+            const int64_t* v = (const int64_t*)data[c];
+            const bool is_signed = kinds[c] == DBZ_I64;
+            for (int64_t r = 0; r < n_rows; r++) {
+                if (valid && !valid[r])
+                    lens[r] += 4;
+                else if (is_signed && v[r] < 0)
+                    lens[r] += 1 + dbz_digits(0 - (uint64_t)v[r]);
+                else
+                    lens[r] += dbz_digits((uint64_t)v[r]);
+            }
+            continue;
+        }
+        const uint8_t* bytes = (const uint8_t*)data[c];
+        const int32_t* off = (const int32_t*)offsets[c];
+        const bool text = kinds[c] == DBZ_TEXT;
+        for (int64_t r = 0; r < n_rows; r++) {
+            int64_t len = (int64_t)off[r + 1] - off[r];
+            if (len < 0) return -1;
+            if (valid && !valid[r]) {
+                lens[r] += 4;
+                continue;
+            }
+            if (text) {
+                len = dbz_text_len(bytes + off[r], bytes + off[r + 1]);
+                if (len < 0) return -2;
+            }
+            lens[r] += len;
+        }
+    }
+    row_off[0] = 0;
+    for (int64_t r = 0; r < n_rows; r++) row_off[r + 1] += row_off[r];
+    return row_off[n_rows];
+}
+
+// Write the messages of rows row_lo to row_hi - 1 end to end into out
+// (row_off[row_hi] - row_off[row_lo] bytes, from the arguments
+// debezium_render_size had); returns the bytes written.  The caller takes
+// a batch a slab at a time through one small buffer, which stays in the
+// cache between the write and the cut.
+int64_t debezium_render_write(int64_t row_lo, int64_t row_hi,
+                              const int32_t* kinds,
+                              const uint64_t* data, const uint64_t* offsets,
+                              const uint64_t* validity, int32_t n_slots,
+                              const int32_t* slot_cols,
+                              const uint8_t* pieces,
+                              const int64_t* piece_off, uint8_t* out) {
+    uint8_t* p = out;
+    for (int64_t r = row_lo; r < row_hi; r++) {
+        for (int32_t s = 0; s <= n_slots; s++) {
+            const int64_t plen = piece_off[s + 1] - piece_off[s];
+            memcpy(p, pieces + piece_off[s], (size_t)plen);
+            p += plen;
+            if (s == n_slots) break;
+            const int32_t c = slot_cols[s];
+            const uint8_t* valid = (const uint8_t*)validity[c];
+            if (valid && !valid[r]) {
+                memcpy(p, "null", 4);
+                p += 4;
+                continue;
+            }
+            switch (kinds[c]) {
+                case DBZ_I64: {
+                    const int64_t v = ((const int64_t*)data[c])[r];
+                    if (v < 0) {
+                        *p++ = '-';
+                        p = dbz_put_uint(p, 0 - (uint64_t)v);
+                    } else {
+                        p = dbz_put_uint(p, (uint64_t)v);
+                    }
+                    break;
+                }
+                case DBZ_U64:
+                    p = dbz_put_uint(p, ((const uint64_t*)data[c])[r]);
+                    break;
+                case DBZ_TEXT: {
+                    const int32_t* off = (const int32_t*)offsets[c];
+                    const uint8_t* bytes = (const uint8_t*)data[c];
+                    p = dbz_put_text(p, bytes + off[r], bytes + off[r + 1]);
+                    break;
+                }
+                default: {
+                    const int32_t* off = (const int32_t*)offsets[c];
+                    const size_t len = (size_t)(off[r + 1] - off[r]);
+                    memcpy(p, (const uint8_t*)data[c] + off[r], len);
+                    p += len;
+                }
+            }
+        }
+    }
+    return p - out;
 }
 
 }  // extern "C"

@@ -24,13 +24,13 @@ Type wire formats (ClickHouse RowBinary):
 
 from __future__ import annotations
 
-import ctypes
 from typing import Optional
 
 import numpy as np
 
 from transferia_tpu.abstract.schema import CanonicalType
 from transferia_tpu.columnar.batch import Column, ColumnBatch, _offsets_from_lengths
+from transferia_tpu.native import new_bytes
 
 
 def _leb128_lengths(values: np.ndarray) -> np.ndarray:
@@ -197,12 +197,6 @@ def _encode_column(col: Column, nullable: bool) -> _EncodedColumn:
     return _EncodedColumn(out, field_lens)
 
 
-# a prototype of our own: ctypes.pythonapi's attribute is the process's
-_new_bytes = ctypes.PYFUNCTYPE(
-    ctypes.py_object, ctypes.c_void_p, ctypes.c_ssize_t,
-)(("PyBytes_FromStringAndSize", ctypes.pythonapi))
-
-
 def encoder_path() -> str:
     """Which encoder `encode_rowbinary` runs, as the `serialize` span
     names it: "native" unless the library is switched off."""
@@ -269,10 +263,8 @@ def _encode_native(cdll, batch: ColumnBatch,
                                 nullable_flags)
     if total < 0:
         raise ValueError("a var-width column's offsets decrease")
-    # the result itself, uninitialised until the writer has filled it (a
-    # numpy buffer and tobytes() would copy the batch once more, holding
-    # the GIL)
-    out = _new_bytes(None, total)
+    # the result itself, uninitialised until the writer has filled it
+    out = new_bytes(None, total)
     written = cdll.rowbinary_write(n, n_cols, widths, data, offsets,
                                    validity, nullable_flags, out)
     if written != total:

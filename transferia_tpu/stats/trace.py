@@ -808,12 +808,14 @@ class DeviceTelemetry:
             self.kafka_handouts = 0
             self.kafka_handouts_buffered = 0
             # parts the MySQL source streamed (one result set each), rows
-            # the Debezium emitter rendered and of those the ones its
-            # columnar path took, and batches the transformer chain
-            # passed on because no step of it applies to their table
+            # the Debezium emitter rendered, of those the ones it rendered
+            # from columns and of those the ones its native renderer
+            # took, and batches the transformer chain passed on because
+            # no step of it applies to their table
             self.mysql_parts = 0
             self.debezium_rows = 0
             self.debezium_rows_fast = 0
+            self.debezium_rows_native = 0
             self.chain_batches_untouched = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
@@ -919,11 +921,15 @@ class DeviceTelemetry:
         with self._lock:
             self.mysql_parts += 1
 
-    def record_debezium_rows(self, n_rows: int, fast: bool) -> None:
+    def record_debezium_rows(self, n_rows: int, path: str) -> None:
+        """path: the `serialize` span's - "native", "fast" or "row"; a
+        native row is a columnar ("fast") row too."""
         with self._lock:
             self.debezium_rows += int(n_rows)
-            if fast:
+            if path != "row":
                 self.debezium_rows_fast += int(n_rows)
+            if path == "native":
+                self.debezium_rows_native += int(n_rows)
 
     def record_chain_untouched(self) -> None:
         with self._lock:
@@ -987,6 +993,7 @@ class DeviceTelemetry:
                 "mysql_parts": self.mysql_parts,
                 "debezium_rows": self.debezium_rows,
                 "debezium_rows_fast": self.debezium_rows_fast,
+                "debezium_rows_native": self.debezium_rows_native,
                 "chain_batches_untouched": self.chain_batches_untouched,
             }
 
