@@ -287,6 +287,47 @@ class TableSchema:
         )
 
 
+def declared_schema(items: Any, where: str) -> Optional[TableSchema]:
+    """A source's declared `output_schema` - a list of {name, type,
+    key?}, the types by CanonicalType's names - as a TableSchema; None
+    for an empty one (the source infers).  ValueError, naming `where`,
+    for anything else."""
+    if not items:
+        return None
+    if not isinstance(items, (list, tuple)):
+        raise ValueError(f"{where}: output_schema must be a list of "
+                         f"{{name, type, key}} mappings")
+    cols, seen = [], set()
+    for i, item in enumerate(items):
+        if not isinstance(item, dict):
+            raise ValueError(f"{where}: output_schema[{i}] is not a "
+                             f"mapping")
+        unknown = set(item) - {"name", "type", "key"}
+        if unknown:
+            raise ValueError(f"{where}: output_schema[{i}] has unknown "
+                             f"keys {sorted(unknown)}")
+        name, typ = item.get("name"), item.get("type")
+        if not isinstance(name, str) or not name:
+            raise ValueError(f"{where}: output_schema[{i}] has no name")
+        if name in seen:
+            raise ValueError(f"{where}: output_schema names column "
+                             f"{name!r} twice")
+        seen.add(name)
+        try:
+            ctype = CanonicalType(typ)
+        except ValueError:
+            raise ValueError(
+                f"{where}: output_schema column {name!r} has unknown type "
+                f"{typ!r}; known: {[t.value for t in CanonicalType]}"
+            ) from None
+        key = item.get("key", False)
+        if not isinstance(key, bool):
+            raise ValueError(f"{where}: output_schema column {name!r}: "
+                             f"key must be true or false")
+        cols.append(ColSchema(name=name, data_type=ctype, primary_key=key))
+    return TableSchema(cols)
+
+
 def new_table_schema(cols: list[tuple], **kw) -> TableSchema:
     """Convenience constructor: list of (name, type[, primary_key]) tuples."""
     out = []

@@ -11,10 +11,17 @@ decode.  Failed rows go to `_unparsed` (utils.go:145 policy).
 
 System columns (_timestamp/_partition/_offset/_idx) become the primary key
 like the reference's generic parser output schema.
+
+`JsonBlockDecoder` is the one block decode of JSON lines: this parser's
+columnar shortcut and the file sources' JSON-lines reader
+(providers/s3readers.py::read_json_lines, which `fs` and `s3` share) both
+call it, so a change to it shows on the Kafka path and the file path alike.
 """
 
 from __future__ import annotations
 
+import calendar
+import datetime
 import json
 from typing import Any, Optional, Sequence
 
@@ -52,6 +59,293 @@ def _field_to_colschema(f: dict) -> ColSchema:
         path=f.get("path", ""),
     )
 
+# -- the block decode ------------------------------------------------------------
+
+_TEMPORAL = (CanonicalType.DATE, CanonicalType.DATETIME,
+             CanonicalType.TIMESTAMP)
+_TEXT = (CanonicalType.UTF8, CanonicalType.STRING)
+_EPOCH_DATE = datetime.date(1970, 1, 1)
+
+
+def _int_range(t: CanonicalType) -> tuple[int, int]:
+    info = np.iinfo(t.np_dtype)
+    return int(info.min), int(info.max)
+
+
+def temporal_from_text(t: CanonicalType, text: str) -> int:
+    """`YYYY-MM-DD` / `YYYY-MM-DD hh:mm:ss[.ffffff]` (no zone) as the
+    type's epoch count: days, seconds or microseconds.  ValueError for
+    anything else, and for a fraction a DATETIME cannot hold."""
+    if t == CanonicalType.DATE:
+        return (datetime.date.fromisoformat(text) - _EPOCH_DATE).days
+    d = datetime.datetime.fromisoformat(text)
+    if d.tzinfo is not None:
+        raise ValueError(f"{text!r}: a zone offset")
+    seconds = calendar.timegm(d.timetuple())
+    if t == CanonicalType.TIMESTAMP:
+        return seconds * 1_000_000 + d.microsecond
+    if d.microsecond:
+        raise ValueError(f"{text!r}: a fraction of a second")
+    return seconds
+
+
+def row_value(cs: ColSchema, v: Any) -> Any:
+    """One value of a `json.loads` row as the column holds it - the row
+    path's side of the block decode: integers from numbers or decimal
+    text, checked against the column's width; DATE / DATETIME / TIMESTAMP
+    from epoch counts or `YYYY-MM-DD[ hh:mm:ss]` text.  ValueError for a
+    value the column cannot hold."""
+    t = cs.data_type
+    if v is None:
+        return None
+    if t.is_integer or t in _TEMPORAL:
+        if isinstance(v, str):
+            try:
+                v = int(v)
+            except ValueError:
+                if t not in _TEMPORAL:
+                    raise
+                v = temporal_from_text(t, v)
+        elif isinstance(v, float) and v.is_integer():
+            v = int(v)
+        if isinstance(v, bool) or not isinstance(v, int):
+            raise ValueError(f"{cs.name}: {v!r} is no integer")
+        lo, hi = _int_range(t)
+        if not lo <= v <= hi:
+            raise ValueError(f"{cs.name}: {v} is outside {t.value}")
+        return v
+    if t.is_float:
+        if isinstance(v, bool) or not isinstance(v, (int, float, str)):
+            raise ValueError(f"{cs.name}: {v!r} is no number")
+        return float(v)
+    if t == CanonicalType.BOOLEAN:
+        if isinstance(v, str) and v.lower() in ("true", "false"):
+            return v.lower() == "true"
+        if not isinstance(v, bool):
+            raise ValueError(f"{cs.name}: {v!r} is no boolean")
+        return v
+    if t in _TEXT:
+        if isinstance(v, (int, float)) and not isinstance(v, bool):
+            return str(v)
+        if not isinstance(v, str):
+            raise ValueError(f"{cs.name}: {v!r} is no text")
+    return v
+
+
+class JsonLineError(ValueError):
+    """A JSON line that neither the block path nor the row path takes."""
+
+
+def row_values(lines: Sequence[bytes], fields: Sequence[ColSchema]
+               ) -> dict[str, list]:
+    """The row path: one `json.loads` a line, `row_value` a cell; a
+    missing key is NULL.  JsonLineError names the first line it cannot
+    take by its index in `lines`."""
+    out: dict[str, list] = {f.name: [] for f in fields}
+    for i, line in enumerate(lines):
+        try:
+            row = json.loads(line)
+            if not isinstance(row, dict):
+                raise ValueError("not an object")
+            for f in fields:
+                out[f.name].append(row_value(f, row.get(f.name)))
+        except ValueError as e:
+            err = JsonLineError(f"line {i}: {e}: {bytes(line[:200])!r}")
+            err.index = i
+            raise err from e
+    return out
+
+
+class JsonBlockDecoder:
+    """JSON lines to arrow columns of the declared types, a block at a
+    time: pyarrow's C++ block reader (the GIL released while it runs)
+    with the fields as its explicit schema - a missing key is NULL, keys
+    come in any order, an undeclared key is ignored, every JSON escape is
+    arrow's to undo.  Integers are read at their declared width, so a
+    value outside it fails the block; DATE / DATETIME / TIMESTAMP are
+    epoch counts.
+
+    A column whose numbers or times come as JSON strings - ClickHouse's
+    `JSONEachRow` quotes 64-bit integers and writes DateTime as
+    `"YYYY-MM-DD hh:mm:ss"` - is read as text and cast a column at a time
+    by arrow.  Which columns those are is learnt from the first line of
+    the first block that fails as it stands (one `json.loads`), and kept
+    for the blocks that follow.
+
+    `read` takes one block whole or raises; `decode` isolates the lines a
+    block fails on by halving and gives those to the row path
+    (`row_values`)."""
+
+    # lines of one block the row path takes one by one before the rest of
+    # the failing range goes to it whole: halving a range that is bad all
+    # over costs more than reading it row by row
+    MAX_ISOLATED = 32
+
+    def __init__(self, fields: Sequence[ColSchema],
+                 use_threads: bool = True):
+        import pyarrow as pa
+
+        self.fields = list(fields)
+        # arrow's own pool over a block's chunks and columns: a reader
+        # whose parts already decode side by side turns it off
+        self.use_threads = use_threads
+        wide = {CanonicalType.DATETIME: pa.int64(),
+                CanonicalType.TIMESTAMP: pa.int64(),
+                CanonicalType.DATE: pa.int32(),
+                CanonicalType.FLOAT: pa.float64(),
+                CanonicalType.DOUBLE: pa.float64(),
+                CanonicalType.BOOLEAN: pa.bool_(),
+                CanonicalType.UTF8: pa.string(),
+                CanonicalType.STRING: pa.string()}
+        # what the table holds a column as
+        self.types = {
+            f.name: wide.get(f.data_type) or pa.from_numpy_dtype(
+                f.data_type.np_dtype) for f in self.fields}
+        self.schema = pa.schema(
+            [pa.field(f.name, self.types[f.name]) for f in self.fields])
+        self._via = {CanonicalType.DATE: pa.date32(),
+                     CanonicalType.DATETIME: pa.timestamp("s"),
+                     CanonicalType.TIMESTAMP: pa.timestamp("us")}
+        # (the columns read as text and cast, the schema arrow reads by):
+        # one attribute, swapped whole, since a parser is shared by threads
+        self._variant: tuple = (frozenset(), self.schema)
+
+    @staticmethod
+    def supports(fields: Sequence[ColSchema]) -> bool:
+        """Scalar columns read by their own name; nested paths and ANY /
+        DECIMAL variants are the row path's."""
+        return bool(fields) and all(
+            not f.path and (f.data_type.is_numeric
+                            or f.data_type in _TEMPORAL + _TEXT
+                            or f.data_type == CanonicalType.BOOLEAN)
+            for f in fields)
+
+    def _sniff(self, blob) -> Optional[frozenset]:
+        """The non-text columns that the block's first line holds as JSON
+        strings; None where that line is no JSON object."""
+        end = blob.find(b"\n")
+        try:
+            row = json.loads(blob[:end if end >= 0 else len(blob)])
+        except ValueError:
+            return None
+        if not isinstance(row, dict):
+            return None
+        return frozenset(
+            f.name for f in self.fields
+            if f.data_type not in _TEXT and isinstance(row.get(f.name), str))
+
+    def _read_as(self, blob, variant: tuple, n_lines: Optional[int]):
+        import pyarrow as pa
+        import pyarrow.compute as pc
+        import pyarrow.json as pajson
+
+        text, schema = variant
+        tbl = pajson.read_json(
+            pa.BufferReader(blob),
+            read_options=pajson.ReadOptions(use_threads=self.use_threads),
+            parse_options=pajson.ParseOptions(
+                newlines_in_values=False,
+                explicit_schema=schema,
+                unexpected_field_behavior="ignore",
+            ),
+        )
+        if n_lines is not None and tbl.num_rows != n_lines:
+            raise pa.ArrowInvalid(
+                f"{tbl.num_rows} rows in {n_lines} lines")
+        for f in self.fields:
+            if f.name not in text:
+                continue
+            i = tbl.schema.get_field_index(f.name)
+            col = tbl.column(i)
+            via = self._via.get(f.data_type)
+            if via is not None:
+                col = pc.cast(col, via)
+            tbl = tbl.set_column(i, f.name,
+                                 pc.cast(col, self.types[f.name]))
+        return tbl
+
+    def read(self, blob, n_lines: Optional[int] = None):
+        """One block as an arrow table of `self.schema`; pa.ArrowInvalid
+        where the block as a whole cannot be taken (a bad line, a value
+        outside its column's width, `n_lines` given and not the rows
+        read)."""
+        import pyarrow as pa
+
+        variant = self._variant
+        try:
+            return self._read_as(blob, variant, n_lines)
+        except (pa.ArrowInvalid, pa.ArrowNotImplementedError) as e:
+            text = self._sniff(blob)
+            if text is None or text == variant[0]:
+                raise pa.ArrowInvalid(str(e)) from e
+        variant = (text, pa.schema(
+            [pa.field(f.name, pa.string() if f.name in text
+                      else self.types[f.name]) for f in self.fields]))
+        try:
+            tbl = self._read_as(blob, variant, n_lines)
+        except pa.ArrowNotImplementedError as e:
+            raise pa.ArrowInvalid(str(e)) from e
+        self._variant = variant
+        return tbl
+
+    def rows_table(self, lines: Sequence[bytes]):
+        """`lines` through the row path, as a table of `self.schema`."""
+        import pyarrow as pa
+
+        data = row_values(lines, self.fields)
+        try:
+            return pa.table(
+                [pa.array(data[f.name], type=self.types[f.name])
+                 for f in self.fields], schema=self.schema)
+        except (pa.ArrowInvalid, pa.ArrowTypeError,
+                UnicodeEncodeError) as e:
+            raise JsonLineError(f"{e}: {bytes(lines[0][:200])!r}") from e
+
+    def decode(self, block: bytes):
+        """(the block's rows in line order as a table of `self.schema`,
+        how many of them the block path took).  Blank lines are no rows.
+        JsonLineError for a line neither path takes."""
+        import pyarrow as pa
+
+        n = block.count(b"\n") + (not block.endswith(b"\n"))
+        try:
+            return self.read(block, n), n
+        except pa.ArrowInvalid:
+            pass
+        lines = [ln for ln in block.split(b"\n") if ln.strip()]
+        pieces: list = []
+        by_row = [0]
+
+        def attempt(lo: int, hi: int) -> None:
+            if by_row[0] <= self.MAX_ISOLATED:
+                try:
+                    pieces.append(
+                        self.read(b"\n".join(lines[lo:hi]), hi - lo))
+                    return
+                except pa.ArrowInvalid:
+                    if hi - lo > 1:
+                        mid = (lo + hi) // 2
+                        attempt(lo, mid)
+                        attempt(mid, hi)
+                        return
+            try:
+                pieces.append(self.rows_table(lines[lo:hi]))
+            except JsonLineError as e:
+                raise JsonLineError(
+                    f"line {lo + getattr(e, 'index', 0)} of the block: "
+                    f"{e}") from e
+            by_row[0] += hi - lo
+
+        if len(lines) == n > 1:
+            # the range that has just failed: its halves
+            attempt(0, n // 2)
+            attempt(n // 2, n)
+        elif lines:
+            attempt(0, len(lines))
+        if not pieces:
+            return self.schema.empty_table(), 0
+        return pa.concat_tables(pieces), len(lines) - by_row[0]
+
 
 class _Lines:
     """Flattened (message, line) view of a batch."""
@@ -86,6 +380,7 @@ class GenericJsonParser(Parser):
         self.add_system_cols = add_system_cols
         self.null_keys_allowed = null_keys_allowed
         self._schema: Optional[TableSchema] = None
+        self._block: Optional[JsonBlockDecoder] = None
         if self.fields:
             self._schema = self._build_schema(self.fields)
 
@@ -179,38 +474,23 @@ class GenericJsonParser(Parser):
             attempt(0, len(values), skip_arrow=skip_full_arrow)
         return out
 
-    def _arrow_schema(self):
-        """Explicit arrow schema for the C++ fast path, or None when the
-        declared fields need features arrow can't mirror (nested paths,
-        ANY variants, inference) or pyarrow is absent."""
-        if not self.fields:
-            return None
-        try:
-            import pyarrow as pa
-        except ImportError:
-            return None
-
-        scalar = {
-            CanonicalType.INT8: pa.int64(), CanonicalType.INT16: pa.int64(),
-            CanonicalType.INT32: pa.int64(),
-            CanonicalType.INT64: pa.int64(),
-            CanonicalType.FLOAT: pa.float64(),
-            CanonicalType.DOUBLE: pa.float64(),
-            CanonicalType.BOOLEAN: pa.bool_(),
-            CanonicalType.UTF8: pa.string(),
-            CanonicalType.STRING: pa.string(),
-            # epoch counts held as int64, as `_coerce` takes them: JSON
-            # integers; a float or a string fails the read and the
-            # general path decides
-            CanonicalType.DATETIME: pa.int64(),
-            CanonicalType.TIMESTAMP: pa.int64(),
-        }
-        out = []
-        for cs in self.fields:
-            if cs.path or cs.data_type not in scalar:
+    def _block_decoder(self) -> Optional[JsonBlockDecoder]:
+        """The block decode for the declared fields, or None when they
+        need features it lacks (nested paths, ANY variants, inference)
+        or pyarrow is absent."""
+        if self._block is None and JsonBlockDecoder.supports(self.fields):
+            try:
+                self._block = JsonBlockDecoder(self.fields)
+            except ImportError:  # minimal install: general path only
                 return None
-            out.append(pa.field(cs.name, scalar[cs.data_type]))
-        return pa.schema(out)
+        return self._block
+
+    def _arrow_schema(self):
+        """The explicit arrow schema the general path's block reads use:
+        the decoder's, every column as its JSON type (numbers bare), so
+        that arrow reads a value as `json.loads` would."""
+        dec = self._block_decoder()
+        return dec.schema if dec is not None else None
 
     def _extract(self, rows: list[dict], cs: ColSchema) -> list[Any]:
         if cs.path:
@@ -237,33 +517,16 @@ class GenericJsonParser(Parser):
             return None
         if len(lines.values) < 256:
             return None
-        import io
-
-        import numpy as np
+        dec = self._block_decoder()
+        if dec is None:
+            return None
+        import pyarrow as pa
 
         try:
-            import pyarrow as pa
-            import pyarrow.json as pajson
-        except ImportError:  # minimal install: general path only
-            return None
-        schema = self._arrow_schema()
-        if schema is None:
-            return None
-        try:
-            tbl = pajson.read_json(
-                io.BytesIO(b"\n".join(lines.values)),
-                parse_options=pajson.ParseOptions(
-                    newlines_in_values=False,
-                    explicit_schema=schema,
-                    unexpected_field_behavior="ignore",
-                ),
-            )
-        except (pa.ArrowInvalid, pa.ArrowNotImplementedError):
+            tbl = dec.read(b"\n".join(lines.values), len(lines.values))
+        except pa.ArrowInvalid:
             # tell the general path the full-range arrow parse is a known
             # failure so it goes straight to bisection
-            lines.arrow_failed_full = True
-            return None
-        if tbl.num_rows != len(lines.values):
             lines.arrow_failed_full = True
             return None
         keep = np.ones(tbl.num_rows, dtype=bool)
@@ -432,13 +695,27 @@ def _coerce(data: dict[str, list], schema: TableSchema) -> dict[str, list]:
         if t.is_numeric or t in (CanonicalType.DATETIME,
                                  CanonicalType.TIMESTAMP,
                                  CanonicalType.DATE):
+            bounds = None if t.is_float else _int_range(t)
+
             def conv(v):
-                if v is None or isinstance(v, (int, float)):
-                    return v
-                try:
-                    return float(v) if t.is_float else int(v)
-                except (TypeError, ValueError):
+                if isinstance(v, str):
+                    try:
+                        v = float(v) if t.is_float else int(v)
+                    except ValueError:
+                        # "YYYY-MM-DD hh:mm:ss" where the block decode
+                        # reads it too
+                        if t not in _TEMPORAL:
+                            return None
+                        try:
+                            v = temporal_from_text(t, v)
+                        except ValueError:
+                            return None
+                elif v is not None and not isinstance(v, (int, float)):
                     return None
+                if bounds and isinstance(v, int) \
+                        and not bounds[0] <= v <= bounds[1]:
+                    return None     # the column's width cannot hold it
+                return v
             out[name] = [conv(v) for v in values]
         elif t == CanonicalType.BOOLEAN:
             out[name] = [

@@ -17,7 +17,7 @@ import json
 import os
 import threading
 import uuid
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Optional
 
 import numpy as np
@@ -34,7 +34,11 @@ from transferia_tpu.abstract.interfaces import (
     is_columnar,
 )
 from transferia_tpu.abstract.kinds import Kind
-from transferia_tpu.abstract.schema import TableID, TableSchema
+from transferia_tpu.abstract.schema import (
+    TableID,
+    TableSchema,
+    declared_schema,
+)
 
 from transferia_tpu.abstract.table import TableDescription
 from transferia_tpu.columnar.batch import ColumnBatch, arrow_to_table_schema
@@ -71,6 +75,28 @@ class FileSourceParams(EndpointParams):
     readahead_groups: int = -1
     readahead_bytes: int = 0
     rowgroups_per_part: int = 0
+    # the table's columns, declared: [{name, type, key}], the types by
+    # abstract/schema.py CanonicalType's names (upstream's OutputSchema).
+    # For jsonl and csv; with it no line is read to learn the schema and
+    # the text is decoded to these types.  Empty: the schema is inferred
+    # from the first file
+    output_schema: list = field(default_factory=list)
+
+    def __post_init__(self):
+        declared_text_schema(self)
+
+
+def declared_text_schema(params) -> Optional[TableSchema]:
+    """The `output_schema` of an `fs` / `s3` source as a TableSchema, None
+    where it declares none; ValueError for one that cannot be read or
+    that a format with a schema of its own is given."""
+    where = f"{params.PROVIDER} source"
+    schema = declared_schema(params.output_schema, where)
+    if schema is not None and params.format not in ("jsonl", "csv"):
+        raise ValueError(
+            f"{where}: output_schema is for format jsonl or csv; "
+            f"{params.format!r} carries its own schema")
+    return schema
 
 
 @register_endpoint
@@ -160,6 +186,8 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
     # -- schema inference ---------------------------------------------------
     def table_schema(self, table: TableID) -> TableSchema:
         if self._schema is None:
+            self._schema = declared_text_schema(self.params)
+        if self._schema is None:
             f = self._files()[0]
             if self.params.format == "parquet":
                 import pyarrow.parquet as pq
@@ -175,18 +203,12 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
                 with pacsv.open_csv(f) as reader:
                     self._schema = arrow_to_table_schema(reader.schema)
             else:  # jsonl: sample first lines
-                import pyarrow as pa
+                from transferia_tpu.providers.s3readers import (
+                    infer_json_lines_schema,
+                )
 
-                rows = []
-                with open(f) as fh:
-                    for line in fh:
-                        if not line.strip():
-                            continue  # skip blanks like the loader does
-                        rows.append(json.loads(line))
-                        if len(rows) >= 100:
-                            break
-                tbl = pa.Table.from_pylist(rows)
-                self._schema = arrow_to_table_schema(tbl.schema)
+                with open(f, "rb") as fh:
+                    self._schema = infer_json_lines_schema(fh)
         return self._schema
 
     def table_list(self, include=None):
@@ -481,11 +503,17 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         elif fmt == "csv":
             import pyarrow.csv as pacsv
 
+            from transferia_tpu.providers.s3readers import (
+                csv_convert_options,
+            )
+
             with pacsv.open_csv(
                 path,
                 read_options=pacsv.ReadOptions(
                     block_size=max(1 << 20, self.params.batch_rows * 64)
                 ),
+                convert_options=csv_convert_options(
+                    schema if self.params.output_schema else None),
             ) as reader:
                 for rb in reader:
                     rb = self._scan_filter(tid, rb)
@@ -494,29 +522,16 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
                         batch.read_bytes = rb.nbytes
                         pusher(batch)
         elif fmt == "jsonl":
-            rows: list[dict] = []
-            nbytes = 0
+            from transferia_tpu.providers.s3readers import read_json_lines
+
             with open(path, "rb") as fh:
-                for line in fh:
-                    if not line.strip():
-                        continue
-                    rows.append(json.loads(line))
-                    nbytes += len(line)
-                    if len(rows) >= self.params.batch_rows:
-                        self._push_json_rows(rows, nbytes, tid, schema, pusher)
-                        rows, nbytes = [], 0
-            if rows:
-                self._push_json_rows(rows, nbytes, tid, schema, pusher)
+                # the parquet decode's rule for its column threads: none
+                # where the upload workers already fill the cores
+                read_json_lines(fh, path, tid, schema,
+                                self.params.batch_rows, pusher,
+                                use_threads=self._decode_threads() > 1)
         else:
             raise ValueError(f"fs source: unknown format {fmt!r}")
-
-    @staticmethod
-    def _push_json_rows(rows: list[dict], nbytes: int, tid: TableID,
-                        schema: TableSchema, pusher: Pusher) -> None:
-        data = {c.name: [r.get(c.name) for r in rows] for c in schema}
-        batch = ColumnBatch.from_pydict(tid, schema, data)
-        batch.read_bytes = nbytes
-        pusher(batch)
 
 
 class FileSinker(Sinker, StagedSinker):

@@ -37,6 +37,7 @@ from transferia_tpu.abstract.schema import TableID, TableSchema
 from transferia_tpu.abstract.table import TableDescription
 from transferia_tpu.columnar.batch import ColumnBatch, arrow_to_table_schema
 from transferia_tpu.models.endpoint import EndpointParams, register_endpoint
+from transferia_tpu.providers.file import declared_text_schema
 from transferia_tpu.providers.registry import (
     Provider,
     TestResult,
@@ -63,6 +64,9 @@ class S3SourceParams(EndpointParams):
     nginx_format: str = ""     # log_format template (default: combined)
     unparsed_policy: str = "route"   # route | skip | fail
     parser: Optional[dict] = None    # protobuf descriptor config (proto)
+    # the table's columns, declared ([{name, type, key}]; upstream's
+    # OutputSchema): for jsonl and csv, as providers/file.py has it
+    output_schema: list = field(default_factory=list)
 
     # -- replication (reference pkg/providers/s3/source/) -------------------
     event_source: str = ""     # "" (snapshot-only) | poll | sqs
@@ -75,6 +79,9 @@ class S3SourceParams(EndpointParams):
     sqs_wait_seconds: int = 10
     path_pattern: str = ""     # restrict replicated keys (glob)
 
+    def __post_init__(self):
+        declared_text_schema(self)
+
     def make_reader(self):
         from transferia_tpu.providers.s3readers import make_reader
 
@@ -82,6 +89,7 @@ class S3SourceParams(EndpointParams):
             self.format, nginx_format=self.nginx_format,
             unparsed_policy=self.unparsed_policy,
             parser_config=self.parser,
+            declared=declared_text_schema(self),
         )
 
 
@@ -177,8 +185,9 @@ class S3Storage(Storage, ShardingStorage, AsyncPartDiscovery):
     # -- schema inference (reader/abstract.go:40-52) ------------------------
     def table_schema(self, table: TableID) -> TableSchema:
         if self._schema is None:
-            self._schema = self.reader.infer_schema(
-                self.fs, self.files()[0])
+            # a declared schema is the reader's own: nothing is listed
+            self._schema = self.reader.declared \
+                or self.reader.infer_schema(self.fs, self.files()[0])
         return self._schema
 
     def table_list(self, include=None):

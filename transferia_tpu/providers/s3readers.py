@@ -4,8 +4,11 @@ reader/abstract.go:40-52).
 
 A Reader turns one object into ColumnBatches.  Formats:
   parquet — arrow row groups straight to columnar (zero pivot)
-  csv     — arrow CSV with inferred schema
-  jsonl   — newline-delimited JSON, schema inferred from a sample
+  csv     — arrow CSV, schema declared (`output_schema`) or inferred
+  jsonl   — newline-delimited JSON, schema declared (`output_schema`) or
+            inferred from a sample; read a block at a time by the block
+            decode the JSON parser has (`read_json_lines`, which the
+            `fs` source calls too)
   line    — each line one row (utf8) + system columns
   nginx   — nginx log_format template parsing ($var tokens), typed fields
   proto   — varint length-prefixed protobuf frames through the protobuf
@@ -53,6 +56,9 @@ class ReaderError(CategorizedError):
 class Reader(abc.ABC):
     """One-object reader; fs is an fsspec filesystem."""
 
+    # the source's `output_schema`, for the formats that take one
+    declared: Optional[TableSchema] = None
+
     @abc.abstractmethod
     def infer_schema(self, fs, path: str) -> TableSchema:
         ...
@@ -98,10 +104,30 @@ class ParquetReader(Reader):
                     pusher(batch)
 
 
+def csv_convert_options(schema: Optional[TableSchema]):
+    """A declared schema's types for arrow's CSV reader: every declared
+    column converted to its type (DateTime from `YYYY-MM-DD hh:mm:ss`,
+    Date from `YYYY-MM-DD`), never guessed."""
+    import pyarrow.csv as pacsv
+
+    from transferia_tpu.columnar.batch import _ARROW_TYPES
+
+    if schema is None:
+        return pacsv.ConvertOptions()
+    return pacsv.ConvertOptions(
+        column_types={c.name: _ARROW_TYPES[c.data_type] for c in schema},
+        include_columns=schema.names())
+
+
 class CsvReader(Reader):
+    def __init__(self, declared: Optional[TableSchema] = None):
+        self.declared = declared
+
     def infer_schema(self, fs, path: str) -> TableSchema:
         import pyarrow.csv as pacsv
 
+        if self.declared is not None:
+            return self.declared
         with fs.open(path, "rb") as fh:
             head = fh.read(1 << 20)
         with pacsv.open_csv(io.BytesIO(head)) as reader:
@@ -112,7 +138,10 @@ class CsvReader(Reader):
 
         with fs.open(path, "rb") as fh:
             data = fh.read()
-        with pacsv.open_csv(io.BytesIO(data)) as reader:
+        with pacsv.open_csv(
+                io.BytesIO(data),
+                convert_options=csv_convert_options(self.declared)
+        ) as reader:
             for rb in reader:
                 if rb.num_rows:
                     batch = ColumnBatch.from_arrow(rb, tid, schema)
@@ -120,40 +149,142 @@ class CsvReader(Reader):
                     pusher(batch)
 
 
-class JsonlReader(Reader):
-    def infer_schema(self, fs, path: str) -> TableSchema:
-        import pyarrow as pa
+# a text object is read this many bytes at a time; a block handed to the
+# decode ends at a newline
+JSONL_BLOCK_BYTES = 16 << 20
 
-        rows = []
+
+def _text_blocks(fh, path: str):
+    """The object's bytes in blocks that end at a newline (the last one
+    where the object ends), one `file_read` span a read."""
+    from transferia_tpu.stats import trace
+
+    def read() -> bytes:
+        with trace.span("file_read") as sp:
+            chunk = fh.read(JSONL_BLOCK_BYTES)
+            if sp:
+                sp.add(bytes=len(chunk), path=path)
+        return chunk
+
+    tail = b""
+    chunk = read()
+    while chunk:
+        following = read()
+        cut = chunk.rfind(b"\n") + 1 if following else len(chunk)
+        if cut:
+            yield tail + chunk[:cut], not following
+            tail = chunk[cut:]
+        else:               # a line longer than a read
+            tail += chunk
+        chunk = following
+
+
+def read_json_lines(fh, path: str, tid: TableID, schema: TableSchema,
+                    batch_rows: int, pusher: Pusher,
+                    use_threads: bool = True) -> None:
+    """The one JSON-lines reader of the file sources (`fs` and `s3`): the
+    object a block at a time through the JSON parser's block decode
+    (parsers/generic.py::JsonBlockDecoder: arrow's C++ reader with the
+    schema's types, the GIL released, never a list of dicts), the blocks'
+    tables cut into ColumnBatches of at most `batch_rows`.  A line the
+    block path cannot take goes through the row path alone (one
+    `json.loads`) and is counted (`jsonl_rows` - `jsonl_rows_block`); a
+    line neither takes fails the read.  A schema the block decode does
+    not support (ANY columns, as inference gives for nested values) goes
+    through the row path whole.  `use_threads`: whether arrow spreads a
+    block over its own pool (a caller whose parts already decode side by
+    side says no)."""
+    import pyarrow as pa
+
+    from transferia_tpu.parsers.generic import (
+        JsonBlockDecoder,
+        JsonLineError,
+        row_values,
+    )
+    from transferia_tpu.stats import trace
+    from transferia_tpu.stats.trace import TELEMETRY
+
+    fields = list(schema)
+    carry = None          # decoded rows short of a batch: an arrow table
+
+    def by_block(block: bytes, last: bool):
+        nonlocal carry
+        tbl, taken = dec.decode(block)
+        rows = tbl.num_rows
+        if carry is not None:
+            tbl = pa.concat_tables([carry, tbl])
+        n = tbl.num_rows
+        whole = n if last else n - n % batch_rows
+        batches = []
+        for lo in range(0, whole, batch_rows):
+            rb = tbl.slice(lo, min(batch_rows, whole - lo)) \
+                .combine_chunks().to_batches()[0]
+            batches.append(ColumnBatch.from_arrow(rb, tid, schema))
+            batches[-1].read_bytes = rb.nbytes
+        carry = tbl.slice(whole) if whole < n else None
+        return rows, taken, batches
+
+    def by_row(block: bytes, last: bool):
+        # no arrow type for these columns: batches cut from the block's
+        # lines
+        lines = [ln for ln in block.split(b"\n") if ln.strip()]
+        batches = []
+        for lo in range(0, len(lines), batch_rows):
+            batches.append(ColumnBatch.from_pydict(
+                tid, schema, row_values(lines[lo:lo + batch_rows], fields)))
+            batches[-1].read_bytes = \
+                len(block) * batches[-1].n_rows // len(lines)
+        return len(lines), 0, batches
+
+    if JsonBlockDecoder.supports(fields):
+        dec, decode = JsonBlockDecoder(fields, use_threads), by_block
+    else:
+        decode = by_row
+    lines_seen = 0
+    for block, last in _text_blocks(fh, path):
+        with trace.span("source_decode", format="jsonl") as sp:
+            try:
+                rows, taken, batches = decode(block, last)
+            except JsonLineError as e:
+                raise ReaderError(
+                    f"{path}: unparsed JSON line after line "
+                    f"{lines_seen}: {e}") from e
+            lines_seen += rows
+            if sp:
+                sp.add(rows=rows, bytes=len(block),
+                       path="block" if taken == rows else "row")
+        TELEMETRY.record_jsonl(rows, taken, len(block))
+        for batch in batches:
+            pusher(batch)
+
+
+def infer_json_lines_schema(fh) -> TableSchema:
+    """The schema of JSON lines where none is declared: what arrow makes
+    of the first 100 rows (every integer Int64, times and dates text)."""
+    import pyarrow as pa
+
+    rows = []
+    for line in fh:
+        if line.strip():
+            rows.append(json.loads(line))
+            if len(rows) >= 100:
+                break
+    return arrow_to_table_schema(pa.Table.from_pylist(rows).schema)
+
+
+class JsonlReader(Reader):
+    def __init__(self, declared: Optional[TableSchema] = None):
+        self.declared = declared
+
+    def infer_schema(self, fs, path: str) -> TableSchema:
+        if self.declared is not None:
+            return self.declared
         with fs.open(path, "rb") as fh:
-            for i, line in enumerate(fh):
-                if i >= 100:
-                    break
-                if line.strip():
-                    rows.append(json.loads(line))
-        return arrow_to_table_schema(pa.Table.from_pylist(rows).schema)
+            return infer_json_lines_schema(fh)
 
     def read(self, fs, path, tid, schema, batch_rows, pusher) -> None:
-        rows: list[dict] = []
-        nbytes = 0
         with fs.open(path, "rb") as fh:
-            for line in fh:
-                if not line.strip():
-                    continue
-                rows.append(json.loads(line))
-                nbytes += len(line)
-                if len(rows) >= batch_rows:
-                    self._push(rows, nbytes, tid, schema, pusher)
-                    rows, nbytes = [], 0
-        if rows:
-            self._push(rows, nbytes, tid, schema, pusher)
-
-    @staticmethod
-    def _push(rows, nbytes, tid, schema, pusher):
-        data = {c.name: [r.get(c.name) for r in rows] for c in schema}
-        batch = ColumnBatch.from_pydict(tid, schema, data)
-        batch.read_bytes = nbytes
-        pusher(batch)
+            read_json_lines(fh, path, tid, schema, batch_rows, pusher)
 
 
 class LineReader(Reader):
@@ -412,13 +543,14 @@ class ProtoReader(Reader):
 
 def make_reader(fmt: str, *, nginx_format: str = "",
                 unparsed_policy: str = "route",
-                parser_config: Optional[dict] = None) -> Reader:
+                parser_config: Optional[dict] = None,
+                declared: Optional[TableSchema] = None) -> Reader:
     if fmt == "parquet":
         return ParquetReader()
     if fmt == "csv":
-        return CsvReader()
+        return CsvReader(declared)
     if fmt == "jsonl":
-        return JsonlReader()
+        return JsonlReader(declared)
     if fmt == "line":
         return LineReader()
     if fmt == "nginx":

@@ -50,6 +50,7 @@ _EXACT = {
     "decode_readahead": "decode",
     "native_rowgroup_decode": "decode",
     "kafka_decode": "decode",
+    "file_read": "decode",
     "pivot": "decode",
     "batch": "decode",
     "transform": "transform",

@@ -29,6 +29,10 @@ roots `part` / `batch` / `replication_attempt` carry identity args
 `pivot`, `pack`, `device_dispatch`, `device_wait`, `host_post`,
 `transform`, `serialize`, `bufferer_flush`, `sink_push`, `sink` nest
 under them.  `device_dispatch`/`device_wait` carry byte counts as args.
+`file_read` is the read of one block of a text object (args `bytes`,
+`path`) and `source_decode` with `format="jsonl"` its decode (args `rows`,
+`bytes`, `path` `"block"` or `"row"`), both in
+providers/s3readers.py::read_json_lines.
 `decode_readahead` spans live on the prefetcher worker threads
 (providers/readahead.py) — decode running there shows as its own
 track, overlapping the part's downstream spans.  Waits that are known
@@ -817,6 +821,13 @@ class DeviceTelemetry:
             self.debezium_rows_fast = 0
             self.debezium_rows_native = 0
             self.chain_batches_untouched = 0
+            # rows the file sources' JSON-lines reader decoded, of those
+            # the ones the block decode took (the others went through
+            # the row path, one `json.loads` each), and the bytes of
+            # their lines (providers/s3readers.py::read_json_lines)
+            self.jsonl_rows = 0
+            self.jsonl_rows_block = 0
+            self.jsonl_bytes = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -931,6 +942,13 @@ class DeviceTelemetry:
             if path == "native":
                 self.debezium_rows_native += int(n_rows)
 
+    def record_jsonl(self, rows: int, rows_block: int,
+                     nbytes: int) -> None:
+        with self._lock:
+            self.jsonl_rows += int(rows)
+            self.jsonl_rows_block += int(rows_block)
+            self.jsonl_bytes += int(nbytes)
+
     def record_chain_untouched(self) -> None:
         with self._lock:
             self.chain_batches_untouched += 1
@@ -995,6 +1013,9 @@ class DeviceTelemetry:
                 "debezium_rows_fast": self.debezium_rows_fast,
                 "debezium_rows_native": self.debezium_rows_native,
                 "chain_batches_untouched": self.chain_batches_untouched,
+                "jsonl_rows": self.jsonl_rows,
+                "jsonl_rows_block": self.jsonl_rows_block,
+                "jsonl_bytes": self.jsonl_bytes,
             }
 
     def fold_into(self, metrics) -> None:
