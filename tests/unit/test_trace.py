@@ -231,6 +231,41 @@ def test_device_telemetry_wired_in_fused_path():
     assert any((s[7] or {}).get("bytes", 0) > 0 for s in waits)
 
 
+def test_snapshot_operation_counts_the_process_cpu_and_faults():
+    from transferia_tpu.abstract import TableID
+    from transferia_tpu.coordinator import MemoryCoordinator
+    from transferia_tpu.models import Transfer
+    from transferia_tpu.providers.memory import (
+        MemorySourceParams,
+        MemoryTargetParams,
+        get_store,
+        seed_source,
+    )
+    from transferia_tpu.providers.sample import make_batch
+    from transferia_tpu.tasks import SnapshotLoader
+
+    names = ("proc_cpu_ms", "proc_cpu_sys_ms", "proc_minor_faults")
+    tid = TableID("sample", "users")
+    seed_source("proc_usage", [make_batch("users", tid, lo, 500, seed=5)
+                               for lo in range(0, 4000, 500)])
+    t = Transfer(id="proc_usage",
+                 src=MemorySourceParams(source_id="proc_usage"),
+                 dst=MemoryTargetParams(sink_id="proc_usage"))
+    trace.TELEMETRY.reset()
+    assert [trace.TELEMETRY.snapshot()[k] for k in names] == [0, 0, 0]
+    SnapshotLoader(t, MemoryCoordinator()).upload_tables()
+    assert get_store("proc_usage").row_count() == 4000
+    tel = trace.TELEMETRY.snapshot()
+    assert tel["proc_cpu_ms"] > 0
+    assert 0 <= tel["proc_cpu_sys_ms"] <= tel["proc_cpu_ms"]
+    assert tel["proc_minor_faults"] >= 0
+    # a second operation adds to the first; reset clears all three
+    SnapshotLoader(t, MemoryCoordinator()).upload_tables()
+    assert trace.TELEMETRY.snapshot()["proc_cpu_ms"] > tel["proc_cpu_ms"]
+    trace.TELEMETRY.reset()
+    assert [trace.TELEMETRY.snapshot()[k] for k in names] == [0, 0, 0]
+
+
 def test_telemetry_folds_into_metrics_facade():
     from transferia_tpu.stats.registry import Metrics
 

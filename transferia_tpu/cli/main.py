@@ -371,7 +371,14 @@ def _setup(args) -> None:
     )
     from transferia_tpu.runtime import knobs
     from transferia_tpu.runtime.backend import setup_compile_cache
+    from transferia_tpu.runtime.limits import (
+        apply_allocator_policy,
+        apply_resource_limits,
+    )
 
+    # first, before this process starts a thread: an arena that is
+    # there keeps its old heap (PERF.md section 6, PR 36)
+    apply_allocator_policy()
     setup_compile_cache()  # before the first jit
     if knobs.env_str("TRANSFERIA_TPU_TRACE", "") not in (
             "", "0", "false", "no"):
@@ -398,8 +405,6 @@ def _setup(args) -> None:
     if args.health_port:
         _start_health_server(args.health_port)
     # cgroup-derived RAM budget (runtime/shared/limits.go parity)
-    from transferia_tpu.runtime.limits import apply_resource_limits
-
     apply_resource_limits()
 
 

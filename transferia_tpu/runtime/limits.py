@@ -10,13 +10,18 @@ apply_resource_limits() is called by the CLI at worker startup:
   forces a full gc.collect() and logs; above the hard fraction it calls
   the on_pressure callback (default: log loudly — sinks' bufferers also
   see memory pressure through the memthrottle middleware).
+
+apply_allocator_policy() is called beside it, once a process: the
+allocators do not go to the kernel for each object.
 """
 
 from __future__ import annotations
 
+import ctypes
 import gc
 import logging
 import os
+import sys
 import threading
 from typing import Callable, Optional
 
@@ -146,3 +151,43 @@ def apply_resource_limits(limit_bytes: Optional[int] = None,
     wd = MemoryWatchdog(limit, on_pressure=on_pressure).start()
     logger.info("memory watchdog armed at %dMiB (cgroup)", limit >> 20)
     return wd
+
+
+# glibc's mallopt parameters (malloc.h) at the values PR 36 measured on
+# the chip's host (PERF.md section 6, "PR 36": the nine readings).  A
+# thread other than the first gets an arena whose heap glibc grows a
+# page at a time, one mprotect each, and gives back the same way: with
+# these a heap is taken whole, what is freed is kept, and a buffer up to
+# half a heap comes from the heap (setting any of the three stops
+# glibc's own raising of the mmap threshold from 128 KiB, so it is set
+# to the most that raising reaches, DEFAULT_MMAP_THRESHOLD_MAX).
+_MALLOPT = (
+    ("M_TOP_PAD", -2, 64 << 20),
+    ("M_TRIM_THRESHOLD", -1, 1 << 30),
+    ("M_MMAP_THRESHOLD", -3, 32 << 20),
+)
+_allocator_policy_applied = False
+
+
+def apply_allocator_policy() -> None:
+    """One allocator policy a process, set before any part thread
+    exists: on Linux with glibc the three `mallopt` settings above.
+    The first call applies it and later calls do nothing (the setting
+    is the process's for life); where `mallopt` is missing (musl,
+    macOS) no call does anything.  A setting glibc refuses (return 0)
+    is logged once as a warning and never raised."""
+    global _allocator_policy_applied
+    if _allocator_policy_applied:
+        return
+    _allocator_policy_applied = True
+    if not sys.platform.startswith("linux"):
+        return
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return
+    for name, param, value in _MALLOPT:
+        if mallopt(param, value) == 0:
+            logger.warning("allocator policy: mallopt(%s, %d) was "
+                           "refused; the setting stays glibc's default",
+                           name, value)

@@ -828,6 +828,15 @@ class DeviceTelemetry:
             self.jsonl_rows = 0
             self.jsonl_rows_block = 0
             self.jsonl_bytes = 0
+            # what the whole process (every thread) spent between the
+            # opening and the closing of its snapshot operations
+            # (tasks/snapshot.py, getrusage): CPU time, of it system
+            # time, and page faults served without I/O - an allocator
+            # that goes to the kernel for each object shows as system
+            # time and faults
+            self.proc_cpu_ms = 0.0
+            self.proc_cpu_sys_ms = 0.0
+            self.proc_minor_faults = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -949,6 +958,16 @@ class DeviceTelemetry:
             self.jsonl_rows_block += int(rows_block)
             self.jsonl_bytes += int(nbytes)
 
+    def record_proc_usage(self, before, after) -> None:
+        """`resource.getrusage(RUSAGE_SELF)` at the two ends of one
+        snapshot operation."""
+        sys_ms = (after.ru_stime - before.ru_stime) * 1e3
+        with self._lock:
+            self.proc_cpu_ms += (after.ru_utime - before.ru_utime) * 1e3 \
+                + sys_ms
+            self.proc_cpu_sys_ms += sys_ms
+            self.proc_minor_faults += after.ru_minflt - before.ru_minflt
+
     def record_chain_untouched(self) -> None:
         with self._lock:
             self.chain_batches_untouched += 1
@@ -1016,6 +1035,9 @@ class DeviceTelemetry:
                 "jsonl_rows": self.jsonl_rows,
                 "jsonl_rows_block": self.jsonl_rows_block,
                 "jsonl_bytes": self.jsonl_bytes,
+                "proc_cpu_ms": round(self.proc_cpu_ms, 3),
+                "proc_cpu_sys_ms": round(self.proc_cpu_sys_ms, 3),
+                "proc_minor_faults": self.proc_minor_faults,
             }
 
     def fold_into(self, metrics) -> None:

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import logging
 import os
+import resource
 import threading
 import time
 from collections import deque
@@ -197,6 +198,7 @@ class SnapshotLoader:
         op_sp = trace.span("snapshot_op", transfer_id=self.transfer.id,
                            operation_id=self.operation_id,
                            worker=self.worker_index)
+        usage = resource.getrusage(resource.RUSAGE_SELF)
         try:
             with op_sp, LEDGER.context(transfer_id=self.transfer.id):
                 if tables is None:
@@ -206,6 +208,8 @@ class SnapshotLoader:
                 else:
                     self._secondary_flow(storage)
         finally:
+            trace.TELEMETRY.record_proc_usage(
+                usage, resource.getrusage(resource.RUSAGE_SELF))
             storage.close()
             # final observability flush: whatever this operation spent
             # survives the process even if it exits right after
