@@ -631,6 +631,43 @@ class TestFleetChromeTrace:
         assert all(e.get("args", {}).get("trace_id") != 6
                    for e in doc["traceEvents"] if e.get("ph") == "X")
 
+    def test_a_twelve_field_record_round_trips_and_an_older_one_reads(
+            self):
+        """The span tuple's twelfth field (self CPU seconds) rides the
+        segment as it is; a segment written before the field has eleven
+        and reads as before."""
+        from transferia_tpu.stats import critpath
+
+        cp = MemoryCoordinator()
+        exp = ObsExporter(cp, worker="wt.1", scope="sc")
+        trace.enable(True)
+        try:
+            trace.reset()
+            with trace.span("snapshot_op", transfer_id="tr-12"):
+                end = time.thread_time() + 0.01
+                while time.thread_time() < end:
+                    pass
+            trace.complete("queue_wait", time.perf_counter() - 1.0, 1.0)
+            assert exp.export("final")
+        finally:
+            trace.enable(False)
+        seg = json.loads(json.dumps(cp.list_obs_segments("sc")[0]))
+        by_name = {r[0]: r for r in seg["spans"]}
+        assert len(by_name["snapshot_op"]) == 12
+        assert by_name["snapshot_op"][11] >= 0.009
+        assert by_name["queue_wait"][11] is None
+        old = make_segment(
+            worker="old", pid=7, seq=1,
+            spans=[_span_rec("part", 1, 0.1, 0.5, None, 5, 2, 0)])
+        assert len(old["spans"][0]) == 11
+        doc = export_fleet_chrome_trace([seg, old])
+        names = {e["name"] for e in doc["traceEvents"]
+                 if e.get("ph") == "X"}
+        assert names >= {"snapshot_op", "queue_wait", "part"}
+        records = critpath.records_from_segments([seg, old])
+        assert {r["name"] for r in records} >= {"snapshot_op", "part"}
+        assert critpath.explain(records, transfer_id="tr-12")
+
     def test_overlapping_export_windows_dedup(self):
         rec = _span_rec("s", 1, 0.0, 1.0, None, 5, 1, 0)
         segs = [make_segment(worker="a", pid=1, seq=1, spans=[rec]),

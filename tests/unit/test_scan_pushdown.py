@@ -174,6 +174,27 @@ class TestFileSourceE2E:
         assert st.scan_rows_pruned > 0
         assert sum(got) + st.scan_rows_pruned == 2000
 
+    def test_the_scan_filter_is_a_span_of_the_part_thread(self, tmp_path):
+        """The pushed-down predicate runs on the part thread between the
+        decode and the `batch` it feeds: a span of its own under `part`
+        (tasks/snapshot.py), so `part` self time does not hold it."""
+        from transferia_tpu.stats import trace
+
+        path = self._write_parquet(tmp_path)
+        trace.enable(True)
+        trace.reset()
+        try:
+            pushed = self._run(path, pushdown=True)
+        finally:
+            trace.enable(False)
+        spans = [s for s in trace.spans() if s[6] >= 0]
+        trace.reset()
+        by_id = {s[9]: s for s in spans}
+        filters = [s for s in spans if s[0] == "scan_filter"]
+        assert len(filters) == 4                  # one a row group
+        assert 0 < len(pushed) < 2000     # and it dropped rows
+        assert all(by_id[s[10]][0] == "part" for s in filters)
+
     def test_zone_map_prunes_sorted_row_groups(self, tmp_path):
         """Sorted data: min/max stats disprove whole row groups -> they
         are skipped before decode."""

@@ -244,7 +244,7 @@ def test_snapshot_operation_counts_the_process_cpu_and_faults():
     from transferia_tpu.providers.sample import make_batch
     from transferia_tpu.tasks import SnapshotLoader
 
-    names = ("proc_cpu_ms", "proc_cpu_sys_ms", "proc_minor_faults")
+    names = ("proc_cpu_ms", "proc_cpu_sys_ms")
     tid = TableID("sample", "users")
     seed_source("proc_usage", [make_batch("users", tid, lo, 500, seed=5)
                                for lo in range(0, 4000, 500)])
@@ -252,18 +252,18 @@ def test_snapshot_operation_counts_the_process_cpu_and_faults():
                  src=MemorySourceParams(source_id="proc_usage"),
                  dst=MemoryTargetParams(sink_id="proc_usage"))
     trace.TELEMETRY.reset()
-    assert [trace.TELEMETRY.snapshot()[k] for k in names] == [0, 0, 0]
+    assert [trace.TELEMETRY.snapshot()[k] for k in names] == [0, 0]
     SnapshotLoader(t, MemoryCoordinator()).upload_tables()
     assert get_store("proc_usage").row_count() == 4000
     tel = trace.TELEMETRY.snapshot()
     assert tel["proc_cpu_ms"] > 0
     assert 0 <= tel["proc_cpu_sys_ms"] <= tel["proc_cpu_ms"]
-    assert tel["proc_minor_faults"] >= 0
-    # a second operation adds to the first; reset clears all three
+    assert "proc_minor_faults" not in tel
+    # a second operation adds to the first; reset clears both
     SnapshotLoader(t, MemoryCoordinator()).upload_tables()
     assert trace.TELEMETRY.snapshot()["proc_cpu_ms"] > tel["proc_cpu_ms"]
     trace.TELEMETRY.reset()
-    assert [trace.TELEMETRY.snapshot()[k] for k in names] == [0, 0, 0]
+    assert [trace.TELEMETRY.snapshot()[k] for k in names] == [0, 0]
 
 
 def test_telemetry_folds_into_metrics_facade():
@@ -724,3 +724,222 @@ def test_kafka_handout_counters_are_in_the_snapshot_and_reset():
     trace.TELEMETRY.reset()
     tel = trace.TELEMETRY.snapshot()
     assert (tel["kafka_handouts"], tel["kafka_handouts_buffered"]) == (0, 0)
+
+
+# -- the thread's CPU clock beside the wall clock -----------------------------
+
+def _spin_cpu(seconds):
+    """Burn `seconds` of this thread's CPU clock (not the wall clock:
+    the suite runs several workers wide)."""
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+def _one(name):
+    found = [s for s in trace.spans() if s[0] == name]
+    assert len(found) == 1, found
+    return found[0]
+
+
+def test_a_span_that_works_reads_its_cpu_seconds():
+    trace.enable(True)
+    with trace.span("spin"):
+        _spin_cpu(0.03)
+    rec = _one("spin")
+    assert len(rec) == 12
+    assert rec[11] >= 0.025
+    # both clocks cover one interval: a thread is on a core for no
+    # longer than the wall clock ran
+    assert rec[11] <= rec[5] + 0.002
+
+
+def test_a_span_that_sleeps_reads_next_to_no_cpu():
+    trace.enable(True)
+    with trace.span("sleep"):
+        time.sleep(0.03)
+    rec = _one("sleep")
+    assert rec[5] >= 0.03
+    assert 0.0 <= rec[11] < 0.01
+
+
+def test_a_parent_keeps_its_childs_cpu_out_of_its_own():
+    trace.enable(True)
+    with trace.span("parent"):
+        with trace.span("child"):
+            _spin_cpu(0.03)
+    parent, child = _one("parent"), _one("child")
+    assert child[11] >= 0.025
+    assert 0.0 <= parent[11] < 0.01
+
+
+class _CostlyCpuClock:
+    """Both clocks of a thread that never leaves its core, where a read
+    of the CPU clock takes 6 units (a system call: the value is taken
+    half way through) and a read of the wall clock none."""
+
+    def __init__(self):
+        self.now = 0.0
+
+    def perf_counter(self):
+        return self.now
+
+    def thread_time(self):
+        self.now += 3.0
+        value = self.now
+        self.now += 3.0
+        return value
+
+
+def test_the_two_clocks_of_a_span_cover_the_same_interval(monkeypatch):
+    """A parent of many short children must not read more CPU than wall
+    seconds (nor a child fewer) for the clock reads themselves: on a
+    thread that is always on a core every span reads 100%, unclipped."""
+    import types
+
+    clock = _CostlyCpuClock()
+    trace.enable(True)
+    monkeypatch.setattr(trace, "time", types.SimpleNamespace(
+        **{**vars(time), "perf_counter": clock.perf_counter,
+           "thread_time": clock.thread_time}))
+    with trace.span("parent"):
+        clock.now += 10.0
+        for _ in range(50):
+            with trace.span("child"):
+                clock.now += 1.0
+    monkeypatch.undo()
+    parent = _one("parent")
+    children = [s for s in trace.spans() if s[0] == "child"]
+    assert len(children) == 50
+    for rec in (parent, *children):
+        assert rec[5] > 0 and rec[11] == pytest.approx(rec[5]), rec
+    # a child holds its work and one clock read, the parent the other
+    assert children[0][5] == pytest.approx(1.0 + 6.0)
+    assert parent[5] == pytest.approx(10.0 + 6.0 + 50 * 6.0)
+
+
+def test_instant_and_complete_records_carry_no_cpu_clock():
+    trace.enable(True)
+    trace.instant("retry", attempt=1)
+    trace.complete("queue_wait", time.perf_counter() - 1.0, 1.0)
+    for name in ("retry", "queue_wait"):
+        rec = _one(name)
+        assert len(rec) == 12 and rec[11] is None
+
+
+def test_summary_has_a_cpu_column_beside_self_seconds():
+    trace.enable(True)
+    with trace.span("transform"):
+        _spin_cpu(0.03)
+    with trace.span("sink_push"):
+        time.sleep(0.03)
+    trace.complete("queue_wait", time.perf_counter() - 1.0, 1.0)
+    s = trace.stage_summary()
+    assert s["stages"]["transform"]["cpu_s"] >= 0.025
+    assert s["stages"]["sink_push"]["cpu_s"] < 0.01
+    assert s["stages"]["sink_push"]["self_s"] >= 0.03
+    # a wait recorded once it ended ran on no thread
+    assert s["waits"]["queue_wait"]["cpu_s"] is None
+    rows = trace.format_summary().splitlines()
+    head = rows[1].split()
+    assert head[head.index("self_s") + 1] == "cpu_s"
+    col = head.index("cpu_s")
+    by_name = {ln.split()[0]: ln.split() for ln in rows[2:5]}
+    assert float(by_name["transform"][col]) >= 0.02
+    assert by_name["~queue_wait"][col] == "-"
+
+
+def test_chrome_export_carries_the_thread_clock_duration():
+    trace.enable(True)
+    with trace.span("part"):
+        with trace.span("transform"):
+            _spin_cpu(0.03)
+        time.sleep(0.02)
+    trace.instant("xla_compile", seconds=0.5)
+    trace.complete("queue_wait", time.perf_counter() - 1.0, 1.0)
+    doc = json.loads(json.dumps(trace.export_chrome_trace()))
+    by_name = {e["name"]: e for e in doc["traceEvents"]
+               if e["ph"] in ("X", "i")}
+    part, tf = by_name["part"], by_name["transform"]
+    assert tf["tdur"] >= 25_000
+    # a slice's CPU time holds its children's, as its `dur` does
+    assert tf["tdur"] <= part["tdur"] <= part["dur"] - 15_000
+    assert "tdur" not in by_name["xla_compile"]
+    assert "tdur" not in by_name["queue_wait"]
+
+
+def test_the_cpu_clock_is_not_read_when_tracing_is_off(monkeypatch):
+    def boom():
+        raise AssertionError("thread_time read with tracing off")
+
+    monkeypatch.setattr(time, "thread_time", boom)
+    sp = trace.span("hot")
+    assert sp is trace.span("other") and not sp
+    with sp:
+        sp.add(rows=1)
+    trace.instant("x")
+    trace.complete("queue_wait", 0.0, 1.0)
+    assert trace.spans() == []
+
+
+def test_a_platform_without_the_clock_records_none(monkeypatch):
+    monkeypatch.setattr(trace, "_HAS_THREAD_TIME", False)
+    trace.enable(True)
+    with trace.span("parent"):
+        with trace.span("child"):
+            pass
+    assert _one("parent")[11] is None and _one("child")[11] is None
+    assert trace.stage_summary()["stages"]["parent"]["cpu_s"] is None
+
+
+# -- the poll thread's wait for a free slot ----------------------------------
+
+class _HoldingSink:
+    """Hands out futures that resolve when the test says so."""
+
+    def __init__(self):
+        self.futures = []
+
+    def async_push(self, batch):
+        import concurrent.futures
+
+        fut = concurrent.futures.Future()
+        self.futures.append(fut)
+        return fut
+
+    def close(self):
+        pass
+
+
+def test_a_full_window_records_one_inflight_wait_as_long_as_the_hold():
+    from transferia_tpu.parsequeue.queue import ParseQueue
+
+    trace.enable(True)
+    sink = _HoldingSink()
+    q = ParseQueue(1, sink, parse_fn=tuple, ack_fn=lambda raw, err: None,
+                   max_inflight=1)
+    q.add([1])
+    deadline = time.time() + 5.0
+    while not sink.futures and time.time() < deadline:
+        time.sleep(0.005)
+    hold = 0.08
+    threading.Timer(hold, lambda: sink.futures[0].set_result(None)).start()
+    t0 = time.perf_counter()
+    q.add([2])       # no slot until the first unit is acked
+    blocked = time.perf_counter() - t0
+    while len(sink.futures) < 2 and time.time() < deadline:
+        time.sleep(0.005)
+    sink.futures[1].set_result(None)
+    q.wait()
+    q.close()
+    rec = _one("inflight_wait")
+    assert 0 <= rec[6] < trace.WAIT_DEPTH     # a thread was blocked there
+    assert hold * 0.8 <= rec[4] <= blocked + 0.001
+    assert rec[11] < 0.02                      # and did not work
+
+
+def test_a_free_slot_records_no_inflight_wait():
+    trace.enable(True)
+    _run_parsequeue(4, seconds=0.0)
+    assert [s for s in trace.spans() if s[0] == "inflight_wait"] == []
+    assert len([s for s in trace.spans() if s[0] == "queue_wait"]) == 4

@@ -6,7 +6,9 @@ gRPC metadata hop, and the shm framing-metadata hop.
 
 Recorded tuple layout (trace.spans()):
   (name, tid, tname, t0, dur, self, depth, args,
-   trace_id, span_id, parent_id)
+   trace_id, span_id, parent_id, self_cpu)
+self_cpu: of `self`, the seconds on the thread's CPU clock; None for
+instants, complete() records and platforms without the clock.
 """
 
 from __future__ import annotations

@@ -84,7 +84,11 @@ class ParseQueue(Generic[T]):
             raise self._failure
         if self._closed:
             raise RuntimeError("parsequeue closed")
-        self._inflight.acquire()
+        if not self._inflight.acquire(blocking=False):
+            # max_inflight units are unacked: the caller (a source's
+            # poll thread) waits here for the ack stage
+            with trace.span("inflight_wait"):
+                self._inflight.acquire()
         parse_fut = self._pool.submit(self._safe_parse, raw)
         # the enqueue time rides the tuple: the wait between here and
         # the push stage taking the item is staleness the program adds

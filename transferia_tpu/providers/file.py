@@ -296,11 +296,14 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         if node is None or rb.num_rows == 0:
             return rb
         from transferia_tpu.predicate.arroweval import eval_mask
+        from transferia_tpu.stats import trace
 
-        mask = eval_mask(node, rb)
-        if mask is None:
-            return rb
-        filtered = rb.filter(mask)  # null mask entries drop (SQL 3VL)
+        # on the part thread, between the decode and the `batch` it feeds
+        with trace.span("scan_filter"):
+            mask = eval_mask(node, rb)
+            if mask is None:
+                return rb
+            filtered = rb.filter(mask)  # null mask entries drop (SQL 3VL)
         self._count_pruned(rb.num_rows - filtered.num_rows)
         return filtered
 
@@ -350,15 +353,19 @@ class FileStorage(Storage, ShardingStorage, ScanPredicateStorage):
         if node is None or batch.n_rows == 0:
             return batch
         from transferia_tpu.predicate.compile import compile_mask
+        from transferia_tpu.stats import trace
 
         fn = self._pred_fns.get(tid)
         if fn is None:
             fn = compile_mask(node)
             self._pred_fns[tid] = fn
-        keep = fn(batch)
-        if keep.all():
-            return batch
-        out = batch.filter(keep)
+        # on the part thread, between the decode and the `batch` it feeds:
+        # the mask and the gather of every column the batch has
+        with trace.span("scan_filter"):
+            keep = fn(batch)
+            if keep.all():
+                return batch
+            out = batch.filter(keep)
         self._count_pruned(batch.n_rows - out.n_rows)
         return out
 

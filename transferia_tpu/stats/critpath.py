@@ -65,6 +65,9 @@ _EXACT = {
     "dict_adopt": "device dispatch",
     "rowhash_pool_accs": "device dispatch",
     "sink_wait": "queue wait",
+    "inflight_wait": "queue wait",
+    "push_backpressure": "queue wait",
+    "part_drain": "queue wait",
     "fleet_queue_wait": "queue wait",
     "replication_pump": "queue wait",
     "serialize": "wire",
@@ -79,6 +82,9 @@ _EXACT = {
     "bufferer_flush": "publish",
     "s3_publish_copy": "publish",
     "ch_publish_partition": "publish",
+    "part_open": "publish",
+    "part_close": "publish",
+    "part_commit": "commit",
     "coord_commit_part": "commit",
 }
 

@@ -154,9 +154,7 @@ class ObsExporter:
                 args = rec[7]
                 if args is not None:
                     args = {k: trace._jsonable(v) for k, v in args.items()}
-                recorded.append([rec[0], rec[1], rec[2], rec[3], rec[4],
-                                 rec[5], rec[6], args, rec[8], rec[9],
-                                 rec[10]])
+                recorded.append([*rec[:7], args, *rec[8:]])
         ledger_snap = LEDGER.snapshot()
         seg = {
             "v": SEGMENT_VERSION,

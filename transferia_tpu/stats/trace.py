@@ -20,6 +20,9 @@ Design constraints:
   attribution need no lock), one lock only around ring appends;
 - monotonic clocks (`time.perf_counter`), microsecond timestamps
   relative to the capture epoch (what the trace-event format expects);
+  beside it the thread's CPU clock (`time.thread_time`), so a span says
+  how much of its self time its thread was on a core - the rest it
+  waited: for the GIL, a lock, a socket, a core (enabled path only);
 - bounded memory: a `deque(maxlen=capacity)` ring — a forgotten-enabled
   tracer on a long replication run costs a fixed buffer, never OOM.
 
@@ -37,7 +40,22 @@ providers/s3readers.py::read_json_lines.
 (providers/readahead.py) — decode running there shows as its own
 track, overlapping the part's downstream spans.  Waits that are known
 only once they end (`queue_wait`, `decode_wait`) are recorded with
-`complete()`, which takes self time from no parent.
+`complete()`, which takes self time from no parent.  Where a thread
+blocks, the wait is a real span, so that it takes its time out of its
+parent and names the idle gap it covers: `sink_wait` (the parsequeue's
+stages on the sink), `inflight_wait` (`ParseQueue.add` with
+`max_inflight` units unacked: the poll thread), and in
+tasks/snapshot.py the part thread's `part_open`, `push_backpressure`
+(under `batch`), `part_drain`, `part_close` (`phase` `done`, and
+`close` after `part` has ended), `part_commit` under `part`, and the
+worker thread's `part_claim` and `part_report` between parts; the file
+source's pushed-down predicate is `scan_filter` (providers/file.py):
+what is left of `part` and `batch` self time is the source iterator's
+own work.
+
+Recorded tuple: (name, tid, tname, t0_s, dur_s, self_s, depth, args,
+trace_id, span_id, parent_id, self_cpu_s); consumers index it or slice
+it, so a field is only ever added at the end.
 
 While tracing is on every span also enters a
 `jax.profiler.TraceAnnotation` of the same name, so a `jax.profiler`
@@ -93,6 +111,8 @@ _recorded = 0
 # jax.  Entered by every Span beside its own clock (enabled path only).
 _annotation = None
 _annotation_tried = False
+# CLOCK_THREAD_CPUTIME_ID; a platform without it records None
+_HAS_THREAD_TIME = hasattr(time, "thread_time")
 
 
 class SpanContext(NamedTuple):
@@ -145,7 +165,7 @@ _NOOP = _NoopSpan()
 
 
 class Span:
-    __slots__ = ("name", "args", "_t0", "_child",
+    __slots__ = ("name", "args", "_t0", "_child", "_c0", "_child_cpu",
                  "trace_id", "span_id", "parent_id", "_token", "_ann")
 
     def __init__(self, name: str, args: Optional[dict] = None):
@@ -153,6 +173,8 @@ class Span:
         self.args = args
         self._t0 = 0.0
         self._child = 0.0  # seconds covered by nested spans
+        self._c0 = None    # the thread's CPU clock at entry
+        self._child_cpu = 0.0  # CPU seconds of nested spans
         self.trace_id = 0
         self.span_id = 0
         self.parent_id = 0
@@ -189,10 +211,17 @@ class Span:
         if _annotation is not None:
             self._ann = _annotation(self.name)
             self._ann.__enter__()
+        # the CPU clock first at both ends: a read of it is a system
+        # call, and so each clock's interval holds one of the two reads -
+        # the other falls to the parent, on both clocks alike
+        if _HAS_THREAD_TIME:
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        cpu = time.thread_time() - self._c0 \
+            if self._c0 is not None else None
         t1 = time.perf_counter()
         dur = t1 - self._t0
         if self._ann is not None:
@@ -206,6 +235,8 @@ class Span:
         depth = len(stack)
         if depth:
             stack[-1]._child += dur
+            if cpu is not None:
+                stack[-1]._child_cpu += cpu
         t = threading.current_thread()
         global _recorded
         with _lock:
@@ -215,6 +246,8 @@ class Span:
                 self._t0 - _epoch, dur, max(0.0, dur - self._child),
                 depth, self.args,
                 self.trace_id, self.span_id, self.parent_id,
+                None if cpu is None
+                else max(0.0, cpu - self._child_cpu),
             ))
         return False
 
@@ -285,7 +318,7 @@ def instant(name: str, ctx: Optional[SpanContext] = None,
         _recorded += 1
         _ring.append((name, t.ident, t.name,
                       time.perf_counter() - _epoch, 0.0, 0.0, -1,
-                      args or None, trace_id, 0, parent_id))
+                      args or None, trace_id, 0, parent_id, None))
 
 
 # depth of a complete() record: it sat on no thread's stack.  >= 0 so
@@ -315,7 +348,7 @@ def complete(name: str, t0: float, dur: float,
         _recorded += 1
         _ring.append((name, t.ident, t.name, t0 - _epoch, dur,
                       dur, WAIT_DEPTH, args or None, trace_id, span_id,
-                      parent_id))
+                      parent_id, None))
 
 
 def current() -> Optional[str]:
@@ -387,8 +420,11 @@ def parse_wire(s) -> Optional[SpanContext]:
 
 def spans() -> list[tuple]:
     """Raw recorded tuples (name, tid, tname, t0_s, dur_s, self_s,
-    depth, args, trace_id, span_id, parent_id) — depth -1 marks
-    instants (span_id 0, parent_id = the span they fired on)."""
+    depth, args, trace_id, span_id, parent_id, self_cpu_s) — depth -1
+    marks instants (span_id 0, parent_id = the span they fired on).
+    `self_cpu_s` is what of `self_s` the thread spent on a core
+    (`time.thread_time`); None for instants, for `complete()` records
+    and where the platform has no such clock."""
     with _lock:
         return list(_ring)
 
@@ -430,15 +466,20 @@ def export_chrome_trace() -> dict:
     events ("s"/"f" pairs keyed by the child span id) for every
     parent→child link that crosses a thread — the arrows that stitch a
     readahead worker's decode, a fleet lane's run, and a Flight
-    server-side span onto the submitting timeline."""
+    server-side span onto the submitting timeline.  A span that has the
+    thread's CPU clock carries `tdur`, its CPU time with its children's
+    (no `tts`: the clock's value at the start is not kept)."""
     recorded = spans()
     events: list[dict] = []
     seen_threads: dict[int, str] = {}
     # span_id -> (tid, ts_us) for flow-arrow sources
     located: dict[int, tuple[int, float]] = {}
+    # (parent span_id, tid) -> CPU seconds of the children recorded so
+    # far on that thread: a child is recorded before its parent
+    child_cpu: dict[tuple[int, int], float] = {}
     for rec in recorded:
         name, tid, tname, t0, dur, _self_s, depth, args = rec[:8]
-        trace_id, span_id, parent_id = rec[8:11]
+        trace_id, span_id, parent_id, self_cpu = rec[8:12]
         if tid not in seen_threads:
             seen_threads[tid] = tname
         ts = round(t0 * 1e6, 1)
@@ -457,6 +498,12 @@ def export_chrome_trace() -> dict:
             ev["dur"] = round(dur * 1e6, 1)
             if span_id:
                 located[span_id] = (tid, ts)
+            if self_cpu is not None:
+                cpu = self_cpu + child_cpu.pop((span_id, tid), 0.0)
+                ev["tdur"] = round(cpu * 1e6, 1)
+                if parent_id:
+                    child_cpu[(parent_id, tid)] = \
+                        child_cpu.get((parent_id, tid), 0.0) + cpu
         if args:
             ev["args"] = {k: _jsonable(v) for k, v in args.items()}
         if trace_id:
@@ -516,6 +563,9 @@ def write_chrome_trace(path: str) -> int:
 
 def stage_summary(wall_seconds: Optional[float] = None) -> dict:
     """Per-stage aggregation: calls, p50/p99 ms, total and self seconds,
+    `cpu_s` (of the self seconds, those the thread spent on a core: the
+    rest it waited - for the GIL, a lock, a socket, a core; None where no
+    record of the stage has the clock),
     bytes moved (summed from span `bytes` args), plus wall span and the
     overlap factor (sum of self-times / wall — >1 means stages overlap
     across threads; the ratio between stages is the signal).  Records
@@ -525,16 +575,18 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
     per: dict[str, dict] = {}
     wait_names = set()
     t_min, t_max = None, None
-    for name, _tid, _tn, t0, dur, self_s, depth, args in (
-            s[:8] for s in recorded):
+    for rec in recorded:
+        name, _tid, _tn, t0, dur, self_s, depth, args = rec[:8]
         d = per.setdefault(name, {"calls": 0, "total_s": 0.0,
-                                  "self_s": 0.0, "bytes": 0,
-                                  "durs": []})
+                                  "self_s": 0.0, "cpu_s": None,
+                                  "bytes": 0, "durs": []})
         if depth == WAIT_DEPTH:
             wait_names.add(name)
         d["calls"] += 1
         d["total_s"] += dur
         d["self_s"] += self_s
+        if rec[11] is not None:
+            d["cpu_s"] = (d["cpu_s"] or 0.0) + rec[11]
         d["durs"].append(dur)
         if args and isinstance(args.get("bytes"), (int, float)):
             d["bytes"] += int(args["bytes"])
@@ -550,6 +602,8 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
             durs[max(0, min(n - 1, int(0.99 * n)))] * 1000, 3)
         d["total_s"] = round(d["total_s"], 4)
         d["self_s"] = round(d["self_s"], 4)
+        if d["cpu_s"] is not None:
+            d["cpu_s"] = round(d["cpu_s"], 4)
 
     def largest_first(names) -> dict:
         return dict(sorted(((n, per[n]) for n in names),
@@ -567,19 +621,21 @@ def stage_summary(wall_seconds: Optional[float] = None) -> dict:
 
 def format_summary(wall_seconds: Optional[float] = None) -> str:
     """Human table for `trtpu trace`: stages by self time, largest
-    first, then the waits (`~name`), then the device counters."""
+    first (`cpu_s`: what of `self_s` the thread was on a core), then the
+    waits (`~name`), then the device counters."""
     s = stage_summary(wall_seconds)
     lines = [
         f"wall={s['wall_s']:.2f}s overlap_factor={s['overlap_factor']}",
         f"{'stage':<18} {'calls':>7} {'p50_ms':>9} {'p99_ms':>9} "
-        f"{'total_s':>8} {'self_s':>8} {'bytes':>12}",
+        f"{'total_s':>8} {'self_s':>8} {'cpu_s':>8} {'bytes':>12}",
     ]
     for name, d in (*s["stages"].items(),
                     *((f"~{n}", d) for n, d in s["waits"].items())):
+        cpu = "-" if d["cpu_s"] is None else f"{d['cpu_s']:.2f}"
         lines.append(
             f"{name:<18} {d['calls']:>7} {d['p50_ms']:>9.2f} "
             f"{d['p99_ms']:>9.2f} {d['total_s']:>8.2f} "
-            f"{d['self_s']:>8.2f} {d['bytes']:>12}")
+            f"{d['self_s']:>8.2f} {cpu:>8} {d['bytes']:>12}")
     if s["waits"]:
         lines.append("~ a wait recorded once it ended: "
                      "in no stage share, not in overlap_factor")
@@ -830,13 +886,11 @@ class DeviceTelemetry:
             self.jsonl_bytes = 0
             # what the whole process (every thread) spent between the
             # opening and the closing of its snapshot operations
-            # (tasks/snapshot.py, getrusage): CPU time, of it system
-            # time, and page faults served without I/O - an allocator
-            # that goes to the kernel for each object shows as system
-            # time and faults
+            # (tasks/snapshot.py, getrusage): CPU time and of it system
+            # time - an allocator that goes to the kernel for each
+            # object shows as system time
             self.proc_cpu_ms = 0.0
             self.proc_cpu_sys_ms = 0.0
-            self.proc_minor_faults = 0
             # per-target fold baselines: several pipelines may each
             # fold the (process-global) counters into their own
             # Metrics; one shared baseline would split deltas between
@@ -966,7 +1020,6 @@ class DeviceTelemetry:
             self.proc_cpu_ms += (after.ru_utime - before.ru_utime) * 1e3 \
                 + sys_ms
             self.proc_cpu_sys_ms += sys_ms
-            self.proc_minor_faults += after.ru_minflt - before.ru_minflt
 
     def record_chain_untouched(self) -> None:
         with self._lock:
@@ -1037,7 +1090,6 @@ class DeviceTelemetry:
                 "jsonl_bytes": self.jsonl_bytes,
                 "proc_cpu_ms": round(self.proc_cpu_ms, 3),
                 "proc_cpu_sys_ms": round(self.proc_cpu_sys_ms, 3),
-                "proc_minor_faults": self.proc_minor_faults,
             }
 
     def fold_into(self, metrics) -> None:

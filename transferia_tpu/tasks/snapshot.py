@@ -810,23 +810,26 @@ class SnapshotLoader:
                             f"part boundary ({done} part(s) committed "
                             f"by this worker)"))
                     return
-                part = self.cp.assign_operation_part(
-                    self.operation_id, self.worker_index
-                )
-                if part is None:
-                    if not discovery_done[0]:
-                        if not self._discovery_open():
-                            discovery_done[0] = True
-                            continue  # drain race: one last assign pass
-                        # async discovery still streaming parts in;
-                        # back off so a slow listing doesn't turn N
-                        # drained workers into a coordinator hot loop
-                        time.sleep(idle_sleep)
-                        idle_sleep = min(1.0, idle_sleep * 2)
-                        continue
-                    if linger_wait():
-                        continue
-                    return
+                # between two parts a worker thread is here: the claim
+                # and, where none came back, the back-off
+                with trace.span("part_claim"):
+                    part = self.cp.assign_operation_part(
+                        self.operation_id, self.worker_index
+                    )
+                    if part is None:
+                        if not discovery_done[0]:
+                            if not self._discovery_open():
+                                discovery_done[0] = True
+                                continue  # drain race: one last assign
+                            # async discovery still streaming parts in;
+                            # back off so a slow listing doesn't turn N
+                            # drained workers into a coordinator hot loop
+                            time.sleep(idle_sleep)
+                            idle_sleep = min(1.0, idle_sleep * 2)
+                            continue
+                        if linger_wait():
+                            continue
+                        return
                 idle_sleep = 0.05
                 if part.stolen_from is not None:
                     self.lease_stats.steals.inc()
@@ -1004,14 +1007,21 @@ class SnapshotLoader:
         futures: deque = deque()
         try:
             with part_sp, LEDGER.context(part=part.key()):
-                if staged is not None:
-                    # a retried part restages from scratch: begin
-                    # REPLACES anything a previous attempt staged
-                    staged.begin_part(part.key(), part.assignment_epoch)
-                    self.commit_stats.staged_parts.inc()
-                sink.async_push(
-                    [init_table_load(tid, schema, part_id)]
-                ).result()
+                # the part thread's waits on the sink, the source and the
+                # coordinator are spans of their own (part_open,
+                # push_backpressure, part_drain, part_close, part_commit):
+                # what is left of `part` and `batch` self time is the
+                # source iterator's own work
+                with trace.span("part_open"):
+                    if staged is not None:
+                        # a retried part restages from scratch: begin
+                        # REPLACES anything a previous attempt staged
+                        staged.begin_part(part.key(),
+                                          part.assignment_epoch)
+                        self.commit_stats.staged_parts.inc()
+                    sink.async_push(
+                        [init_table_load(tid, schema, part_id)]
+                    ).result()
 
                 def pusher(batch):
                     nonlocal rows_done, read_bytes, batch_seq
@@ -1041,22 +1051,35 @@ class SnapshotLoader:
                                        batch_seq=batch_seq,
                                        rows=len(batch))
                         batch_seq += 1
-                        futures.append(sink.async_push(batch))
-                        # bounded in-flight window (deque: the window
-                        # slides O(1) per batch, not O(n) list shifts)
-                        while len(futures) > 32:
-                            futures.popleft().result()
+                        # the hand-over blocks in the sink's throttler
+                        # and the bufferer's lock, the window on the
+                        # oldest push
+                        bp = trace.span("push_backpressure")
+                        with bp:
+                            inflight = len(futures)
+                            futures.append(sink.async_push(batch))
+                            # bounded in-flight window (deque: the window
+                            # slides O(1) per batch, not O(n) list shifts)
+                            while len(futures) > 32:
+                                futures.popleft().result()
+                            if bp:
+                                bp.add(inflight=inflight,
+                                       cause="window" if inflight >= 32
+                                       else "push")
 
                 storage.load_table(part.to_description(), pusher)
-                resolve_all(futures)
-                sink.async_push(
-                    [done_table_load(tid, schema, part_id)]
-                ).result()
+                with trace.span("part_drain"):
+                    resolve_all(futures)
+                with trace.span("part_close", phase="done"):
+                    sink.async_push(
+                        [done_table_load(tid, schema, part_id)]
+                    ).result()
                 if staged is not None:
                     # phase 2: the single fenced publish decision, then
                     # the staged data becomes visible (or is aborted)
-                    publish_fenced = not self._commit_and_publish(
-                        staged, part)
+                    with trace.span("part_commit"):
+                        publish_fenced = not self._commit_and_publish(
+                            staged, part)
         except BaseException as e:
             if staged is not None:
                 # discard this attempt's staging; a retry re-begins
@@ -1074,18 +1097,21 @@ class SnapshotLoader:
             # drain/cancel in-flight pushes BEFORE close: on a pusher
             # error, close() must not race pushes still running in the
             # sink's executor (a torn close can double-land a batch)
-            while futures:
-                f = futures.popleft()
-                if not f.cancel():
-                    try:
-                        f.result(timeout=60.0)
-                    # deliberate swallow: this is the error path's drain —
-                    # the first failure is already propagating as
-                    # TableUploadError above; secondary push errors here
-                    # would only mask it
-                    except Exception:  # trtpu: ignore[EXC001]
-                        pass
-            sink.close()
+            # `part` has ended by now: the close (the asynchronizer's
+            # joins its worker thread) is the second `part_close`
+            with trace.span("part_close", phase="close"):
+                while futures:
+                    f = futures.popleft()
+                    if not f.cancel():
+                        try:
+                            f.result(timeout=60.0)
+                        # deliberate swallow: this is the error path's
+                        # drain — the first failure is already propagating
+                        # as TableUploadError above; secondary push errors
+                        # here would only mask it
+                        except Exception:  # trtpu: ignore[EXC001]
+                            pass
+                sink.close()
         if publish_fenced:
             # staged-commit fence: the part was reclaimed since our
             # claim (or our publish lost to a newer epoch at the sink).
@@ -1124,34 +1150,35 @@ class SnapshotLoader:
                 part.fingerprint = _json.dumps(
                     {out.fqtn(): a.digest() for out, a in aggs.items()},
                     sort_keys=True)
-        with self._progress_lock:
-            rejected = self.cp.update_operation_parts(
-                self.operation_id, [part])
-            if not rejected:
-                self.table_stats.completed_parts.inc()
-                self.table_stats.completed_rows.inc(rows_done)
-                self._local_parts_done += 1
-                self._local_rows_done += rows_done
-        if rejected:
-            # epoch fence: our lease expired mid-part and the part was
-            # reclaimed — the new owner's completion is authoritative,
-            # our rows are at-least-once duplicates.  Do NOT fail the
-            # worker: drop the stale result and claim the next part
-            # (which re-leases us).
-            self.lease_stats.fence_rejected.inc(len(rejected))
-            logger.warning(
-                "part %s completion fenced (stale epoch %d): lease "
-                "expired and the part was reclaimed; dropping result",
-                part.key(), part.assignment_epoch)
-            return
-        # device counters surface on this pipeline's metrics as parts
-        # complete (H2D/D2H bytes, launches, XLA compiles) — the
-        # attribution ledger folds alongside so the ledger_* series
-        # track the same cadence
-        trace.TELEMETRY.fold_into(self.metrics)
-        LEDGER.fold_into(self.metrics)
-        # part completion is an export trigger (coalesced inside the
-        # exporter): the committed part's spend is durable immediately
-        self._obs.export("part")
+        with trace.span("part_report"):
+            with self._progress_lock:
+                rejected = self.cp.update_operation_parts(
+                    self.operation_id, [part])
+                if not rejected:
+                    self.table_stats.completed_parts.inc()
+                    self.table_stats.completed_rows.inc(rows_done)
+                    self._local_parts_done += 1
+                    self._local_rows_done += rows_done
+            if rejected:
+                # epoch fence: our lease expired mid-part and the part
+                # was reclaimed — the new owner's completion is
+                # authoritative, our rows are at-least-once duplicates.
+                # Do NOT fail the worker: drop the stale result and claim
+                # the next part (which re-leases us).
+                self.lease_stats.fence_rejected.inc(len(rejected))
+                logger.warning(
+                    "part %s completion fenced (stale epoch %d): lease "
+                    "expired and the part was reclaimed; dropping result",
+                    part.key(), part.assignment_epoch)
+                return
+            # device counters surface on this pipeline's metrics as parts
+            # complete (H2D/D2H bytes, launches, XLA compiles) — the
+            # attribution ledger folds alongside so the ledger_* series
+            # track the same cadence
+            trace.TELEMETRY.fold_into(self.metrics)
+            LEDGER.fold_into(self.metrics)
+            # part completion is an export trigger (coalesced inside the
+            # exporter): the committed part's spend is durable immediately
+            self._obs.export("part")
         logger.info("part %s done: %d rows, %d bytes",
                     part.key(), rows_done, read_bytes)
