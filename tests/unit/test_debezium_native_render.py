@@ -25,6 +25,7 @@ from transferia_tpu.abstract.schema import (
 )
 from transferia_tpu.columnar.batch import Column, ColumnBatch
 from transferia_tpu.debezium.emitter import DebeziumEmitter
+from transferia_tpu.serializers.formats import MessageBlock
 from transferia_tpu.stats import trace
 from transferia_tpu.typesystem.rules import map_source_type
 
@@ -47,10 +48,20 @@ def emitter(**cfg):
     return DebeziumEmitter(**cfg)
 
 
+def columnar(em, batch, snapshot):
+    """(pairs, path) of the columnar renderers, the native path's block
+    cut into its pairs; None where neither takes the batch."""
+    out = em._emit_columnar(batch, snapshot)
+    if out is None or out[1] != "native":
+        return out
+    assert isinstance(out[0], MessageBlock)
+    return out[0].pairs(), out[1]
+
+
 def taken(batch, snapshot=True, **cfg):
     """(pairs, path) of the columnar renderers, the native one first."""
     assert native.lib() is not None
-    out = emitter(**cfg)._emit_columnar(batch, snapshot)
+    out = columnar(emitter(**cfg), batch, snapshot)
     assert out is not None
     return out
 
@@ -62,7 +73,7 @@ def python_pairs(batch, snapshot=True, **cfg):
         mp.setattr(native, "_lib", None)
         mp.setenv("TRANSFERIA_TPU_NO_NATIVE", "1")
         assert native.lib() is None
-        pairs, path = emitter(**cfg)._emit_columnar(batch, snapshot)
+        pairs, path = columnar(emitter(**cfg), batch, snapshot)
     assert path == "fast"
     return pairs
 
@@ -144,16 +155,35 @@ def test_tpcc_shapes_render_the_python_bytes(table, include_schema,
         assert {k for k, _ in pairs} == {None}
 
 
-@pytest.mark.parametrize("slab_bytes", [1, 3000, 20000, 1 << 30])
-def test_a_batch_goes_through_the_buffer_a_slab_of_whole_rows_at_a_time(
-        monkeypatch, slab_bytes):
-    # rows longer than the slab, a few rows a slab, the batch in one slab
-    from transferia_tpu.debezium import emitter as emitter_module
+_BLOCK_CASES = {
+    "customer": (lambda: tpcc_batch(TPCC[2], n=200), True),
+    "stock": (lambda: tpcc_batch(TPCC[3], n=200), False),
+    "text": (lambda: text_batch(ADVERSARIAL), False),
+    "text_keyless": (lambda: text_batch(ADVERSARIAL, keyed=False), False),
+}
 
-    monkeypatch.setattr(emitter_module, "_SLAB_BYTES", slab_bytes)
-    same(tpcc_batch(TPCC[2], n=200))
-    same(tpcc_batch(TPCC[3], n=200))
-    same(text_batch(ADVERSARIAL), include_schema=False)
+
+@pytest.mark.parametrize("case", list(_BLOCK_CASES))
+def test_a_batch_renders_into_one_block_the_pairs_are_cut_from(case):
+    # the values, and the keys, end to end in one buffer each - what the
+    # Kafka sink frames as they are, and emit_batch cuts
+    make, include_schema = _BLOCK_CASES[case]
+    batch = make()
+    pairs = same(batch, include_schema=include_schema)
+    em = emitter(include_schema=include_schema)
+    b = em.emit_block(batch, snapshot=True)
+    assert isinstance(b, MessageBlock) and b.n == batch.n_rows
+    assert b.values == b"".join(v for _, v in pairs)
+    assert b.value_offsets.dtype == np.int64
+    assert b.value_offsets.tolist() == [0, *np.cumsum(
+        [len(v) for _, v in pairs]).tolist()]
+    assert b.key_null is None and b.value_null is None
+    if not batch.schema.key_columns():
+        assert b.keys is None and b.key_offsets is None
+    else:
+        assert b.keys == b"".join(k for k, _ in pairs)
+    assert b.pairs() == pairs
+    assert em.emit_batch(batch, snapshot=True) == pairs
 
 
 # -- text --------------------------------------------------------------------
@@ -262,7 +292,7 @@ def test_random_bytes_are_utf8_exactly_where_pythons_decoder_says():
             "v", CanonicalType.UTF8,
             np.frombuffer(raw + b"tail", dtype=np.uint8),
             np.array([0, len(raw), len(raw) + 4], dtype=np.int32))
-        pairs, path = em._emit_columnar(batch, True)
+        pairs, path = columnar(em, batch, True)
         assert (path == "native") == (text is not None), raw
         if text is not None:
             well_formed += 1
@@ -488,7 +518,7 @@ def test_offsets_that_do_not_fit_are_an_error_not_a_read():
 def test_four_threads_render_the_single_threaded_bytes():
     batches = [tpcc_batch(TPCC[i], n=3000, seed=i) for i in (2, 6, 8, 3)]
     em = emitter()
-    alone = [em._emit_columnar(b, True) for b in batches]
+    alone = [columnar(em, b, True) for b in batches]
     assert [path for _, path in alone] == ["native"] * 4
     got = [None] * 4
     start = threading.Barrier(4)

@@ -8,8 +8,9 @@ for queue sources.  Type fidelity follows Kafka Connect schema names
 (io.debezium.time.*, org.apache.kafka.connect.data.Decimal).
 
 The emitter renders a snapshot's insert-only ColumnBatch natively (its keys
-and its values written by native/hostops.cpp a slab of rows at a time, the
-GIL released), the same batch in Python where the library is switched off
+and its values written by native/hostops.cpp into one buffer each, the GIL
+released: a MessageBlock the Kafka sink frames as it is), the same batch
+in Python where the library is switched off
 or declines, as it does replication's inserts with their per-row lsn, commit
 time and txId, and anything else - updates, deletes, packers - row by row;
 emitter.py's docstring has the rules.

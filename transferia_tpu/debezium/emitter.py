@@ -18,15 +18,16 @@ and the `debezium_rows*` counters name; all three give the same bytes
     %s-templates, which are cut at their slots into constant pieces;
     native/hostops.cpp then walks the batch twice with the GIL released,
     so part threads render side by side: once for every message's size,
-    then writing the values, and the keys, a slab of rows at a time
-    through one buffer of _SLAB_BYTES.  It reads the
-    columns' own buffers: integers (DATE, DATETIME and TIMESTAMP scaled
-    with numpy first) as digits, UTF8 and DECIMAL text quoted exactly as
-    json.dumps quotes under ensure_ascii.  A column it cannot read that
-    way - FLOAT/DOUBLE, BOOLEAN, STRING, a `pg` or _SLOW_MYSQL original
-    type, a lazy dictionary - comes to it as the fragments the Python
-    renderer makes, to be copied.  The pairs are `bytes` cut from the
-    buffer slab by slab.
+    then writing the values, and the keys, into one buffer each.  It
+    reads the columns' own buffers: integers (DATE, DATETIME and
+    TIMESTAMP scaled with numpy first) as digits, UTF8 and DECIMAL text
+    quoted exactly as json.dumps quotes under ensure_ascii.  A column it
+    cannot read that way - FLOAT/DOUBLE, BOOLEAN, STRING, a `pg` or
+    _SLOW_MYSQL original type, a lazy dictionary - comes to it as the
+    fragments the Python renderer makes, to be copied.  The buffers and
+    their offsets are the batch's MessageBlock (serializers/formats.py),
+    which the Kafka sink frames as it is (emit_block); emit_batch cuts
+    its pairs from it.
 "fast"  The same batch rendered in Python, a str per cell and two `%` per
     row: what runs under TRANSFERIA_TPU_NO_NATIVE=1, for a batch of
     inserts with source metadata row by row (replication's, a few rows a
@@ -42,7 +43,6 @@ and the `debezium_rows*` counters name; all three give the same bytes
 from __future__ import annotations
 
 import base64
-import itertools
 import json
 import re
 import time
@@ -61,18 +61,11 @@ from transferia_tpu.debezium.types import (
     to_connect,
 )
 from transferia_tpu import native
+from transferia_tpu.serializers.formats import MessageBlock
 from transferia_tpu.stats import trace
 
 # column kinds of native/hostops.cpp's debezium_render_*
 _I64, _U64, _TEXT, _RAW = 0, 1, 2, 3
-# The native renderer writes a batch through one buffer of this size, a
-# slab of rows at a time, and the pairs are cut from it slab by slab.  A
-# batch's 100-225 MB as one buffer are written to memory, read back for
-# the cut and handed back to the kernel; 1 MiB stays in a core's cache and
-# is taken once.  Four threads of 30,000 CUSTOMER rows each on the chip's
-# host: 14.0 s with one buffer a batch, 8.1 s with 4 MiB, 6.3 s with 1 MiB
-# or 256 KiB (PERF.md section 6, PR 34).
-_SLAB_BYTES = 1 << 20
 
 
 def _digit_column(data: np.ndarray, validity) -> Optional[tuple]:
@@ -359,17 +352,30 @@ class DebeziumEmitter:
     def emit_batch(self, batch, snapshot: bool = False
                    ) -> list[tuple[Optional[bytes], Optional[bytes]]]:
         """ColumnBatch or row list -> envelope pairs, order-preserving."""
+        return self._emit(batch, snapshot, cut=True)
+
+    def emit_block(self, batch, snapshot: bool = False):
+        """The native path's messages as one MessageBlock, with no object
+        per row; the pairs of the path that took the batch where another
+        did (nothing renders twice)."""
+        return self._emit(batch, snapshot, cut=False)
+
+    def _emit(self, batch, snapshot: bool, cut: bool):
+        """Render in the `serialize` span; cut: pairs on every path, the
+        block cut here (the one place that cuts it)."""
         with trace.span("serialize", format="debezium") as sp:
             out, rows, path = self._emit_batch(batch, snapshot)
+            if cut and path == "native":
+                out = out.pairs()
             if sp:
                 sp.add(path=path, rows=rows)
         if rows:
             trace.TELEMETRY.record_debezium_rows(rows, path)
         return out
 
-    def _emit_batch(self, batch, snapshot: bool) -> tuple[list, int, str]:
-        """(pairs, rows rendered, the path that took them: "native",
-        "fast" or "row")."""
+    def _emit_batch(self, batch, snapshot: bool) -> tuple:
+        """(a MessageBlock or pairs, rows rendered, the path that took
+        them: "native" - the block -, "fast" or "row")."""
         items: Iterable[ChangeItem]
         if isinstance(batch, ColumnBatch):
             taken = self._emit_columnar(batch, snapshot)
@@ -495,12 +501,16 @@ class DebeziumEmitter:
         """The pairs of an insert-only JSON-mode batch from its columns;
         None defers to the per-row path."""
         taken = self._emit_columnar(batch, snapshot)
-        return None if taken is None else taken[0]
+        if taken is None:
+            return None
+        out, path = taken
+        return out.pairs() if path == "native" else out
 
     def _emit_columnar(self, batch: ColumnBatch, snapshot: bool
-                       ) -> Optional[tuple[list, str]]:
-        """(pairs, "native" or "fast") for a batch inside the envelope
-        (the module docstring says which batch takes which path)."""
+                       ) -> Optional[tuple]:
+        """(a MessageBlock, "native") or (pairs, "fast") for a batch
+        inside the envelope (the module docstring says which batch takes
+        which path)."""
         if self.value_packer is not None:
             return None
         schema = batch.schema
@@ -538,10 +548,10 @@ class DebeziumEmitter:
                 spec = None if cdll is None else _packed_fragments(frags)
             cols.append(spec)
         if cdll is not None and all(spec is not None for spec in cols):
-            pairs = self._render_native(cdll, batch, schema, names,
+            block = self._render_native(cdll, batch, schema, names,
                                         key_cols, cols, snapshot)
-            if pairs is not None:
-                return pairs, "native"
+            if block is not None:
+                return block, "native"
         for cs in schema:
             if cs.name not in frag_by_name:
                 frags = self._col_fragments(batch.columns[cs.name], cs)
@@ -571,16 +581,16 @@ class DebeziumEmitter:
 
     def _render_native(self, cdll, batch: ColumnBatch, schema, names,
                        key_cols, cols: list, snapshot: bool
-                       ) -> Optional[list]:
-        """The pairs of a batch with no source metadata of its own, cut
-        from the buffer that native/hostops.cpp fills a slab at a time
-        with the GIL released; None where it takes no part (a text cell
-        that is not UTF-8): the Python renderer decides."""
+                       ) -> Optional[MessageBlock]:
+        """The messages of a batch with no source metadata of its own as a
+        MessageBlock, its values and its keys written by
+        native/hostops.cpp into one buffer each with the GIL released;
+        None where it takes no part (a text cell that is not UTF-8): the
+        Python renderer decides."""
         n = batch.n_rows
         now_ms = int(time.time() * 1000)
         after_p, key_p, (head, mid, tail), src_p = self._templates(
             batch, schema, names, key_cols, snapshot)[-1]
-        value_slots = list(range(len(names)))
         # one source block for every row: ts_ms now, no lsn, no txId
         src_p = [src_p[0] + str(now_ms) + src_p[1] + "null"
                  + src_p[2] + "null" + src_p[3]]
@@ -617,11 +627,13 @@ class DebeziumEmitter:
                 validity[c] = valid.ctypes.data
                 held.append(valid)
 
-        def render(pieces: list, slots: list) -> Optional[list]:
+        def render(pieces: list, slots: list) -> Optional[tuple]:
+            """(buffer, every row's offset in it) of a message's constant
+            pieces and slots: a walk for the sizes, a walk that writes;
+            None: a cell is not UTF-8."""
             raw = [p.encode() for p in pieces]
             piece_off = np.zeros(len(raw) + 1, dtype=np.int64)
             np.cumsum([len(p) for p in raw], out=piece_off[1:])
-            joined = b"".join(raw)
             slot_cols = np.asarray(slots, dtype=np.int32)
             row_off = np.empty(n + 1, dtype=np.int64)
             total = cdll.debezium_render_size(
@@ -632,37 +644,27 @@ class DebeziumEmitter:
             if total < 0:
                 raise ValueError(
                     f"{batch.table_id}: a column's offsets decrease")
-            # a slab of whole rows at a time through one buffer (the
-            # longest row fits; one byte over, so that no cut is the
-            # whole buffer, which a slice would hand out uncopied)
-            room = max(_SLAB_BYTES, int(np.diff(row_off).max()))
-            slab = native.new_bytes(None, min(total, room) + 1)
-            out: list = []
-            lo = 0
-            while lo < n:
-                hi = int(np.searchsorted(row_off, row_off[lo] + room,
-                                         side="right")) - 1
-                written = cdll.debezium_render_write(
-                    lo, hi, kinds, data, offsets, validity, len(slots),
-                    slot_cols, joined, piece_off, slab)
-                cuts = (row_off[lo:hi + 1] - row_off[lo]).tolist()
-                if written != cuts[-1]:
-                    raise RuntimeError(
-                        f"debezium renderer wrote {written} of "
-                        f"{cuts[-1]} bytes")
-                out.extend(map(slab.__getitem__,
-                               map(slice, cuts, cuts[1:])))
-                lo = hi
-            return out
+            out = native.new_bytes(None, total)
+            written = cdll.debezium_render_write(
+                0, n, kinds, data, offsets, validity, len(slots),
+                slot_cols, b"".join(raw), piece_off, out)
+            if written != total:
+                raise RuntimeError(
+                    f"debezium renderer wrote {written} of {total} bytes")
+            return out, row_off
 
-        values = render(value_p, value_slots)
+        values = render(value_p, list(range(len(names))))
         if values is None:
             return None
-        if not key_cols:
-            # no primary key: a null message key (emit_item's rule)
-            return list(zip(itertools.repeat(None), values))
-        keys = render(key_p, [names.index(c.name) for c in key_cols])
-        return None if keys is None else list(zip(keys, values))
+        block = MessageBlock(n, *values)
+        if key_cols:
+            keys = render(key_p, [names.index(c.name) for c in key_cols])
+            if keys is None:
+                return None
+            block.keys, block.key_offsets = keys
+        # no primary key: no keys, every message key null (emit_item's
+        # rule)
+        return block
 
     def _templates(self, batch: ColumnBatch, schema, names, key_cols,
                    snapshot: bool) -> tuple:

@@ -149,6 +149,16 @@ def _bind(cdll: ctypes.CDLL) -> ctypes.CDLL:
         ctypes.c_void_p, ctypes.c_int64, u8, ctypes.c_int64,
     ]
     cdll.kafka_encode_records.restype = ctypes.c_int64
+    # the gather framer: keys, their offsets and the null flags may be
+    # absent (None); out None asks for the size
+    cdll.kafka_frame_rows.argtypes = [
+        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_char_p, i64, ctypes.c_void_p,
+        i64, ctypes.c_int64, ctypes.c_int64,
+        ctypes.c_char_p,  # the bytes object the records are written into
+        ctypes.c_int64,
+    ]
+    cdll.kafka_frame_rows.restype = ctypes.c_int64
     # parquet decoder (parquetdec.cpp)
     cdll.pq_decode_fixed.argtypes = [
         u8, ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,

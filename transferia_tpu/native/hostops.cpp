@@ -651,12 +651,10 @@ uint32_t crc32c_buf(const uint8_t* p, int64_t n, uint32_t init) {
 }
 
 // ---------------------------------------------------------------------------
-// Kafka RecordBatch v2 record-section encoder (the per-record varint
+// Kafka RecordBatch v2 record-section encoders (the per-record varint
 // framing that dominated the produce path in Python).  Records carry no
-// headers (the sink emits none); ts_delta is per record.  Null keys or
-// values are flagged via the *_null arrays (varint -1 markers).
-// Returns bytes written, or -1 when out_cap is too small (caller sizes
-// out with the exact formula below, so -1 means a caller bug).
+// headers (the sink emits none).  A null key or value is a length of -1
+// (varint -1 marker, no bytes).
 
 static inline int64_t put_varint(uint8_t* out, int64_t v) {
     uint64_t u = ((uint64_t)v << 1) ^ (uint64_t)(v >> 63);
@@ -669,6 +667,52 @@ static inline int64_t put_varint(uint8_t* out, int64_t v) {
     return i;
 }
 
+static inline int64_t varint_len(int64_t v) {
+    uint64_t u = ((uint64_t)v << 1) ^ (uint64_t)(v >> 63);
+    int64_t n = 1;
+    while (u >= 0x80) {
+        u >>= 7;
+        n++;
+    }
+    return n;
+}
+
+// a record's length as its own prefix counts it: attributes, timestamp
+// and offset deltas, key, value, header count
+static inline int64_t record_body_len(int64_t ts_delta, int64_t delta,
+                                      int64_t klen, int64_t vlen) {
+    return 1 + varint_len(ts_delta) + varint_len(delta)
+           + varint_len(klen) + (klen > 0 ? klen : 0)
+           + varint_len(vlen) + (vlen > 0 ? vlen : 0) + 1;
+}
+
+// one record, its length prefix first; returns the bytes written
+static inline int64_t put_record(uint8_t* out, int64_t body_len,
+                                 int64_t ts_delta, int64_t delta,
+                                 const uint8_t* key, int64_t klen,
+                                 const uint8_t* val, int64_t vlen) {
+    int64_t p = put_varint(out, body_len);
+    out[p++] = 0;  // attributes
+    p += put_varint(out + p, ts_delta);
+    p += put_varint(out + p, delta);
+    p += put_varint(out + p, klen);
+    if (klen > 0) {
+        memcpy(out + p, key, (size_t)klen);
+        p += klen;
+    }
+    p += put_varint(out + p, vlen);
+    if (vlen > 0) {
+        memcpy(out + p, val, (size_t)vlen);
+        p += vlen;
+    }
+    out[p++] = 0;  // header count varint(0)
+    return p;
+}
+
+// Records 0 .. n-1 with offset deltas 0 .. n-1 and a timestamp delta each
+// (ts_delta NULL: all 0); null keys or values are flagged via the *_null
+// arrays.  Returns bytes written, or -1 when out_cap is too small (the
+// caller sizes out with a bound, so -1 means a caller bug).
 int64_t kafka_encode_records(const uint8_t* key_data,
                              const int64_t* key_off,
                              const uint8_t* key_null,
@@ -677,43 +721,50 @@ int64_t kafka_encode_records(const uint8_t* key_data,
                              const uint8_t* val_null,
                              const int64_t* ts_delta,
                              int64_t n, uint8_t* out, int64_t out_cap) {
-    uint8_t tmp[64];
     int64_t pos = 0;
     for (int64_t i = 0; i < n; i++) {
-        // body renders into tmp up to the key bytes; lengths first so the
-        // record-length prefix is known without a second pass
         int64_t klen = key_null && key_null[i] ? -1
                        : key_off[i + 1] - key_off[i];
         int64_t vlen = val_null && val_null[i] ? -1
                        : val_off[i + 1] - val_off[i];
-        int64_t hl = 0;
-        tmp[hl++] = 0;  // attributes
-        hl += put_varint(tmp + hl, ts_delta ? ts_delta[i] : 0);
-        hl += put_varint(tmp + hl, i);          // offset delta
-        hl += put_varint(tmp + hl, klen);
-        int64_t body_len = hl + (klen > 0 ? klen : 0);
-        // varint(vlen) + value + varint(0 headers)
-        uint8_t vtmp[16];
-        int64_t vl = put_varint(vtmp, vlen);
-        body_len += vl + (vlen > 0 ? vlen : 0) + 1;
-        uint8_t ltmp[16];
-        int64_t ll = put_varint(ltmp, body_len);
-        if (pos + ll + body_len > out_cap) return -1;
-        memcpy(out + pos, ltmp, (size_t)ll);
-        pos += ll;
-        memcpy(out + pos, tmp, (size_t)hl);
-        pos += hl;
-        if (klen > 0) {
-            memcpy(out + pos, key_data + key_off[i], (size_t)klen);
-            pos += klen;
+        int64_t ts = ts_delta ? ts_delta[i] : 0;
+        int64_t body = record_body_len(ts, i, klen, vlen);
+        if (pos + varint_len(body) + body > out_cap) return -1;
+        pos += put_record(out + pos, body, ts, i, key_data + key_off[i],
+                          klen, val_data + val_off[i], vlen);
+    }
+    return pos;
+}
+
+// The gather form: the records of messages rows[0 .. n-1] (indices into
+// the offsets, in any order) with offset deltas from first_delta and
+// timestamp delta 0 - one partition's share of a batch, framed straight
+// from the buffers its messages were rendered into.  key_off NULL: every
+// key is null.  out NULL: returns the bytes it would write, else writes
+// them (out_cap at the least, -1 where it is less) and returns the count.
+int64_t kafka_frame_rows(const uint8_t* key_data, const int64_t* key_off,
+                         const uint8_t* key_null,
+                         const uint8_t* val_data, const int64_t* val_off,
+                         const uint8_t* val_null,
+                         const int64_t* rows, int64_t n,
+                         int64_t first_delta,
+                         uint8_t* out, int64_t out_cap) {
+    int64_t pos = 0;
+    for (int64_t i = 0; i < n; i++) {
+        const int64_t r = rows[i];
+        int64_t klen = !key_off || (key_null && key_null[r]) ? -1
+                       : key_off[r + 1] - key_off[r];
+        int64_t vlen = val_null && val_null[r] ? -1
+                       : val_off[r + 1] - val_off[r];
+        int64_t body = record_body_len(0, first_delta + i, klen, vlen);
+        if (!out) {
+            pos += varint_len(body) + body;
+            continue;
         }
-        memcpy(out + pos, vtmp, (size_t)vl);
-        pos += vl;
-        if (vlen > 0) {
-            memcpy(out + pos, val_data + val_off[i], (size_t)vlen);
-            pos += vlen;
-        }
-        out[pos++] = 0;  // header count varint(0)
+        if (pos + varint_len(body) + body > out_cap) return -1;
+        pos += put_record(out + pos, body, 0, first_delta + i,
+                          klen > 0 ? key_data + key_off[r] : nullptr, klen,
+                          val_data + val_off[r], vlen);
     }
     return pos;
 }
@@ -1203,9 +1254,8 @@ int64_t debezium_render_size(int64_t n_rows, const int32_t* kinds,
 
 // Write the messages of rows row_lo to row_hi - 1 end to end into out
 // (row_off[row_hi] - row_off[row_lo] bytes, from the arguments
-// debezium_render_size had); returns the bytes written.  The caller takes
-// a batch a slab at a time through one small buffer, which stays in the
-// cache between the write and the cut.
+// debezium_render_size had); returns the bytes written.  The caller
+// writes a whole batch into one buffer of the size that walk counted.
 int64_t debezium_render_write(int64_t row_lo, int64_t row_hi,
                               const int32_t* kinds,
                               const uint64_t* data, const uint64_t* offsets,

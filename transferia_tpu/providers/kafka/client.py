@@ -29,13 +29,15 @@ from transferia_tpu.abstract.errors import CategorizedError
 from transferia_tpu.providers.kafka.protocol import (
     Reader,
     Record,
+    RecordSection,
+    batch_header,
     decode_record_batches,
     enc_bytes,
     enc_str,
     encode_record_batch,
     scan_record_batches,
 )
-from transferia_tpu.utils.net import recv_exact
+from transferia_tpu.utils.net import recv_exact, send_pieces
 
 logger = logging.getLogger(__name__)
 
@@ -206,16 +208,20 @@ class KafkaClient:
             for node in list(self._conns):
                 self._drop_conn(node)
 
-    def _roundtrip(self, api_key: int, api_version: int, body: bytes,
+    def _roundtrip(self, api_key: int, api_version: int, body,
                    node="boot", span: str = "kafka_roundtrip",
                    **span_args) -> Reader:
-        """One request and its response.  `span` names the span it is
+        """One request and its response.  `body` is bytes, or a list of
+        buffers that go out in order as they are (a transactional
+        Produce's record sections: no join).  `span` names the span it is
         recorded as: a Produce is the sink's write (`sink_push` with
         `direction="kafka_produce"`, its `bytes` the request's), every
         other call `kafka_roundtrip` (its `bytes` the response's)."""
         from transferia_tpu.chaos.failpoints import failpoint
         from transferia_tpu.stats import trace
 
+        pieces = [body] if isinstance(body, bytes) else body
+        body_len = sum(map(len, pieces))
         failpoint("client.kafka.roundtrip")  # before the lock: may sleep
         with trace.span(span, api=api_key, **span_args) as sp, self._lock:
             sock = self._conn_for(node)
@@ -223,12 +229,12 @@ class KafkaClient:
             corr = self._corr
             header = struct.pack("!hhi", api_key, api_version, corr) \
                 + enc_str(self.client_id)
-            msg = header + body
             # I/O under self._lock is the design: the lock serializes
             # request/response framing on the single broker socket
             try:
-                sock.sendall(  # trtpu: ignore[LCK001]
-                    struct.pack("!i", len(msg)) + msg)
+                send_pieces(  # trtpu: ignore[LCK001]
+                    sock, [struct.pack("!i", len(header) + body_len)
+                           + header, *pieces])
                 t_sent = time.perf_counter() if sp else 0.0
                 size = struct.unpack(
                     "!i", recv_exact(sock, 4))[0]  # trtpu: ignore[LCK001]
@@ -316,15 +322,18 @@ class KafkaClient:
 
     # -- produce ------------------------------------------------------------
     def produce(self, topic: str, partition: int,
-                records: list[Record], acks: int = -1,
+                records, acks: int = -1,
                 timeout_ms: int = 30_000, compression: str = "") -> int:
-        """Append records; returns the base offset assigned (Produce v3)."""
+        """Append records (a list of Records, or a RecordSection the sink
+        framed); returns the base offset assigned (Produce v3)."""
         from transferia_tpu.stats import trace
 
+        n = records.count if isinstance(records, RecordSection) \
+            else len(records)
         with trace.span("kafka_encode") as sp:
             batch = encode_record_batch(records, compression=compression)
             if sp:
-                sp.add(records=len(records), bytes=len(batch))
+                sp.add(records=n, bytes=len(batch))
         body = enc_str(None)                      # transactional id
         body += struct.pack("!hi", acks, timeout_ms)
         body += struct.pack("!i", 1) + enc_str(topic)
@@ -334,7 +343,7 @@ class KafkaClient:
         def attempt() -> int:
             r = self._routed(topic, partition, API_PRODUCE, 3, body,
                              span="sink_push", direction="kafka_produce",
-                             bytes=len(body), records=len(records),
+                             bytes=len(body), records=n,
                              partitions=1)
             base_offset = -1
             for _ in range(r.i32()):
@@ -390,43 +399,47 @@ class KafkaClient:
 
     def txn_produce(self, transactional_id: str, producer_id: int,
                     producer_epoch: int,
-                    messages: dict[tuple[str, int], list[Record]],
+                    sections: dict[tuple[str, int], RecordSection],
                     acks: int = -1, timeout_ms: int = 30_000) -> int:
         """One transactional produce: every (topic, partition) record
-        list lands in a single Produce request carrying the
+        section lands in a single Produce request carrying the
         transactional id and producer-epoch-stamped batches — the
         broker applies it atomically and fences a stale epoch.
         Returns records produced."""
-        by_topic: dict[str, list[tuple[int, list[Record]]]] = {}
-        for (topic, partition), records in sorted(messages.items()):
-            by_topic.setdefault(topic, []).append((partition, records))
+        by_topic: dict[str, list[tuple[int, RecordSection]]] = {}
+        for (topic, partition), section in sorted(sections.items()):
+            by_topic.setdefault(topic, []).append((partition, section))
         from transferia_tpu.stats import trace
 
-        # the request in pieces, joined once: a part's records are
-        # hundreds of megabytes, and `+=` would copy them a partition
-        pieces = [enc_str(transactional_id),
-                  struct.pack("!hi", acks, timeout_ms),
-                  struct.pack("!i", len(by_topic))]
+        # the request as buffers: a batch's header, then its section's
+        # buffers as the push framed them (a part's records are hundreds
+        # of megabytes: nothing joins them)
+        now = int(time.time() * 1000)
+        head = enc_str(transactional_id) \
+            + struct.pack("!hii", acks, timeout_ms, len(by_topic))
+        body: list = []
         total = 0
         with trace.span("kafka_encode") as sp:
             for topic, parts in sorted(by_topic.items()):
-                pieces.append(enc_str(topic))
-                pieces.append(struct.pack("!i", len(parts)))
-                for partition, records in parts:
-                    batch = encode_record_batch(
-                        records, producer_id=producer_id,
+                head += enc_str(topic) + struct.pack("!i", len(parts))
+                for partition, section in parts:
+                    header = batch_header(
+                        section, now, now, producer_id=producer_id,
                         producer_epoch=producer_epoch)
-                    pieces.append(struct.pack("!ii", partition,
-                                              len(batch)))
-                    pieces.append(batch)
-                    total += len(records)
-            body = b"".join(pieces)
+                    body.append(head + struct.pack(
+                        "!ii", partition, len(header) + section.nbytes)
+                        + header)
+                    body.extend(section.buffers)
+                    head = b""
+                    total += section.count
+            if head:
+                body.append(head)
+            size = sum(map(len, body))
             if sp:
-                sp.add(records=total, bytes=len(body))
-        del pieces
+                sp.add(records=total, bytes=size)
         r = self._roundtrip(API_PRODUCE, 3, body, span="sink_push",
-                            direction="kafka_produce", bytes=len(body),
-                            records=total, partitions=len(messages))
+                            direction="kafka_produce", bytes=size,
+                            records=total, partitions=len(sections))
         for _ in range(r.i32()):
             r.string()
             for _ in range(r.i32()):

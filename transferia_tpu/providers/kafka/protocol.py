@@ -19,6 +19,9 @@ try:
 
     def crc32c(data: bytes) -> int:
         return google_crc32c.value(data)
+
+    def _crc_extend(crc: int, data) -> int:
+        return google_crc32c.extend(crc, data)
 except ImportError:
     # native SSE4.2 path (hostops.cpp crc32c_buf) with a pure-python
     # table as the last resort; resolved lazily so importing this module
@@ -33,11 +36,13 @@ except ImportError:
             table.append(c)
         return table
 
-    def _crc_py(data: bytes) -> int:
-        crc = 0xFFFFFFFF
-        for b in data:
+    def _crc_py(data: bytes, init: int = 0) -> int:
+        crc = init ^ 0xFFFFFFFF
+        for b in bytes(data):
             crc = _TABLE[(crc ^ b) & 0xFF] ^ (crc >> 8)
         return crc ^ 0xFFFFFFFF
+
+    _crc_extend = _crc_py
 
     _TABLE = _make_table()
     _crc_impl = None
@@ -161,6 +166,134 @@ class Record:
 
 
 _CODEC_GZIP = 1
+# attributes bit 4: this batch is part of a transaction
+_ATTR_TRANSACTIONAL = 0x10
+
+
+def crc32c_chain(pieces) -> int:
+    """CRC32C of the buffers end to end, chained from one to the next
+    with no concatenation: natively (`crc32c_buf` takes the running value,
+    GIL released) where the library is loaded."""
+    import numpy as np
+
+    from transferia_tpu.native import lib as native_lib
+
+    cdll = native_lib()
+    crc = 0
+    for piece in pieces:
+        if not len(piece):
+            continue
+        if cdll is None:
+            crc = _crc_extend(crc, piece)
+        else:
+            data = np.frombuffer(piece, dtype=np.uint8)
+            crc = cdll.crc32c_buf(data, data.size, crc)
+    return int(crc)
+
+
+@dataclass
+class RecordSection:
+    """One partition's records framed as a RecordBatch v2 carries them
+    (offset deltas 0 .. count - 1 in order, no headers): buffers that go
+    into a request end to end, the records they hold and their bytes.
+    What the Kafka sink stages between a push and its publish;
+    `batch_header` makes the batch around it."""
+
+    buffers: list = field(default_factory=list)
+    count: int = 0
+    nbytes: int = 0
+
+    def add(self, buf, count: int) -> None:
+        self.buffers.append(buf)
+        self.count += count
+        self.nbytes += len(buf)
+
+
+def batch_header(section: RecordSection, first_ts: int, max_ts: int,
+                 base_offset: int = 0, attrs: int = 0,
+                 producer_id: int = -1, producer_epoch: int = -1) -> bytes:
+    """The 61 bytes of a RecordBatch v2 before its record section, the
+    CRC32C chained over the header's tail and the section's buffers.
+    `producer_id`/`producer_epoch` stamp the batch for transactional
+    produce (the broker fences a batch whose producer epoch is older than
+    the transactional id's current one)."""
+    if producer_id >= 0:
+        attrs |= _ATTR_TRANSACTIONAL
+    # attributes, lastOffsetDelta, first and max timestamp, producerId,
+    # producerEpoch, baseSequence, recordCount: what the CRC covers
+    tail = struct.pack("!hiqqqhii", attrs, max(0, section.count - 1),
+                       first_ts, max_ts, producer_id, producer_epoch, -1,
+                       section.count)
+    crc = crc32c_chain([tail, *section.buffers])
+    # baseOffset, batchLength, partitionLeaderEpoch, magic, crc
+    return struct.pack("!qiibI", base_offset,
+                       9 + len(tail) + section.nbytes, 0, 2, crc) + tail
+
+
+def _record_py(delta: int, ts_delta: int, key: Optional[bytes],
+               value: Optional[bytes], headers=()) -> bytes:
+    """One record with its length prefix, in Python."""
+    body = [b"\x00", enc_varint(ts_delta), enc_varint(delta)]  # attributes
+    for b in (key, value):
+        if b is None:
+            body.append(enc_varint(-1))
+        else:
+            body.append(enc_varint(len(b)))
+            body.append(b)
+    body.append(enc_varint(len(headers)))
+    for hk, hv in headers:
+        body.append(enc_varint(len(hk)))
+        body.append(hk)
+        body.append(enc_varint(len(hv)))
+        body.append(hv)
+    blob = b"".join(body)
+    return enc_varint(len(blob)) + blob
+
+
+def frame_messages(block, groups: list) -> list[bytes]:
+    """The records of a message block's rows (serializers/formats.py
+    `MessageBlock`), a group at a time: groups are (row indices as int64,
+    first offset delta), and each comes back as one buffer of records
+    with offset deltas from its first and timestamp delta 0.  Natively
+    (`kafka_frame_rows`: a walk for the size, a walk that writes, both
+    with the GIL released); record by record in Python under
+    TRANSFERIA_TPU_NO_NATIVE=1, the same bytes."""
+    import numpy as np
+
+    from transferia_tpu import native
+
+    cdll = native.lib()
+    if cdll is None:
+        pairs = block.pairs()
+        return [b"".join(_record_py(first + i, 0, *pairs[r])
+                         for i, r in enumerate(rows.tolist()))
+                for rows, first in groups]
+
+    def addr(a):
+        return None if a is None else a.ctypes.data
+
+    for buf, offs, null in ((block.values, block.value_offsets,
+                             block.value_null),
+                            (block.keys, block.key_offsets, block.key_null)):
+        if buf is not None and (
+                offs.dtype != np.int64 or offs.shape != (block.n + 1,)
+                or offs[-1] > len(buf)
+                or null is not None and null.shape != (block.n,)):
+            raise ValueError(f"a message block of {block.n} messages "
+                             f"whose offsets do not fit its buffers")
+    args = (block.keys, addr(block.key_offsets), addr(block.key_null),
+            block.values, block.value_offsets, addr(block.value_null))
+    out = []
+    for rows, first in groups:
+        size = cdll.kafka_frame_rows(*args, rows, len(rows), first, None, 0)
+        buf = native.new_bytes(None, size)
+        written = cdll.kafka_frame_rows(*args, rows, len(rows), first,
+                                        buf, size)
+        if written != size:
+            raise RuntimeError(
+                f"kafka framer wrote {written} of {size} bytes")
+        out.append(buf)
+    return out
 
 
 def _encode_records_native(records: list[Record], now: int,
@@ -208,64 +341,31 @@ def _encode_records_native(records: list[Record], now: int,
     return out[:rc].tobytes()
 
 
-def encode_record_batch(records: list[Record],
+def encode_record_batch(records,
                         base_offset: int = 0,
                         compression: str = "",
                         producer_id: int = -1,
                         producer_epoch: int = -1) -> bytes:
-    """Records -> one RecordBatch v2 blob (optionally gzip-compressed).
-
-    `producer_id`/`producer_epoch` stamp the batch header for
-    transactional produce (the broker fences a batch whose producer
-    epoch is older than the transactional id's current one)."""
+    """Records, or a RecordSection framed at push (its timestamps all
+    now), -> one RecordBatch v2 blob (optionally gzip-compressed): the
+    at-least-once produce's, with the header `batch_header` writes for
+    every batch."""
     now = int(time.time() * 1000)
-    base_ts = records[0].timestamp_ms or now if records else now
-    native = _encode_records_native(records, now, base_ts) \
-        if records else None
-    if native is not None:
-        return _finish_record_batch(records, native, base_offset,
-                                    compression, now, base_ts,
-                                    producer_id, producer_epoch)
-    # accumulate in a list: += on bytes is O(total^2) and a 20k-record
-    # batch would copy gigabytes
-    parts: list[bytes] = []
-    for i, r in enumerate(records):
-        body = [b"\x00"]  # attributes
-        body.append(enc_varint((r.timestamp_ms or now) - base_ts))
-        body.append(enc_varint(i))  # offset delta
-        if r.key is None:
-            body.append(enc_varint(-1))
-        else:
-            body.append(enc_varint(len(r.key)))
-            body.append(r.key)
-        if r.value is None:
-            body.append(enc_varint(-1))
-        else:
-            body.append(enc_varint(len(r.value)))
-            body.append(r.value)
-        body.append(enc_varint(len(r.headers)))
-        for hk, hv in r.headers:
-            body.append(enc_varint(len(hk)))
-            body.append(hk)
-            body.append(enc_varint(len(hv)))
-            body.append(hv)
-        blob = b"".join(body)
-        parts.append(enc_varint(len(blob)))
-        parts.append(blob)
-    return _finish_record_batch(records, b"".join(parts), base_offset,
-                                compression, now, base_ts,
-                                producer_id, producer_epoch)
-
-
-# attributes bit 4: this batch is part of a transaction
-_ATTR_TRANSACTIONAL = 0x10
-
-
-def _finish_record_batch(records: list[Record], recs: bytes,
-                         base_offset: int, compression: str,
-                         now: int, base_ts: int,
-                         producer_id: int = -1,
-                         producer_epoch: int = -1) -> bytes:
+    if isinstance(records, RecordSection):
+        base_ts = max_ts = now
+        count = records.count
+        recs = b"".join(records.buffers)
+    else:
+        base_ts = records[0].timestamp_ms or now if records else now
+        max_ts = (records[-1].timestamp_ms or now) if records else now
+        count = len(records)
+        recs = _encode_records_native(records, now, base_ts) \
+            if records else None
+        if recs is None:
+            recs = b"".join(
+                _record_py(i, (r.timestamp_ms or now) - base_ts, r.key,
+                           r.value, r.headers)
+                for i, r in enumerate(records))
     attrs = 0
     if compression == "gzip":
         import gzip as _gzip
@@ -275,29 +375,9 @@ def _finish_record_batch(records: list[Record], recs: bytes,
     elif compression:
         raise ValueError(f"unsupported compression {compression!r} "
                          f"(only gzip ships dependency-free)")
-    if producer_id >= 0:
-        attrs |= _ATTR_TRANSACTIONAL
-    # batch body after the crc field
-    after_crc = (
-        struct.pack("!h", attrs)                   # attributes
-        + struct.pack("!i", max(0, len(records) - 1))  # lastOffsetDelta
-        + struct.pack("!q", base_ts)
-        + struct.pack("!q", (records[-1].timestamp_ms or now)
-                      if records else now)
-        + struct.pack("!q", producer_id)           # producerId
-        + struct.pack("!h", producer_epoch)        # producerEpoch
-        + struct.pack("!i", -1)                    # baseSequence
-        + struct.pack("!i", len(records))
-        + recs
-    )
-    header = (
-        struct.pack("!i", 0)       # partitionLeaderEpoch
-        + b"\x02"                  # magic
-        + struct.pack("!I", crc32c(after_crc))
-    )
-    batch_len = len(header) + len(after_crc)
-    return struct.pack("!q", base_offset) + struct.pack("!i", batch_len) \
-        + header + after_crc
+    section = RecordSection([recs], count, len(recs))
+    return batch_header(section, base_ts, max_ts, base_offset, attrs,
+                        producer_id, producer_epoch) + recs
 
 
 class RecordView(Sequence):

@@ -15,13 +15,22 @@ from collections.abc import Sequence
 from dataclasses import dataclass, field
 from typing import Optional
 
+import numpy as np
+
 from transferia_tpu.abstract.commit import StagedSinker
 from transferia_tpu.abstract.interfaces import Batch, Sinker, is_columnar
 from transferia_tpu.coordinator.interface import Coordinator
 from transferia_tpu.models.endpoint import EndpointParams, register_endpoint
 from transferia_tpu.parsers import Message
 from transferia_tpu.providers.kafka.client import KafkaClient, KafkaError
-from transferia_tpu.providers.kafka.protocol import Record, payload_bytes
+from transferia_tpu.native import lib as native_lib
+from transferia_tpu.providers.kafka.protocol import (
+    Record,
+    RecordSection,
+    crc32c,
+    frame_messages,
+    payload_bytes,
+)
 from transferia_tpu.providers.queue_common import FetchedBatch, QueueSource
 from transferia_tpu.providers.registry import (
     Provider,
@@ -29,6 +38,7 @@ from transferia_tpu.providers.registry import (
     register_provider,
 )
 from transferia_tpu.serializers import make_queue_serializer
+from transferia_tpu.serializers.formats import MessageBlock
 from transferia_tpu.stats import trace
 from transferia_tpu.transform.plugins.sharder import hash_column_to_shards
 
@@ -293,7 +303,8 @@ class KafkaSinker(Sinker, StagedSinker):
         self._partitions: dict[str, list[int]] = {}
         self._stage = None  # staging.PartStage when open
         self._stage_key = ""
-        self._staged: dict[tuple[str, int], list[Record]] = {}
+        # per (topic, partition): the part's records, framed at push
+        self._staged: dict[tuple[str, int], RecordSection] = {}
 
     def _topic_partitions(self, topic: str) -> list[int]:
         if topic not in self._partitions:
@@ -301,91 +312,95 @@ class KafkaSinker(Sinker, StagedSinker):
             self._partitions[topic] = meta.get(topic) or [0]
         return self._partitions[topic]
 
-    def _key_partitions(self, pairs, n_parts: int):
-        """crc32c(key) % n_parts per pair, batched through the native lib
-        when present (Kafka's own default partitioner hashes the key with
-        murmur2: a key's partition here is stable, not the one a Java
-        producer would pick).  A batch of null keys (a table without a
-        primary key) is dealt round the partitions in turn, as Kafka's
-        partitioner spreads records without a key."""
-        import numpy as np
-
-        from transferia_tpu.native import lib as native_lib
-
-        if all(k is None for k, _ in pairs):
+    def _partition_of(self, batch: Batch, block: MessageBlock,
+                      n_parts: int) -> np.ndarray:
+        """Each message's partition index: the configured column's hash
+        where the batch gave one message a row, else crc32c(key) % n_parts
+        over the block's key buffer as it is, batched through the native
+        lib when present (Kafka's own default partitioner hashes the key
+        with murmur2: a key's partition here is stable, not the one a Java
+        producer would pick; a null key among keys hashes as empty).  A
+        batch of null keys (a table without a primary key) is dealt round
+        the partitions in turn, as Kafka's partitioner spreads records
+        without a key."""
+        by = self.params.partition_by
+        if is_columnar(batch) and by and by in batch.columns and \
+                block.n == batch.n_rows:
+            return np.asarray(hash_column_to_shards(batch.column(by),
+                                                    n_parts))
+        if block.keys is None:
             turn = self._null_key_turn
-            self._null_key_turn = (turn + len(pairs)) % n_parts
-            return (np.arange(len(pairs), dtype=np.int64) + turn) % n_parts
+            self._null_key_turn = (turn + block.n) % n_parts
+            return (np.arange(block.n, dtype=np.int64) + turn) % n_parts
+        # deterministic key hash (crc32c): built-in hash() is randomized
+        # per process and would break per-key partition affinity across
+        # restarts
         cdll = native_lib()
-        keys = [bytes(k or b"") for k, _ in pairs]
-        if cdll is not None:
-            data = np.frombuffer(b"".join(keys), dtype=np.uint8)
-            offs = np.zeros(len(keys) + 1, dtype=np.int64)
-            np.cumsum([len(k) for k in keys], out=offs[1:])
-            out = np.empty(len(keys), dtype=np.uint32)
-            cdll.crc32c_batch(
-                data if data.size else np.zeros(1, dtype=np.uint8),
-                offs, len(keys), out)
-            return out % n_parts
-        from transferia_tpu.providers.kafka.protocol import crc32c
+        if cdll is None:
+            offs = block.key_offsets.tolist()
+            return np.array([crc32c(block.keys[lo:hi])
+                             for lo, hi in zip(offs, offs[1:])],
+                            dtype=np.int64) % n_parts
+        data = np.frombuffer(block.keys, dtype=np.uint8)
+        out = np.empty(block.n, dtype=np.uint32)
+        cdll.crc32c_batch(data if data.size else np.zeros(1, np.uint8),
+                          block.key_offsets, block.n, out)
+        return out % n_parts
 
-        return [crc32c(k) % n_parts for k in keys]
-
-    def _partitioned_records(self, batch: Batch
-                             ) -> dict[tuple[str, int], list[Record]]:
-        """Serialize one batch into per-(topic, partition) records."""
-        pairs = self.serializer.serialize_messages(batch)
-        if not pairs:
-            return {}
+    def _frame(self, batch: Batch,
+               sections: dict[tuple[str, int], RecordSection]) -> None:
+        """Serialize one batch and frame its records at push into the
+        per-(topic, partition) sections, each partition's offset deltas
+        going on from its records so far: a renderer's block as it is,
+        other serializers' pairs laid into one first."""
+        out = self.serializer.serialize_block(batch)
+        rendered = isinstance(out, MessageBlock)
+        if not rendered:
+            if not out:
+                return
+            out = MessageBlock.from_pairs(out)
+        block = out
         if is_columnar(batch):
             topic = self.params.topic or str(batch.table_id)
         else:
-            rows = [it for it in batch if it.is_row_event()]
+            rows = list(batch)
             topic = self.params.topic or (
                 str(rows[0].table_id) if rows else "controls"
             )
         partitions = self._topic_partitions(topic)
-        n_parts = len(partitions)
-        col_parts = None
-        if is_columnar(batch) and self.params.partition_by and \
-                self.params.partition_by in batch.columns and \
-                len(pairs) == batch.n_rows:
-            col_parts = hash_column_to_shards(
-                batch.column(self.params.partition_by), n_parts
-            )
-        if col_parts is not None:
-            part_idx = col_parts
-        else:
-            # deterministic key hash (crc32c): built-in hash() is
-            # randomized per process and would break per-key partition
-            # affinity across restarts.  One batched native call when
-            # available; the per-key fallback is the same function.
-            part_idx = self._key_partitions(pairs, n_parts)
-        out: dict[tuple[str, int], list[Record]] = {}
-        for i, (key, value) in enumerate(pairs):
-            p = partitions[int(part_idx[i])]
-            out.setdefault((topic, p), []).append(
-                Record(key=key, value=value)
-            )
-        return out
+        part_idx = self._partition_of(batch, block, len(partitions))
+        order = np.argsort(part_idx, kind="stable")
+        groups = []
+        lo = 0
+        for i, count in enumerate(np.bincount(
+                part_idx, minlength=len(partitions)).tolist()):
+            if count:
+                section = sections.setdefault((topic, partitions[i]),
+                                              RecordSection())
+                groups.append((section, order[lo:lo + count]))
+                lo += count
+        framed = frame_messages(block, [(rows, section.count)
+                                        for section, rows in groups])
+        for (section, rows), buf in zip(groups, framed):
+            section.add(buf, len(rows))
+        trace.TELEMETRY.record_kafka_framed(block.n, rendered)
 
     def push(self, batch: Batch) -> None:
         if self._stage is not None:
             batch = self._stage.stage(batch)
             try:
-                for tp, records in self._partitioned_records(
-                        batch).items():
-                    self._staged.setdefault(tp, []).extend(records)
+                self._frame(batch, self._staged)
             except BaseException:
                 # serialization died after the dedup window recorded
                 # the batch: only a full part restage is safe
                 self._stage.mark_failed()
                 raise
             return
-        for (topic, p), records in self._partitioned_records(
-                batch).items():
+        sections: dict[tuple[str, int], RecordSection] = {}
+        self._frame(batch, sections)
+        for (topic, p), section in sections.items():
             self.client.produce(
-                topic, p, records,
+                topic, p, section,
                 compression=getattr(self.params, "compression", ""))
 
     # -- StagedSinker (publish = one kafka transaction) ---------------------

@@ -6,8 +6,12 @@ from __future__ import annotations
 import abc
 import csv
 import io
+import itertools
 import json
+from dataclasses import dataclass
 from typing import Any, Optional, Sequence
+
+import numpy as np
 
 from transferia_tpu.abstract.change_item import ChangeItem
 from transferia_tpu.abstract.interfaces import Batch, is_columnar
@@ -118,6 +122,66 @@ class RawSerializer(BatchSerializer):
         return out.getvalue()
 
 
+@dataclass
+class MessageBlock:
+    """A batch's messages end to end, with no object per message: message
+    i's value is values[value_offsets[i]:value_offsets[i + 1]] (int64
+    offsets, n + 1 of them) and its key likewise; `keys` None means every
+    key is null.  *_null (uint8, n) flag the messages whose key or value
+    is null, where any is.  What the Debezium emitter's native renderer
+    hands a sink, a batch's rows (`DebeziumEmitter.emit_block`), and
+    what pairs become to be framed (`from_pairs`)."""
+
+    n: int
+    values: bytes
+    value_offsets: np.ndarray
+    keys: Optional[bytes] = None
+    key_offsets: Optional[np.ndarray] = None
+    key_null: Optional[np.ndarray] = None
+    value_null: Optional[np.ndarray] = None
+
+    @staticmethod
+    def _laid(parts: Sequence) -> tuple:
+        """(buffer, offsets, null flags or None) of bytes-or-None parts."""
+        lens = np.fromiter(map(len, (p or b"" for p in parts)),
+                           dtype=np.int64, count=len(parts))
+        offsets = np.zeros(len(parts) + 1, dtype=np.int64)
+        np.cumsum(lens, out=offsets[1:])
+        null = np.fromiter((p is None for p in parts), dtype=np.uint8,
+                           count=len(parts))
+        return (b"".join(p or b"" for p in parts), offsets,
+                null if null.any() else None)
+
+    @classmethod
+    def from_pairs(cls, pairs: Sequence) -> "MessageBlock":
+        """(key, value) pairs laid end to end, once per batch."""
+        keys = [k for k, _ in pairs]
+        values, value_offsets, value_null = cls._laid(
+            [v for _, v in pairs])
+        block = cls(len(pairs), values, value_offsets,
+                    value_null=value_null)
+        if any(k is not None for k in keys):
+            block.keys, block.key_offsets, block.key_null = \
+                cls._laid(keys)
+        return block
+
+    def pairs(self) -> list[tuple[Optional[bytes], Optional[bytes]]]:
+        """The messages as (key, value) pairs of bytes, the one place a
+        block is cut into objects."""
+        def cut(buf, offsets, null) -> list:
+            offs = offsets.tolist()
+            out = list(map(buf.__getitem__, map(slice, offs, offs[1:])))
+            if null is not None:
+                out = [None if z else b for b, z in zip(out, null.tolist())]
+            return out
+
+        values = cut(self.values, self.value_offsets, self.value_null)
+        if self.keys is None:
+            return list(zip(itertools.repeat(None), values))
+        return list(zip(cut(self.keys, self.key_offsets, self.key_null),
+                        values))
+
+
 class QueueSerializer(abc.ABC):
     """Per-row (key, value) pairs for message brokers."""
 
@@ -125,6 +189,11 @@ class QueueSerializer(abc.ABC):
     def serialize_messages(self, batch: Batch
                            ) -> list[tuple[bytes, Optional[bytes]]]:
         ...
+
+    def serialize_block(self, batch: Batch):
+        """The batch's messages as one MessageBlock where the serializer
+        renders them into buffers, else as serialize_messages' pairs."""
+        return self.serialize_messages(batch)
 
 
 class JsonQueueSerializer(QueueSerializer):
@@ -165,6 +234,9 @@ class DebeziumQueueSerializer(QueueSerializer):
 
     def serialize_messages(self, batch):
         return self.emitter.emit_batch(batch, snapshot=self.snapshot)
+
+    def serialize_block(self, batch):
+        return self.emitter.emit_block(batch, snapshot=self.snapshot)
 
 
 class MirrorQueueSerializer(QueueSerializer):

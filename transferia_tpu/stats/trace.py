@@ -867,6 +867,12 @@ class DeviceTelemetry:
             # in that call (providers/kafka/provider.py)
             self.kafka_handouts = 0
             self.kafka_handouts_buffered = 0
+            # records the Kafka sink framed at push into a part's staged
+            # record sections, and of those the ones framed straight from
+            # a renderer's block, with no object per message
+            # (providers/kafka/provider.py::KafkaSinker._frame)
+            self.kafka_records_framed = 0
+            self.kafka_records_framed_block = 0
             # parts the MySQL source streamed (one result set each), rows
             # the Debezium emitter rendered, of those the ones it rendered
             # from columns and of those the ones its native renderer
@@ -991,6 +997,12 @@ class DeviceTelemetry:
             self.kafka_handouts += 1
             self.kafka_handouts_buffered += buffered
 
+    def record_kafka_framed(self, n_records: int, block: bool) -> None:
+        with self._lock:
+            self.kafka_records_framed += int(n_records)
+            if block:
+                self.kafka_records_framed_block += int(n_records)
+
     def record_mysql_part(self) -> None:
         with self._lock:
             self.mysql_parts += 1
@@ -1080,6 +1092,9 @@ class DeviceTelemetry:
                 "parsequeue_pushes_ahead": self.parsequeue_pushes_ahead,
                 "kafka_handouts": self.kafka_handouts,
                 "kafka_handouts_buffered": self.kafka_handouts_buffered,
+                "kafka_records_framed": self.kafka_records_framed,
+                "kafka_records_framed_block":
+                    self.kafka_records_framed_block,
                 "mysql_parts": self.mysql_parts,
                 "debezium_rows": self.debezium_rows,
                 "debezium_rows_fast": self.debezium_rows_fast,

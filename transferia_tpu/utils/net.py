@@ -3,6 +3,10 @@
 from __future__ import annotations
 
 import socket
+import ssl
+
+# buffers one sendmsg takes at most (Linux's IOV_MAX)
+_IOV_MAX = 1024
 
 
 class BufferedSock:
@@ -68,3 +72,24 @@ def recv_exact(sock: socket.socket, n: int,
         parts.append(chunk)
         got += len(chunk)
     return b"".join(parts) if len(parts) != 1 else parts[0]
+
+
+def send_pieces(sock: socket.socket, pieces) -> None:
+    """Send buffers in order as they are, with no join: a sendmsg over as
+    many as one call takes, a partial send resumed where it stopped.  A
+    TLS socket has no scatter send and takes them a sendall each."""
+    if isinstance(sock, ssl.SSLSocket):
+        for piece in pieces:
+            sock.sendall(piece)
+        return
+    views = [memoryview(p).cast("B") for p in pieces if len(p)]
+    i = 0
+    while i < len(views):
+        sent = sock.sendmsg(views[i:i + _IOV_MAX])
+        while sent:
+            n = len(views[i])
+            if sent < n:
+                views[i] = views[i][sent:]
+                break
+            sent -= n
+            i += 1
