@@ -2,6 +2,7 @@
 (cf. pkg/parsequeue/parsequeue_test.go)."""
 
 import json
+import random
 import threading
 import time
 
@@ -13,7 +14,12 @@ from transferia_tpu.abstract.schema import new_table_schema
 from transferia_tpu.columnar import ColumnBatch
 from transferia_tpu.parsequeue import ParseQueue
 from transferia_tpu.parsers import Message
-from transferia_tpu.providers.queue_common import FetchedBatch, QueueSource
+from transferia_tpu.providers.queue_common import (
+    FetchedBatch,
+    QueueSource,
+    Sequencer,
+)
+from transferia_tpu.stats import trace
 from transferia_tpu.serializers import (
     make_queue_serializer,
     make_serializer,
@@ -387,18 +393,141 @@ class TestParseQueue:
         pq.close()                        # a second close is a no-op
 
 
+class PerOffsetSequencer:
+    """The commit contract written out one offset at a time: every
+    in-flight offset a `[offset, acked]` entry in start order; an ack
+    marks its offsets and frees the acked front."""
+
+    def __init__(self):
+        self.inflight = {}
+
+    def start_processing(self, topic, partition, offsets):
+        self.inflight.setdefault((topic, partition), []).extend(
+            [o, False] for o in offsets)
+
+    def ack(self, topic, partition, offsets):
+        lst = self.inflight.get((topic, partition), [])
+        done = set(offsets)
+        for entry in lst:
+            if entry[0] in done:
+                entry[1] = True
+        commit = None
+        while lst and lst[0][1]:
+            commit = lst.pop(0)[0]
+        return commit
+
+
+def random_units(rng, n_units, start=0):
+    """Ascending offset lists of 1 to 1,024 offsets, with gaps between
+    the units and inside them."""
+    units, o = [], start
+    for _ in range(n_units):
+        o += rng.choice((0, 0, 1, 7, 300))       # a gap before the unit
+        unit = []
+        for _ in range(rng.choice((1, 2, rng.randint(1, 1024), 1024))):
+            unit.append(o)
+            o += 1 + (rng.random() < 0.05) * rng.randint(1, 40)
+        units.append(unit)
+    return units
+
+
+class TestSequencer:
+    """The per-unit Sequencer against the per-offset model of the same
+    contract: the highest offset with no unacked predecessor."""
+
+    @pytest.mark.parametrize("seed", [1, 2, 3, 2**31 + 11])
+    def test_every_ack_commits_what_the_per_offset_model_commits(
+            self, seed):
+        rng = random.Random(seed)
+        parts = [("t", 0), ("t", 1), ("u", 0)]
+        pending = {key: random_units(rng, 24, start=rng.randint(0, 10**9))
+                   for key in parts}
+        seq, model = Sequencer(), PerOffsetSequencer()
+        started, commits = [], 0
+        while any(pending.values()) or started:
+            if started and (not any(pending.values())
+                            or rng.random() < 0.45):
+                key, unit = started.pop(rng.randrange(len(started)))
+                got = seq.ack(*key, unit[0])
+                assert got == model.ack(*key, unit)
+                commits += got is not None
+            else:
+                key = rng.choice([k for k in parts if pending[k]])
+                unit = pending[key].pop(0)
+                seq.start_processing(*key, unit[0], unit[-1])
+                model.start_processing(*key, unit)
+                started.append((key, unit))
+            if rng.random() < 0.1:
+                # a unit never started: past every offset, or a
+                # partition with nothing in flight
+                key = rng.choice(parts + [("t", 9)])
+                depth = seq.inflight_units(*key)
+                assert seq.ack(*key, 2**62) is None
+                assert model.ack(*key, [2**62]) is None
+                assert seq.inflight_units(*key) == depth
+        assert commits > 0
+        assert all(seq.inflight_units(*k) == 0 for k in parts)
+
+    def test_a_unit_never_started_changes_nothing(self):
+        seq = Sequencer()
+        assert seq.ack("t", 0, 0) is None
+        seq.start_processing("t", 0, 0, 9)
+        seq.start_processing("t", 0, 10, 19)
+        assert seq.ack("t", 0, 5) is None        # inside a unit, not one
+        assert seq.ack("t", 1, 0) is None        # another partition
+        assert seq.inflight_units("t", 0) == 2
+        assert seq.ack("t", 0, 0) == 9
+        assert seq.ack("t", 0, 0) is None        # acked already
+        assert seq.ack("t", 0, 10) == 19
+
+    def test_a_unit_of_one_offset(self):
+        seq = Sequencer()
+        seq.start_processing("t", 0, 5, 5)
+        seq.start_processing("t", 0, 6, 6)
+        seq.start_processing("t", 0, 9, 9)
+        assert seq.ack("t", 0, 6) is None        # 5 is still in flight
+        assert seq.ack("t", 0, 5) == 6
+        assert seq.ack("t", 0, 9) == 9
+        assert seq.inflight_units("t", 0) == 0
+
+    def test_the_record_holds_one_entry_a_unit_at_depth(self):
+        seq = Sequencer()
+        for u in range(64):
+            seq.start_processing("t", 0, u * 1024, u * 1024 + 1023)
+        # 65,536 offsets in flight, 64 entries
+        assert seq.inflight_units("t", 0) == 64
+        assert len(seq._inflight[("t", 0)]) == 64
+        for u in range(64):
+            assert seq.ack("t", 0, u * 1024) == u * 1024 + 1023
+            # an in-order ack pops exactly its own entry
+            assert seq.inflight_units("t", 0) == 63 - u
+        # out of order at depth: the acked tail waits for the head
+        for u in range(64, 128):
+            seq.start_processing("t", 0, u * 1024, u * 1024 + 1023)
+        for u in range(127, 64, -1):
+            assert seq.ack("t", 0, u * 1024) is None
+            assert seq.inflight_units("t", 0) == 64
+        assert seq.ack("t", 0, 64 * 1024) == 128 * 1024 - 1
+        assert seq.inflight_units("t", 0) == 0
+
+
 class TestQueueSourceCommits:
     """QueueSource + Sequencer over a stub client and a sink whose futures
     the test resolves: what is committed when a flush fails."""
 
     class Client:
-        def __init__(self, n_batches, per_batch):
+        def __init__(self, n_batches, per_batch, gaps=False):
+            # with gaps: every other offset of a batch, and 5 skipped
+            # between batches (compaction, transaction markers)
+            step, skip = (2, 5) if gaps else (1, 0)
+            span = per_batch * step + skip
             self.batches = [
                 FetchedBatch("t", 0, [
                     Message(value=b"x", topic="t", partition=0,
-                            offset=b * per_batch + i)
+                            offset=b * span + i * step)
                     for i in range(per_batch)])
                 for b in range(n_batches)]
+            self.last = [fb.last_offset for fb in self.batches]
             self.commits = []
             self.closed = False
 
@@ -426,14 +555,14 @@ class TestQueueSourceCommits:
         th.start()
         return sink, src, th, raised
 
-    def test_a_failed_flush_commits_nothing_past_the_last_acked_offset(
-            self):
-        client = self.Client(n_batches=5, per_batch=10)
+    def _failed_flush(self, gaps):
+        client = self.Client(n_batches=5, per_batch=10, gaps=gaps)
+        last = client.last
         sink, _src, th, raised = self._start(client)
         assert wait_until(lambda: len(sink.futs) == 5)
         assert client.commits == []       # pushed ahead, nothing acked
         sink.futs[0].set_result(None)
-        assert wait_until(lambda: client.commits == [9])
+        assert wait_until(lambda: client.commits == [last[0]])
         boom = RuntimeError("insert refused")
         sink.futs[1].set_exception(boom)
         # the batches behind it landed (say): still no commit for them
@@ -441,10 +570,12 @@ class TestQueueSourceCommits:
             f.set_result(None)
         th.join(5)
         assert not th.is_alive() and raised == [boom]
-        assert client.commits == [9] and client.closed
+        assert client.commits == [last[0]] and client.closed
+        return client.commits
 
-    def test_commits_follow_the_acks_in_order(self):
-        client = self.Client(n_batches=4, per_batch=10)
+    def _commits_in_order(self, gaps):
+        client = self.Client(n_batches=4, per_batch=10, gaps=gaps)
+        last = client.last
         sink, src, th, raised = self._start(client)
         assert wait_until(lambda: len(sink.futs) == 4)
         for k in (2, 1):
@@ -452,12 +583,42 @@ class TestQueueSourceCommits:
         time.sleep(0.05)
         assert client.commits == []
         sink.futs[0].set_result(None)
-        assert wait_until(lambda: client.commits == [9, 19, 29])
+        assert wait_until(lambda: client.commits == last[:3])
         src.stop()
         sink.futs[3].set_result(None)
         th.join(5)
         assert not th.is_alive() and raised == []
-        assert client.commits == [9, 19, 29, 39]
+        assert client.commits == last
+        return client.commits
+
+    def test_a_failed_flush_commits_nothing_past_the_last_acked_offset(
+            self):
+        assert self._failed_flush(gaps=False) == [9]
+
+    def test_with_gaps_a_failed_flush_commits_nothing_past_the_last_ack(
+            self):
+        assert self._failed_flush(gaps=True) == [18]
+
+    def test_commits_follow_the_acks_in_order(self):
+        assert self._commits_in_order(gaps=False) == [9, 19, 29, 39]
+
+    def test_commits_follow_the_acks_in_order_with_gaps(self):
+        assert self._commits_in_order(gaps=True) == [18, 43, 68, 93]
+
+    def test_every_acked_unit_records_a_source_ack_span(self):
+        trace.enable(True)
+        trace.reset()
+        try:
+            self._commits_in_order(gaps=False)
+        finally:
+            trace.enable(False)
+        acks = [s for s in trace.spans() if s[0] == "source_ack"]
+        trace.reset()
+        assert len(acks) == 4
+        depths = [s[7]["inflight_units"] for s in acks]
+        # units 2 and 1 are acked while 0 is in flight before them
+        assert all(1 <= d <= 4 for d in depths)
+        assert depths[-1] == 1                  # the last unit alone
 
 
 # helper used above: wait() raises on failure; tests need a non-raising wait

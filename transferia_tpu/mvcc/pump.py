@@ -161,7 +161,7 @@ class MvccPump:
                 self._pending.setdefault(str(b.table_id), []).append(b)
                 self._pending_rows += b.n_rows
             key = partition_key(fb.topic, fb.partition)
-            high = max(fb.offsets())
+            high = fb.last_offset
             if high > self._offsets.get(key, -1):
                 self._offsets[key] = high
             if self._pending_rows >= self.layer_rows:

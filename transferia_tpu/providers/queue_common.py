@@ -12,8 +12,9 @@ from __future__ import annotations
 import logging
 import threading
 import time
+from collections import deque
 from dataclasses import dataclass
-from typing import Callable, Optional, Sequence
+from typing import Optional
 
 from transferia_tpu.abstract.interfaces import AsyncSink, Source
 from transferia_tpu.chaos.failpoints import failpoint
@@ -27,35 +28,52 @@ logger = logging.getLogger(__name__)
 
 
 class Sequencer:
-    """Tracks in-flight (partition, offset) ranges; yields the highest
-    offset safe to commit once pushes confirm (kafka/source.go sequencer:
-    out-of-order acks must not commit past an unacked message)."""
+    """Tracks in-flight fetched units per (topic, partition); yields the
+    highest offset safe to commit once pushes confirm (kafka/source.go
+    sequencer: out-of-order acks must not commit past an unacked
+    message).
+
+    A unit is the range of one fetched batch, recorded by its first and
+    last offset: its offsets ascend, with gaps allowed (compaction,
+    transaction markers), and the units of a partition do not overlap.
+    Start and ack cost O(1) a unit whatever the depth in flight; a unit
+    is acked whole."""
 
     def __init__(self):
         self._lock = threading.Lock()
-        # partition -> sorted list of [offset, acked]
-        self._inflight: dict[tuple[str, int], list[list]] = {}
+        # partition -> in-flight units [first, last, acked], oldest first
+        self._inflight: dict[tuple[str, int], deque[list]] = {}
+        # (topic, partition, a unit's first offset) -> its entry above
+        self._by_first: dict[tuple[str, int, int], list] = {}
 
     def start_processing(self, topic: str, partition: int,
-                         offsets: Sequence[int]) -> None:
+                         first_offset: int, last_offset: int) -> None:
+        entry = [first_offset, last_offset, False]
         with self._lock:
-            lst = self._inflight.setdefault((topic, partition), [])
-            for o in offsets:
-                lst.append([o, False])
+            self._inflight.setdefault((topic, partition),
+                                      deque()).append(entry)
+            self._by_first[(topic, partition, first_offset)] = entry
+
+    def inflight_units(self, topic: str, partition: int) -> int:
+        with self._lock:
+            return len(self._inflight.get((topic, partition), ()))
 
     def ack(self, topic: str, partition: int,
-            offsets: Sequence[int]) -> Optional[int]:
-        """Mark offsets done; return new committable high-water mark (the
-        largest offset with no unacked predecessors), or None."""
+            first_offset: int) -> Optional[int]:
+        """Mark the unit that starts at `first_offset` done; return the
+        new committable high-water mark (the largest offset with no
+        unacked predecessors), or None.  A unit never started, or acked
+        already, changes nothing."""
         with self._lock:
-            lst = self._inflight.get((topic, partition), [])
-            offset_set = set(offsets)
-            for entry in lst:
-                if entry[0] in offset_set:
-                    entry[1] = True
+            entry = self._by_first.pop((topic, partition, first_offset),
+                                       None)
+            if entry is None:
+                return None
+            entry[2] = True
+            units = self._inflight[(topic, partition)]
             commit = None
-            while lst and lst[0][1]:
-                commit = lst.pop(0)[0]
+            while units and units[0][2]:
+                commit = units.popleft()[1]
             return commit
 
 
@@ -65,8 +83,13 @@ class FetchedBatch:
     partition: int
     messages: list[Message]
 
-    def offsets(self) -> list[int]:
-        return [m.offset for m in self.messages]
+    @property
+    def first_offset(self) -> int:
+        return self.messages[0].offset
+
+    @property
+    def last_offset(self) -> int:
+        return self.messages[-1].offset
 
 
 def pump_checkpoint(fb: FetchedBatch,
@@ -100,7 +123,8 @@ class QueueSource(Source):
     """Generic replication source over a fetch/commit client.
 
     client contract:
-      fetch(max_messages) -> list[FetchedBatch] (blocking up to poll timeout)
+      fetch(max_messages) -> list[FetchedBatch] (blocking up to poll
+        timeout; each batch non-empty, its offsets ascending)
       commit(topic, partition, offset) -> None
       close() -> None
     """
@@ -134,10 +158,14 @@ class QueueSource(Source):
         def ack(fb: FetchedBatch, err: Optional[BaseException]):
             if err is not None:
                 return  # failure latches in the parsequeue; no commit
-            commit = self.sequencer.ack(fb.topic, fb.partition,
-                                        fb.offsets())
-            if commit is not None:
-                self.client.commit(fb.topic, fb.partition, commit)
+            with trace.span("source_ack") as sp:
+                if sp:
+                    sp.add(inflight_units=self.sequencer.inflight_units(
+                        fb.topic, fb.partition))
+                commit = self.sequencer.ack(fb.topic, fb.partition,
+                                            fb.first_offset)
+                if commit is not None:
+                    self.client.commit(fb.topic, fb.partition, commit)
 
         pq = ParseQueue(self.parallelism, sink, parse, ack)
         try:
@@ -151,8 +179,8 @@ class QueueSource(Source):
                 for fb in fetched:
                     pump_checkpoint(fb, self.stats, self.transfer_id)
                     self.sequencer.start_processing(
-                        fb.topic, fb.partition, fb.offsets()
-                    )
+                        fb.topic, fb.partition,
+                        fb.first_offset, fb.last_offset)
                     pq.add(fb)
             pq.wait()
             if pq.failure is not None:
